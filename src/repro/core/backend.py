@@ -35,6 +35,9 @@ runs never pay the ``jax.experimental.pallas`` import.
 from __future__ import annotations
 
 import dataclasses
+import os
+import re
+from pathlib import Path
 from typing import Callable, Dict, Optional, Tuple
 
 BACKENDS: Tuple[str, ...] = ("jax", "pallas")
@@ -43,10 +46,16 @@ BACKENDS: Tuple[str, ...] = ("jax", "pallas")
 # once at dispatch instead of per call site
 DEDUP_MODES: Tuple[str, ...] = ("sort", "bloom")
 
-# closure schedules of the jax reference ops; the pallas kernels bake in
-# the static-trip-count doubling schedule (the TPU design point)
+# closure schedules of the jax reference ops.  The pallas kernels have one
+# fixed closure algorithm (Warshall pivots, DESIGN §3) and ignore the
+# choice; they accept only the default name, so existing callers validate
 JAX_SCHEDULES: Tuple[str, ...] = ("doubling", "while", "linear", "matmul")
 PALLAS_SCHEDULES: Tuple[str, ...] = ("doubling",)
+
+# the pallas Bloom kernel holds the whole filter in VMEM twice (input and
+# output block); at 2^29 bits that fills the 128 MiB of a TPU v5e's VMEM,
+# the largest filter its compiler accepts (tests/test_tpu_compile.py)
+PALLAS_BLOOM_MAX_BITS = 1 << 29
 
 # backends whose ops are safe under a leading vmapped lane axis (the
 # multi-lane engine in ``core.batch``).  jax ops vmap trivially; the pallas
@@ -114,15 +123,12 @@ def device_memory_budget(fraction: float = 0.5) -> Optional[int]:
     absent on the CPU backend) and hands ``fraction`` of the free bytes to
     the caller — the rest stays headroom for the adjacency/children
     tensors and XLA scratch.  Returns ``None`` when the platform exposes
-    no stats, which callers (``batch.plan_capacity``) treat as
-    "state-space bound only".  DESIGN.md §10.
+    no stats (the CPU backend), which callers (``batch.plan_capacity``)
+    treat as "state-space bound only"; a device query that fails raises.
+    DESIGN.md §10.
     """
-    try:
-        import jax
-        dev = jax.devices()[0]
-        stats = getattr(dev, "memory_stats", lambda: None)()
-    except Exception:                                # noqa: BLE001
-        return None
+    import jax
+    stats = jax.devices()[0].memory_stats()
     if not stats:
         return None
     limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
@@ -130,6 +136,35 @@ def device_memory_budget(fraction: float = 0.5) -> Optional[int]:
         return None
     free = max(0, int(limit) - int(stats.get("bytes_in_use", 0)))
     return int(free * fraction)
+
+
+# JAX's persistent compilation cache: where ``JAX_COMPILATION_CACHE_DIR``
+# points, else this fixed directory at the checkout root (the path is part
+# of what a later run must find again, so it never depends on a temp
+# name, a pid or the time)
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
+CHECKOUT_CACHE_DIR = CHECKOUT_ROOT / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads it and no
+    directory is set here; otherwise the cache goes to ``.jax_cache/`` at
+    the checkout root.  Either way, source locations drop the checkout
+    root: a Pallas kernel's serialized body keeps them in the cache key,
+    so without this a checkout at another path misses every entry its
+    Pallas programs wrote.  Call once from an entry point, before the
+    first compile.  Returns the directory in use."""
+    import jax
+    if not jax.config.jax_hlo_source_file_canonicalization_regex:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(str(CHECKOUT_ROOT) + os.sep))
+    if os.environ.get(CACHE_ENV):
+        return os.environ[CACHE_ENV]
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    return str(CHECKOUT_CACHE_DIR)
 
 
 def validate(backend: str, *, mode: str = "sort",
@@ -176,15 +211,22 @@ def validate(backend: str, *, mode: str = "sort",
         raise BackendCapabilityError(
             f"backend={backend!r} does not implement schedule="
             f"{schedule!r} (supported: {', '.join(schedules)}). The pallas "
-            "wavefront kernel bakes in the static doubling fixpoint — the "
-            "alternative schedules exist only as jax reference loops; use "
-            "schedule='doubling' or backend='jax'.")
+            "wavefront kernel has one fixed closure algorithm and accepts "
+            "only the default schedule name; the alternative schedules "
+            "exist only as jax reference loops. Use schedule='doubling' or "
+            "backend='jax'.")
     if mode == "bloom" and backend == "pallas" \
             and m_bits is not None and m_bits % 32:
         raise BackendCapabilityError(
             f"backend='pallas' keeps the Bloom filter bit-packed in uint32 "
             f"words, so m_bits must be a multiple of 32 (got {m_bits}). "
             "Round m_bits up or use backend='jax'.")
+    if mode == "bloom" and backend == "pallas" \
+            and m_bits is not None and m_bits > PALLAS_BLOOM_MAX_BITS:
+        raise BackendCapabilityError(
+            f"backend='pallas' holds the Bloom filter in VMEM, which fits at "
+            f"most m_bits={PALLAS_BLOOM_MAX_BITS} (got {m_bits}). Lower "
+            "m_bits or use backend='jax'.")
     # pruning-rule coverage: both rules ride inside the fused pallas
     # wavefront kernel, so nothing to reject here — but resolving the op
     # now turns a future capability regression into an import-time error
@@ -227,7 +269,7 @@ def _pallas_expand_degrees():
     from repro.kernels.expand import expand_degrees
 
     def expand_degrees_op(adj, states, *, n, schedule="doubling"):
-        del schedule          # the kernel bakes in the doubling fixpoint
+        del schedule          # the kernel has one fixed closure algorithm
         return expand_degrees(adj, states, n=n)
     return expand_degrees_op
 

@@ -38,8 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils import compat
-
 from . import backend as backend_lib
 from . import bitset, bounds, dedup
 from . import engine as engine_lib
@@ -189,10 +187,11 @@ def _make_level_shardmap(mesh, *, n, cap_local, block, cap_send,
                 dropped.astype(jnp.int32), stats)
 
     spec_sharded = P(axes)
-    return compat.shard_map(
-        local_fn, mesh,
+    return jax.shard_map(
+        local_fn, mesh=mesh,
         in_specs=(P(), spec_sharded, spec_sharded, P(), P()),
-        out_specs=(spec_sharded, spec_sharded, spec_sharded, P()))
+        out_specs=(spec_sharded, spec_sharded, spec_sharded, P()),
+        check_vma=False)
 
 
 _DIST_FN_CACHE: dict = {}
@@ -255,7 +254,9 @@ class DistFrontier:
     k: int
 
 
-def _init_frontier(mesh, cap_local, w):
+def init_frontier(mesh, cap_local, w):
+    """The DP root {∅} as a frontier sharded over the mesh: states
+    (devices · cap_local, W) split row-wise, one count per device."""
     axes = tuple(mesh.axis_names)
     ndev = mesh.devices.size
     sh_states = NamedSharding(mesh, P(axes))
@@ -302,7 +303,7 @@ def decide_launch(g: Graph, k: int, clique, mesh: Mesh, *,
     allowed_dev = jnp.asarray(_allowed_words(n, clique))
     cap_send = max(32, (2 * cap_local) // ndev)
 
-    states, counts = _init_frontier(mesh, cap_local, w)
+    states, counts = init_frontier(mesh, cap_local, w)
     start_level, expanded0, inexact0 = 0, 0, False
     if resume is not None:
         states, counts = _restore(mesh, resume, cap_local, w)
@@ -378,7 +379,7 @@ def decide_distributed(g: Graph, k: int, clique: list, mesh: Mesh, *,
     allowed_dev = jnp.asarray(_allowed_words(n, clique))
     cap_send = max(32, (2 * cap_local) // ndev)
 
-    states, counts = _init_frontier(mesh, cap_local, w)
+    states, counts = init_frontier(mesh, cap_local, w)
     start_level, expanded, inexact = 0, 0, False
     if resume is not None:
         states, counts = _restore(mesh, resume, cap_local, w)

@@ -75,7 +75,7 @@ def shard_frontiers(shards: int, cap: int, w: int) -> Frontier:
 
     Unlike ``lane_frontiers`` (B independent instances, B roots) a
     sharded frontier holds ONE search: the single ``{∅}`` root lives in
-    shard 0 (mirroring ``distributed._init_frontier``) and subsequent
+    shard 0 (mirroring ``distributed.init_frontier``) and subsequent
     levels spread across shards by ownership routing (``core.shard``).
     Leaves carry a leading ``shards`` axis: states ``(S, cap, W)``,
     count/dropped ``(S,)``."""

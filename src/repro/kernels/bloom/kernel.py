@@ -9,16 +9,17 @@ mutex/false-negative problem disappears.  Across devices, the distributed
 solver hash-partitions states so each filter shard has a single writer
 (DESIGN.md §2) — ownership replaces atomicity.
 
-The filter itself is bit-packed uint32 (as on the GPU) and is updated
-in place via input/output aliasing.  Murmur3 is recomputed inside the
-kernel (uint32 arithmetic on the VPU).
+The filter is bit-packed uint32 (as on the GPU), viewed as (T, 8, 128)
+vreg tiles and held in VMEM for the whole call; it is updated in place via
+input/output aliasing.  A probe is a vector read-modify-write of the one
+tile that holds its word: load the tile, test and set the bit under a
+one-hot (sublane, lane) mask, store the tile back.  The murmur3 hashes of
+the rows are computed by ``repro.core.bloom.murmur3_words`` before the
+call (one XLA pass) and read per row from SMEM with the valid flags.
 
-NOTE on memory spaces: the filter is declared with a whole-array BlockSpec.
-On a real TPU a multi-megabyte filter would stream through VMEM in DMA'd
-tiles; random-probe scatter into HBM is the one part of the paper's design
-that has no efficient TPU analogue — which is exactly why the framework's
-default dedup is the sort-based one (see dedup.py).  This kernel is the
-paper-faithful artifact, validated in interpret mode.
+Random probes into a multi-megabyte filter are the one part of the
+paper's design that has no efficient TPU analogue — which is exactly why
+the framework's default dedup is the sort-based one (see dedup.py).
 """
 from __future__ import annotations
 
@@ -28,60 +29,48 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.bloom import C1, C2, MIX1, MIX2, SEED1, SEED2
+from repro.core.bloom import SEED1, SEED2, murmur3_words
+from repro.kernels.common import U32
 
-U32 = jnp.uint32
-
-
-def _rotl(x, r):
-    r = np.uint32(r)
-    return (x << r) | (x >> np.uint32(32 - r))
+TILE_WORDS = 8 * 128
 
 
-def _murmur_scalar(words, w: int, seed):
-    """Murmur3-32 of a (w,) uint32 vector -> scalar uint32 (unrolled)."""
-    h = jnp.asarray(seed, dtype=U32)
-    for j in range(w):
-        kv = words[j]
-        kv = kv * C1
-        kv = _rotl(kv, 15)
-        kv = kv * C2
-        h = h ^ kv
-        h = _rotl(h, 13)
-        h = h * np.uint32(5) + np.uint32(0xE6546B64)
-    h = h ^ np.uint32(w * 4)
-    h = h ^ (h >> np.uint32(16))
-    h = h * MIX1
-    h = h ^ (h >> np.uint32(13))
-    h = h * MIX2
-    h = h ^ (h >> np.uint32(16))
-    return h
+def _bloom_kernel(rows_ref, filt_in_ref, new_ref, filt_ref, *, m_bits: int,
+                  k_hashes: int, block: int):
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        filt_ref[...] = filt_in_ref[...]
 
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
 
-def _bloom_kernel(states_ref, valid_ref, filt_in_ref, new_ref, filt_ref, *,
-                  w: int, m_bits: int, k_hashes: int, block: int):
-    del filt_in_ref  # aliased with filt_ref (in-place update)
+    def insert_one(i, carry):
+        h1, h2, valid = rows_ref[0, i], rows_ref[1, i], rows_ref[2, i]
 
-    def insert_one(i, _):
-        words = states_ref[i, :]
-        valid = valid_ref[i] != 0
-        h1 = _murmur_scalar(words, w, SEED1)
-        h2 = _murmur_scalar(words, w, SEED2)
+        def probe(j, any_zero):
+            idx = (h1 + j.astype(U32) * h2) % np.uint32(m_bits)
+            word = idx >> np.uint32(5)
+            tile = (word >> np.uint32(10)).astype(jnp.int32)
+            at = ((sub == ((word >> np.uint32(7)) & np.uint32(7))
+                   .astype(jnp.int32))
+                  & (lane == (word & np.uint32(127)).astype(jnp.int32)))
+            bit = jnp.where(at, np.uint32(1) << (idx & np.uint32(31)),
+                            np.uint32(0))
+            old = filt_ref[tile]
+            seen = jnp.max(jnp.where((old & bit) != 0, 1, 0))
+            filt_ref[tile] = old | bit
+            return any_zero | (seen == 0)
 
-        def probe(j, carry):
-            any_zero = carry
-            idx = (h1 + jnp.asarray(j, U32) * h2) % np.uint32(m_bits)
-            word_idx = (idx >> np.uint32(5)).astype(jnp.int32)
-            bit = U32(1) << (idx & np.uint32(31))
-            old = filt_ref[pl.dslice(word_idx, 1)][0]
-            new_word = jnp.where(valid, old | bit, old)
-            filt_ref[pl.dslice(word_idx, 1)] = new_word[None]
-            return any_zero | ((old & bit) == 0)
+        new_ref[0, i] = 0
 
-        any_zero = jax.lax.fori_loop(0, k_hashes, probe, jnp.bool_(False))
-        new_ref[i] = (valid & any_zero).astype(jnp.int32)
-        return 0
+        @pl.when(valid != 0)                 # invalid rows probe nothing
+        def _():
+            any_zero = jax.lax.fori_loop(0, k_hashes, probe, False)
+            new_ref[0, i] = any_zero.astype(jnp.int32)
+
+        return carry
 
     jax.lax.fori_loop(0, block, insert_one, 0)
 
@@ -91,34 +80,43 @@ def _bloom_kernel(states_ref, valid_ref, filt_in_ref, new_ref, filt_ref, *,
 def bloom_insert_pallas(filter_words: jnp.ndarray, states: jnp.ndarray,
                         valid: jnp.ndarray, *, m_bits: int,
                         k_hashes: int = 17, block: int = 256,
-                        interpret: bool = True):
+                        interpret: bool):
     """Sequentially insert ``states`` rows; returns (was_new (B,), filter).
 
-    B must be a multiple of ``block`` (callers pad with valid=0 rows).
+    filter_words (m_bits / 32,) uint32; states (B, W); valid (B,).  Rows
+    are padded to a multiple of ``block`` (rows per grid step) with
+    invalid rows.
     """
-    bt, w = states.shape
-    assert bt % block == 0
+    b = states.shape[0]
     m_words = filter_words.shape[0]
-    grid = (bt // block,)
-    kernel = functools.partial(_bloom_kernel, w=w, m_bits=m_bits,
+    pad = (-b) % block
+    rows = jnp.stack([murmur3_words(states, SEED1),
+                      murmur3_words(states, SEED2),
+                      valid.astype(U32)])
+    rows = jnp.pad(rows, ((0, 0), (0, pad)))
+    tiles = -(-m_words // TILE_WORDS)
+    filt = jnp.pad(filter_words, (0, tiles * TILE_WORDS - m_words))
+    kernel = functools.partial(_bloom_kernel, m_bits=m_bits,
                                k_hashes=k_hashes, block=block)
+    whole_filter = pl.BlockSpec((tiles, 8, 128), lambda i: (0, 0, 0))
     was_new, filt = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=((b + pad) // block,),
         in_specs=[
-            pl.BlockSpec((block, w), lambda i: (i, 0)),     # states tile
-            pl.BlockSpec((block,), lambda i: (i,)),         # valid tile
-            pl.BlockSpec((m_words,), lambda i: (0,)),       # filter (aliased)
+            pl.BlockSpec((3, block), lambda i: (0, i),
+                         memory_space=pltpu.SMEM),      # h1, h2, valid
+            whole_filter,                               # filter (aliased)
         ],
         out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((m_words,), lambda i: (0,)),
+            pl.BlockSpec((1, block), lambda i: (0, i),
+                         memory_space=pltpu.SMEM),
+            whole_filter,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bt,), jnp.int32),
-            jax.ShapeDtypeStruct((m_words,), jnp.uint32),
+            jax.ShapeDtypeStruct((1, b + pad), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, 8, 128), U32),
         ],
-        input_output_aliases={2: 1},
+        input_output_aliases={1: 1},
         interpret=interpret,
-    )(states, valid.astype(jnp.int32), filter_words)
-    return was_new.astype(jnp.bool_), filt
+    )(rows, filt.reshape(tiles, 8, 128))
+    return was_new[0, :b].astype(jnp.bool_), filt.reshape(-1)[:m_words]

@@ -20,19 +20,9 @@ def make_filter_words(m_bits: int) -> jnp.ndarray:
 def bloom_insert(filter_words, states, valid, *, m_bits: int,
                  k_hashes: int = 17, block: int = 256,
                  interpret: bool | None = None):
-    """Insert states (B, W) into the packed filter; returns (was_new, filter).
-
-    Pads the batch to the kernel block size with invalid rows.
-    """
+    """Insert states (B, W) into the packed filter; returns (was_new, filter)."""
     if interpret is None:
         interpret = default_interpret()
-    b, w = states.shape
-    pad = (-b) % block
-    if pad:
-        states = jnp.concatenate(
-            [states, jnp.zeros((pad, w), dtype=states.dtype)], axis=0)
-        valid = jnp.concatenate([valid, jnp.zeros((pad,), dtype=bool)], axis=0)
-    was_new, filt = bloom_insert_pallas(
+    return bloom_insert_pallas(
         filter_words, states, valid, m_bits=m_bits, k_hashes=k_hashes,
         block=block, interpret=interpret)
-    return was_new[:b], filt

@@ -1,86 +1,126 @@
 """Pallas TPU kernel: wavefront state expansion.
 
 Replaces the paper's thread-per-(state, vertex) DFS (Listing 1, lines 7-19)
-with a blocked, divergence-free bitset fixpoint executed on the VPU:
+with a divergence-free bitset computation on the VPU:
 
-  * the packed adjacency matrix (n x W uint32, <= 8 KiB at n=256) is pinned
-    in VMEM for every grid step (the analogue of the paper putting adjacency
-    lists in constant memory);
-  * each grid step processes a ``block`` of states resident in VMEM
+  * states lie across vector lanes (``repro.kernels.common``): every
+    bitset operation below is elementwise over 128·S states at once;
+  * the packed (n, W) uint32 adjacency matrix is pinned in SMEM and
+    read as scalars — the analogue of the paper putting adjacency lists in
+    constant memory;
+  * each grid step processes a ``block`` of state rows resident in VMEM
     (the analogue of the work-group size knob from Table 2);
-  * the component-closure doubling loop has a static trip count
-    ceil(log2 n) — zero branch divergence by construction.
+  * the component closure is Warshall's algorithm with a static trip
+    count of n pivots — zero branch divergence by construction — and
+    each pivot updates all n rows at once (rows lie on the untiled
+    leading axis of a VMEM ref).
 
 ``reach_block`` is the factored kernel body: the closure/reach/degree math
 shared with the fused wavefront kernel (``repro.kernels.wavefront``), which
 composes it with feasibility masking and the pruning rules in one VMEM
 pass.  This standalone kernel emits only deg_S(v); child construction /
-dedup happen outside.  Validated in interpret mode against ``ref.expand_ref``
-and the python DFS oracle (tests/test_kernels_expand.py).
+dedup happen outside.  Validated against ``ref.expand_ref`` and the python
+DFS oracle (tests/test_kernels_expand.py).
 """
 from __future__ import annotations
 
 import functools
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
+from repro.kernels.common import LANES, U32, mask_of
 
-U32 = jnp.uint32
+
+N_SCRATCH = 5   # adjv, z, nb, reach, q — each (n, W, S, 128) uint32
 
 
-def reach_block(adj, states, *, n: int):
-    """Closure + reach + degrees for a block of states, all in registers/VMEM.
+def reach_block(adj_ref, s, adjv_ref, z_ref, nb_ref, reach_ref, q_ref, *,
+                n: int):
+    """Closure + reach + degrees for a block of states, all in VMEM.
 
-    adj (n, W) uint32; states (B, W) uint32 ->
-      (deg (B, n) int32, reach (B, n, W) uint32, q (B, n, W) uint32)
-    where reach[b, v] is the eliminated-graph adjacency row of v under
-    state b and q = reach \\ S \\ {v} (the paper's Q(S, v) set).
-    Rows for v in S are garbage; callers mask them.
+    adj_ref: SMEM (n, W) uint32 adjacency words; s: W arrays (S, 128) of
+    state words.  The other refs are (n, W, S, 128) VMEM scratch, row v on
+    the leading axis: adjv_ref gets the adjacency broadcast over states,
+    reach_ref[v] the eliminated-graph adjacency row of v under each state
+    and q_ref[v] the set q = reach \\ S \\ {v} (the paper's Q(S, v)).
+    Returns deg (n, S, 128) int32, deg[v] = |q[v]|.  Rows for v in S are
+    garbage; callers mask them.
     """
-    b, w = states.shape
-    eye = common.eye_words(n, w)
-    steps = common.log2_ceil(max(n, 2))
+    w = len(s)
+    zero = jnp.zeros_like(s[0])
+    for v in range(n):
+        for j in range(w):
+            adjv_ref[v, j] = zero | adj_ref[v, j]
+    eye = [common.eye(n, j, s[0]) for j in range(w)]
+    in_s = mask_of(common.unpack(s, n))
 
-    s_bits = common.unpack(states, n)              # (B, n)
-    masked_adj = adj[None, :, :] & states[:, None, :]      # N(i) ∩ S
-    z = jnp.where(s_bits[:, :, None], masked_adj | eye[None], U32(0))
+    # z[i] = (N(i) ∩ S) ∪ {i} for i in S, else ∅
+    for j in range(w):
+        z_ref[:, j] = in_s & ((adjv_ref[:, j] & s[j][None]) | eye[j])
+        nb_ref[:, j] = jnp.zeros_like(in_s)
 
-    for _ in range(steps):                         # static: no divergence
-        z = z | common.bor_matmul(z, z, n)
+    def close(c, cw, sh):                  # Warshall: z[i] |= z[c], c ∈ z[i]
+        zc = z_ref[c]
+        m = mask_of((z_ref[:, cw] >> sh) & np.uint32(1))
+        for j in range(w):
+            z_ref[:, j] = z_ref[:, j] | (zc[j][None] & m)
 
-    rows_adj = jnp.broadcast_to(adj[None], (b, n, w))
-    nb = common.bor_matmul(z, rows_adj, n)         # N(component(i))
-    reach = adj[None] | common.bor_matmul(masked_adj, nb, n)
-    q = (reach & ~states[:, None, :]) & ~eye[None]
-    deg = common.popcount(q)
-    return deg, reach, q
+    common.pivot_loop(n, w, close)         # z[i] = component of i in G[S]
+
+    def spread(c, cw, sh):                 # nb[i] = N(component(i))
+        ac = adjv_ref[c]
+        m = mask_of((z_ref[:, cw] >> sh) & np.uint32(1))
+        for j in range(w):
+            nb_ref[:, j] = nb_ref[:, j] | (ac[j][None] & m)
+
+    common.pivot_loop(n, w, spread)
+
+    def gather(i, iw, sh):                 # reach[v] |= nb[i], i ∈ N(v)
+        # nb[i] = ∅ for i ∉ S (z[i] = ∅), so N(v) ∩ S needs no S mask
+        nbi = nb_ref[i]
+        m = mask_of((adjv_ref[:, iw] >> sh) & np.uint32(1))
+        for j in range(w):
+            reach_ref[:, j] = reach_ref[:, j] | (nbi[j][None] & m)
+
+    reach_ref[...] = adjv_ref[...]
+    common.pivot_loop(n, w, gather)
+
+    q = []
+    for j in range(w):
+        q.append(reach_ref[:, j] & ~s[j][None] & ~eye[j])
+        q_ref[:, j] = q[j]
+    return common.popcount(q)
 
 
-def _expand_kernel(adj_ref, states_ref, deg_ref, *, n: int):
-    deg, _reach, _q = reach_block(adj_ref[...], states_ref[...], n=n)
-    deg_ref[...] = deg                             # (B, n)
+def _expand_kernel(adj_ref, states_ref, deg_ref, *scratch, n: int):
+    s = [states_ref[j] for j in range(states_ref.shape[0])]
+    deg_ref[...] = reach_block(adj_ref, s, *scratch, n=n)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
 def expand_degrees_pallas(adj: jnp.ndarray, states: jnp.ndarray, *, n: int,
-                          block: int = 16, interpret: bool = True):
-    """deg_S(v) for every state row and vertex v.  states (B, W) must have
-    B % block == 0 (callers pad; padding rows give garbage, mask outside)."""
-    bt, w = states.shape
-    assert bt % block == 0, (bt, block)
-    grid = (bt // block,)
+                          block: int, interpret: bool):
+    """deg_S(v) for every state row and vertex v: adj (n, W), states
+    (B, W) -> (B, n) int32.  ``block`` is state rows of 128 per grid step
+    (``common.lane_geometry``)."""
+    b, w = states.shape
+    rows, step = common.lane_geometry(b, block)
     kernel = functools.partial(_expand_kernel, n=n)
-    return pl.pallas_call(
+    deg = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(rows // step,),
         in_specs=[
-            pl.BlockSpec((n, w), lambda i: (0, 0)),        # adjacency: pinned
-            pl.BlockSpec((block, w), lambda i: (i, 0)),    # states tile
+            common.smem((n, w)),                       # adjacency: pinned
+            common.lane_tile(step, w),                 # states tile
         ],
-        out_specs=pl.BlockSpec((block, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bt, n), jnp.int32),
+        out_specs=common.lane_tile(step, n),
+        out_shape=jax.ShapeDtypeStruct((n, rows, LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((n, w, step, LANES), U32)] * N_SCRATCH,
         interpret=interpret,
-    )(adj, states)
+    )(adj, common.to_lanes(states, rows))
+    return common.from_lanes(deg, b)

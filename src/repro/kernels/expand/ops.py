@@ -12,19 +12,12 @@ from .kernel import expand_degrees_pallas
 
 @functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
 def expand_degrees(adj: jnp.ndarray, states: jnp.ndarray, *, n: int,
-                   block: int = 16, interpret: bool | None = None):
-    """Degrees deg_S(v) for a batch of states; pads the batch to the kernel
-    block size and strips the padding again.
+                   block: int = 8, interpret: bool | None = None):
+    """Degrees deg_S(v) for a batch of states.
 
     adj: (n, W) uint32; states: (B, W) uint32 -> (B, n) int32.
     """
     if interpret is None:
         interpret = default_interpret()
-    b, w = states.shape
-    pad = (-b) % block
-    if pad:
-        states = jnp.concatenate(
-            [states, jnp.zeros((pad, w), dtype=states.dtype)], axis=0)
-    out = expand_degrees_pallas(adj, states, n=n, block=block,
-                                interpret=interpret)
-    return out[:b]
+    return expand_degrees_pallas(adj, states, n=n, block=block,
+                                 interpret=interpret)

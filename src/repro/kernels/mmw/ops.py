@@ -11,19 +11,11 @@ from .kernel import mmw_bounds_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
-def mmw_bounds(reach, states, k, *, n: int, block: int = 64,
+def mmw_bounds(reach, states, k, *, n: int, block: int = 8,
                interpret: bool | None = None):
-    """MMW lower bounds, padding the batch to the kernel block size."""
+    """MMW lower bounds: reach (B, n, W), states (B, W), k -> (B,) int32."""
     if interpret is None:
         interpret = default_interpret()
-    b = reach.shape[0]
-    pad = (-b) % block
-    if pad:
-        reach = jnp.concatenate(
-            [reach, jnp.zeros((pad,) + reach.shape[1:], reach.dtype)])
-        states = jnp.concatenate(
-            [states, jnp.zeros((pad,) + states.shape[1:], states.dtype)])
-    k = jnp.asarray(k, jnp.int32)[None]
-    out = mmw_bounds_pallas(reach, states, k, n=n, block=block,
-                            interpret=interpret)
-    return out[:b]
+    k = jnp.asarray(k, jnp.int32).reshape(1, 1)
+    return mmw_bounds_pallas(reach, states, k, n=n, block=block,
+                             interpret=interpret)

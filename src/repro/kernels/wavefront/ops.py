@@ -23,7 +23,7 @@ def wavefront_expand(adj, states, valid, k, allowed, *, n: int,
                      schedule: str = "doubling", use_mmw: bool = False,
                      use_simplicial: bool = False, block: int = 8,
                      interpret: bool | None = None):
-    """Fused expand + feasibility + pruning, padding to the kernel block.
+    """Fused expand + feasibility + pruning.
 
     adj (n, W) uint32; states (B, W) uint32; valid (B,) bool; k scalar
     int32; allowed (W,) uint32 -> (children (B, n, W), feasible (B, n) bool).
@@ -32,19 +32,13 @@ def wavefront_expand(adj, states, valid, k, allowed, *, n: int,
         # the registry rejects this combination before dispatch; this guard
         # catches direct callers
         raise ValueError(
-            f"pallas wavefront kernel fuses the closure fixpoint with a "
-            f"static doubling schedule; schedule={schedule!r} is jax-only")
+            f"pallas wavefront kernel has one fixed closure algorithm and "
+            f"accepts only schedule='doubling'; schedule={schedule!r} is "
+            f"jax-only")
     if interpret is None:
         interpret = default_interpret()
-    b, w = states.shape
-    pad = (-b) % block
-    if pad:
-        states = jnp.concatenate(
-            [states, jnp.zeros((pad, w), dtype=states.dtype)], axis=0)
-        valid = jnp.concatenate(
-            [valid, jnp.zeros((pad,), dtype=bool)], axis=0)
-    kdev = jnp.asarray(k, jnp.int32).reshape(1)
+    kdev = jnp.asarray(k, jnp.int32).reshape(1, 1)
     children, feas = wavefront_pallas(
         adj, states, valid, kdev, allowed, n=n, block=block,
         use_mmw=use_mmw, use_simplicial=use_simplicial, interpret=interpret)
-    return children[:b], feas[:b].astype(jnp.bool_)
+    return children, feas.astype(jnp.bool_)

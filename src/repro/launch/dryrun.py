@@ -20,7 +20,6 @@ from repro.launch.mesh import make_production_mesh          # noqa: E402
 from repro.models import Model                              # noqa: E402
 from repro.sharding import rules as rules_lib               # noqa: E402
 from repro.train import step as step_lib                    # noqa: E402
-from repro.utils import compat                              # noqa: E402
 from repro.utils import hlo as hlo_lib                      # noqa: E402
 from repro.utils import hlo2 as hlo2_lib                    # noqa: E402
 
@@ -61,7 +60,7 @@ def lower_cell(arch: str, shape_name: str, mesh, constrain: bool = False,
     if remat_override:
         cfg = cfg.replace(remat=remat_override)
     if constrain or gather_once:
-        compat.set_mesh(mesh)
+        jax.sharding.set_mesh(mesh)
     shape = SHAPES[shape_name]
     ok, reason = applicable(cfg, shape)
     if not ok:
@@ -117,7 +116,7 @@ def lower_cell(arch: str, shape_name: str, mesh, constrain: bool = False,
     compiled = lowered.compile()
     compile_s = time.time() - t0
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     text = compiled.as_text()
     coll = hlo_lib.collective_bytes(text)            # body-once (raw)
     coll_scaled = hlo2_lib.collective_bytes_scaled(text)  # x trip counts

@@ -170,4 +170,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.core.backend import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

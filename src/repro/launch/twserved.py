@@ -257,15 +257,15 @@ class TwServer:
     def _drive(self):
         """The one thread that owns JAX: overlapped scheduler steps while
         busy, condition-wait while idle.  A raising step must never kill
-        the only thread that advances the pool — it is logged, the
-        scheduler recovers its in-flight state, and driving resumes."""
+        the only thread that advances the pool — it is logged, counted as
+        ``driver_errors`` on the pool tracker (so ``metrics`` shows it),
+        the scheduler recovers its in-flight state, and driving resumes."""
         while not self._stop.is_set():
             try:
                 stepped = self.sched.step()
                 self._evict()
             except Exception:        # noqa: BLE001 — keep the pool alive
-                traceback.print_exc()
-                self.sched.recover()
+                self._driver_fault()
                 self._stop.wait(timeout=0.5)    # never a hot error loop
                 continue
             if not stepped:
@@ -275,8 +275,12 @@ class TwServer:
         try:
             self.sched.run()
         except Exception:            # noqa: BLE001
-            traceback.print_exc()
-            self.sched.recover()
+            self._driver_fault()
+
+    def _driver_fault(self) -> None:
+        traceback.print_exc()
+        self.sched.tracker.count(driver_errors=1)
+        self.sched.recover()
 
     def _evict(self):
         """Bound a long-lived server's memory: keep only the newest
@@ -517,4 +521,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.core.backend import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

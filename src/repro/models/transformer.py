@@ -285,8 +285,7 @@ def _constrain_dp(x, cfg):
     if x.ndim >= 2 and x.shape[1] == 1:          # decode step
         return x
     try:
-        from repro.utils import compat
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         names = getattr(mesh, "axis_names", ()) or ()
         dp = tuple(a for a in ("pod", "data") if a in names)
         if not dp:

@@ -11,7 +11,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models import causal_lm_loss
 from repro.optim import optimizers as opt_lib
 from repro.sharding import rules as rules_lib
-from repro.utils import compat
 
 
 def init_state(model, key, tcfg):
@@ -106,7 +105,7 @@ def build_train_step(model, tcfg, mesh=None):
                 lambda x: x.reshape((nm, b // nm) + x.shape[1:]), batch)
             # the (B,)->(nm, B/nm) reshape must keep the DP sharding on the
             # inner batch dim, or GSPMD replicates every microbatch slice
-            amesh = compat.get_abstract_mesh()
+            amesh = jax.sharding.get_abstract_mesh()
             if getattr(amesh, "axis_names", None):
                 dp = tuple(a for a in ("pod", "data")
                            if a in amesh.axis_names)
@@ -132,7 +131,7 @@ def build_train_step(model, tcfg, mesh=None):
 
     def _gather_specs():
         """FSDP-free param specs (model axes only) from the ambient mesh."""
-        amesh = compat.get_abstract_mesh()
+        amesh = jax.sharding.get_abstract_mesh()
         if not getattr(amesh, "axis_names", None):
             return None
         gather_rules = dict(rules_lib.DEFAULT_RULES, embed=())
@@ -142,7 +141,7 @@ def build_train_step(model, tcfg, mesh=None):
                 p.shape, p.axes, amesh, gather_rules)), model.spec)
 
     def _fsdp_specs():
-        amesh = compat.get_abstract_mesh()
+        amesh = jax.sharding.get_abstract_mesh()
         if not getattr(amesh, "axis_names", None):
             return None
         from repro.models.params import map_spec
@@ -221,7 +220,7 @@ def build_compressed_grads(model, tcfg, mesh):
     pspec = jax.tree.map(lambda _: P(), model.abstract())
     # shard_map with axis_names restricted to the DP axes leaves the
     # remaining mesh axes automatic (TP composes via GSPMD)
-    return compat.shard_map(local, mesh=mesh,
-                            in_specs=(pspec, P(dp)),
-                            out_specs=(pspec, P()),
-                            axis_names=set(dp))
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(pspec, P(dp)),
+                         out_specs=(pspec, P()),
+                         axis_names=set(dp), check_vma=False)

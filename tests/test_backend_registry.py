@@ -6,6 +6,7 @@ registry; unsupported op/backend/flag combinations raise
 ``solver.decide``/``solve`` and the CLI — never a bare TypeError deep
 inside a jit.
 """
+import os
 import warnings
 
 import pytest
@@ -68,6 +69,16 @@ def test_pallas_bloom_requires_word_aligned_filter():
     backend_lib.validate("jax", mode="bloom", m_bits=(1 << 14) + 1)
 
 
+def test_pallas_bloom_filter_must_fit_vmem():
+    top = backend_lib.PALLAS_BLOOM_MAX_BITS
+    backend_lib.validate("pallas", mode="bloom", m_bits=top)
+    with pytest.raises(BackendCapabilityError, match="VMEM"):
+        backend_lib.validate("pallas", mode="bloom", m_bits=2 * top)
+    # the jax filter lives in HBM; sort mode ignores m_bits
+    backend_lib.validate("jax", mode="bloom", m_bits=2 * top)
+    backend_lib.validate("pallas", mode="sort", m_bits=2 * top)
+
+
 def test_validate_rejects_unknown_mode_and_backend():
     with pytest.raises(BackendCapabilityError, match="mode"):
         backend_lib.validate("jax", mode="hashset")
@@ -117,3 +128,69 @@ def test_cli_reports_capability_error(capsys):
                    "--schedule", "while"])
     assert rc == 2
     assert "unsupported configuration" in capsys.readouterr().err
+
+
+@pytest.fixture
+def source_regex():
+    """Restore JAX's source-path canonicalization after the test."""
+    import jax
+    name = "jax_hlo_source_file_canonicalization_regex"
+    before = getattr(jax.config, name)
+    yield
+    jax.config.update(name, before)
+
+
+def test_compile_cache_leaves_env_dir_to_jax(monkeypatch, tmp_path,
+                                             source_regex):
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(backend_lib.CACHE_ENV, str(tmp_path))
+    assert backend_lib.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_keys_drop_the_checkout_path(monkeypatch, tmp_path,
+                                                   source_regex):
+    """A program traced from this checkout names its source files
+    relative to the checkout root, so its cache key is the same wherever
+    the checkout lives."""
+    import jax
+    import jax.numpy as jnp
+    monkeypatch.setenv(backend_lib.CACHE_ENV, str(tmp_path))
+    root = str(backend_lib.CHECKOUT_ROOT) + os.sep
+
+    def traced_here(x):
+        return jnp.sin(x) + 1
+
+    def locations():
+        return jax.jit(traced_here).lower(jnp.ones(4)).as_text(
+            debug_info=True)
+
+    assert root + "tests" in locations()
+    backend_lib.enable_compile_cache()
+    text = locations()
+    assert root not in text
+    assert '"tests' + os.sep + "test_backend_registry.py" in text
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch,
+                                                      source_regex):
+    import os
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.delenv(backend_lib.CACHE_ENV, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        first = backend_lib.enable_compile_cache()
+        assert backend_lib.enable_compile_cache() == first
+        assert jax.config.jax_compilation_cache_dir == first
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        cc.reset_cache()
+
+
+def test_device_memory_budget_is_none_without_stats():
+    # the CPU backend reports no allocator stats: "state-space bound only"
+    assert backend_lib.device_memory_budget() is None

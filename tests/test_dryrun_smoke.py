@@ -33,7 +33,6 @@ def test_reduced_cells_lower_on_4x4_mesh():
         from repro.models import Model
         from repro.sharding import rules as rules_lib
         from repro.train import step as step_lib
-        from repro.utils import compat
 
         mesh = jax.make_mesh((4, 4), ("data", "model"))
         for arch in ["qwen3-0.6b", "granite-moe-1b-a400m", "xlstm-1.3b",
@@ -61,7 +60,7 @@ def test_reduced_cells_lower_on_4x4_mesh():
                               out_shardings=(state_sh, None)).lower(
                                   state_abs, specs)
             compiled = lowered.compile()
-            cost = compat.cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis()
             assert cost.get("flops", 0) > 0, arch
             print("LOWERED", arch)
         print("DRYRUN-SMOKE-OK")

@@ -274,3 +274,45 @@ def test_keep_levels_forces_host_engine():
                       reconstruct=True, engine="fused")
     assert res.order is not None
     assert solver.order_width(g, res.order) == res.width == 4
+
+
+@pytest.mark.parametrize("cfg", CONFIGS, ids=CONFIG_IDS)
+def test_lane_engine_backend_parity_multi_step_chunks(cfg):
+    """jax vs pallas under the pool's lane vmap at the service's chunk of
+    2048 states: 16 rows of 128, so every chunk runs the kernel over two
+    grid steps of its default 8 rows, as on the chip.  The graph's levels
+    grow past 1024 states (to 1716 in sort mode), so the second step
+    holds live states too.  The filter is large enough that no two states
+    of one chunk share all their probe bits: there the jax op (query the
+    whole chunk, then insert) and the sequential kernel differ."""
+    from repro.core import batch, frontier as fr_lib
+    from repro.kernels.common import lane_geometry
+
+    chunk = 2048
+    rows, step = lane_geometry(chunk, 8)
+    assert rows // step == 2
+    g = graph.gnp(13, 0.2, 5)
+    n, cap = g.n, chunk
+    adj, allowed = _devify(g)
+    ks = [4, 5]
+    b = len(ks)
+    out = {}
+    for backend in BACKENDS:
+        kw = dict(n=n, cap=cap, block=chunk, m_bits=1 << 20, k_hashes=4,
+                  schedule="doubling", backend=backend, **cfg)
+        out[backend] = batch._lanes_decide(
+            jnp.broadcast_to(adj, (b,) + adj.shape),
+            jnp.broadcast_to(allowed, (b,) + allowed.shape),
+            jnp.asarray(ks, jnp.int32),
+            jnp.asarray([n - (k + 1) for k in ks], jnp.int32),
+            fr_lib.lane_frontiers(b, cap, adj.shape[-1]), **kw)
+    (fr_j, lvl_j, exp_j, drop_j) = out["jax"]
+    (fr_p, lvl_p, exp_p, drop_p) = out["pallas"]
+    np.testing.assert_array_equal(np.asarray(lvl_j), np.asarray(lvl_p))
+    np.testing.assert_array_equal(np.asarray(exp_j), np.asarray(exp_p))
+    np.testing.assert_array_equal(np.asarray(drop_j), np.asarray(drop_p))
+    np.testing.assert_array_equal(np.asarray(fr_j.count),
+                                  np.asarray(fr_p.count))
+    np.testing.assert_array_equal(np.asarray(fr_j.states),
+                                  np.asarray(fr_p.states))
+    assert int(np.asarray(exp_j).sum()) > 0

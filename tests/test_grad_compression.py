@@ -38,7 +38,6 @@ def test_compressed_psum_matches_mean_grad():
         import numpy as np
         import jax, jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.train.step import compressed_psum
 
         mesh = Mesh(np.asarray(jax.devices()), ("data",))
@@ -47,8 +46,8 @@ def test_compressed_psum_matches_mean_grad():
         def local(xs):
             return compressed_psum(xs, ("data",))
 
-        f = jax.jit(shard_map(local, mesh=mesh, in_specs=P("data"),
-                              out_specs=P("data"), check_rep=False))
+        f = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P("data"),
+                                  out_specs=P("data"), check_vma=False))
         got = np.asarray(f(x))[0]              # every shard returns the mean
         want = np.asarray(jnp.mean(x, axis=0))
         rel = np.linalg.norm(got - want) / np.linalg.norm(want)

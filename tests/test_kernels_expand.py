@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import bitset, expand, graph
+from repro.kernels.common import LANES, lane_geometry
 from repro.kernels.expand import expand_degrees, expand_ref
 
 
@@ -32,8 +33,11 @@ def test_kernel_matches_ref_shape_sweep(n):
 
 @pytest.mark.parametrize("block", [1, 2, 8, 16])
 def test_block_size_sweep(block):
-    n = 24
-    g, ss = _random_case(n, 16, seed=7)
+    """Three grid steps of ``block`` rows of 128 states, the last padded."""
+    n, b = 24, 2 * LANES * block + 37
+    rows, step = lane_geometry(b, block)
+    assert (rows // step, step) == (3, block)
+    g, ss = _random_case(n, b, seed=7)
     adj = jnp.asarray(g.packed())
     states = jnp.asarray(bitset.np_pack(ss, n))
     got = np.asarray(expand_degrees(adj, states, n=n, block=block))

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import bitset, components, graph
+from repro.kernels.common import LANES, lane_geometry
 from repro.kernels.mmw import mmw_bounds, mmw_bounds_ref
 
 
@@ -34,8 +35,11 @@ def test_shape_sweep(n):
 
 @pytest.mark.parametrize("block", [1, 3, 8])
 def test_block_sweep_and_padding(block):
-    n = 20
-    reach, states = _case(n, 7, seed=3)        # 7 pads to block multiples
+    """Three grid steps of ``block`` rows of 128 states, the last padded."""
+    n, b = 20, 2 * LANES * block + 37
+    rows, step = lane_geometry(b, block)
+    assert (rows // step, step) == (3, block)
+    reach, states = _case(n, b, seed=3)
     got = np.asarray(mmw_bounds(reach, states, jnp.int32(1000), n=n,
                                 block=block))
     want = np.asarray(mmw_bounds_ref(reach, states, jnp.int32(1000), n))

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import bitset, expand, graph
+from repro.kernels.common import LANES, lane_geometry
 from repro.kernels.wavefront import wavefront_expand, wavefront_ref
 
 
@@ -58,12 +59,16 @@ def test_pruning_flags_match_ref(use_mmw, use_simplicial):
 
 @pytest.mark.parametrize("block", [1, 2, 8])
 def test_block_sweep_and_padding(block):
-    n = 16
-    _, _, adj, states, valid, allowed = _case(n, 5, seed=7)   # 5 pads
+    """``block`` is rows of 128 states per grid step: the batch spans three
+    steps, the last one padded with a partial row and empty rows."""
+    n, b = 16, 2 * LANES * block + 37
+    rows, step = lane_geometry(b, block)
+    assert (rows // step, step) == (3, block)
+    _, _, adj, states, valid, allowed = _case(n, b, seed=7)
     got = wavefront_expand(adj, states, valid, jnp.int32(4), allowed,
                            n=n, block=block)
     want = wavefront_ref(adj, states, valid, jnp.int32(4), allowed, n=n)
-    assert got[0].shape == (5, n, bitset.n_words(n))
+    assert got[0].shape == (b, n, bitset.n_words(n))
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 

@@ -271,3 +271,24 @@ def test_wire_responses_coerce_numpy_payloads():
         assert res2["per_k"]["g"]["expanded"] == 7
     finally:
         srv.close()
+
+
+def test_driver_step_fault_is_counted_in_metrics(server, monkeypatch):
+    """A scheduler step that raises keeps the pool alive (recover and
+    resume) but is counted, so a fault on the chip is visible in
+    ``metrics`` instead of only as a traceback on stderr."""
+    real_step = server.sched.step
+    faults = []
+
+    def failing_step():
+        if not faults:
+            faults.append(1)
+            raise RuntimeError("injected step fault")
+        return real_step()
+
+    monkeypatch.setattr(server.sched, "step", failing_step)
+    c = TwClient(port=server.port)
+    rid = c.submit("petersen")
+    assert c.result(rid)["width"] == 4       # the pool kept serving
+    assert faults
+    assert c.metrics()["pool"]["counters"]["driver_errors"] == 1
