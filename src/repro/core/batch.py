@@ -262,30 +262,38 @@ def decide_lanes_async(lanes: Sequence[Lane], *, cap: Optional[int] = None,
                   for lane in lanes)
     block = engine_lib.validate_geometry(cap, block)
 
-    adj, allowed, ks, targets = _pack_lanes(lanes, n_max, w)
-    fr = frontier_lib.lane_frontiers(len(lanes), cap, w)
-    out_fr, _levels, expanded, dropped = _lanes_decide(
-        jnp.asarray(adj), jnp.asarray(allowed), jnp.asarray(ks),
-        jnp.asarray(targets), fr, n=n_max, cap=cap, block=block, mode=mode,
-        use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
-        schedule=schedule, backend=backend, use_simplicial=use_simplicial)
     tr = telemetry.get(tracker)
+    slots = len(lanes)
+    with tr.span("tw.pack"):
+        adj, allowed, ks, targets = _pack_lanes(lanes, n_max, w)
+        args = (jnp.asarray(adj), jnp.asarray(allowed), jnp.asarray(ks),
+                jnp.asarray(targets),
+                frontier_lib.lane_frontiers(slots, cap, w))
+    with tr.span("tw.enqueue"):
+        out_fr, levels, expanded, dropped = _lanes_decide(
+            *args, n=n_max, cap=cap, block=block, mode=mode,
+            use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
+            schedule=schedule, backend=backend,
+            use_simplicial=use_simplicial)
     tr.count(dispatches=1)
 
     def finalize(host):
-        counts_h, exp_h, drop_h = host
+        counts_h, exp_h, drop_h, lev_h = host
         out = [LaneResult(bool(counts_h[i] > 0), bool(drop_h[i] > 0),
                           int(exp_h[i])) for i in range(live)]
         # per-lane work accounting for the batch layer: how many real
-        # lanes this dispatch decided, the states they expanded, and how
-        # many hit the overflow (inexact) path
-        tr.count(lanes_decided=live,
+        # lanes this dispatch decided out of the lane slots it padded
+        # them to, the states they expanded against the frontier rows
+        # their full-cap buffers held (levels x cap), and how many hit
+        # the overflow (inexact) path
+        tr.count(lanes_decided=live, lane_slots=slots,
                  lane_expanded=sum(r.expanded for r in out),
+                 lane_row_slots=cap * int(np.sum(lev_h[:live])),
                  lane_overflows=sum(1 for r in out if r.inexact))
         return out
 
-    return engine_lib.DispatchHandle((out_fr.count, expanded, dropped),
-                                     finalize, tracker=tr)
+    return engine_lib.DispatchHandle(
+        (out_fr.count, expanded, dropped, levels), finalize, tracker=tr)
 
 
 def decide_lanes(lanes: Sequence[Lane], *, cap: Optional[int] = None,

@@ -22,6 +22,12 @@ against.  This module fuses both loops:
     implementations, ``backend="pallas"`` dispatches the fused wavefront
     kernel that runs the whole expand→prune pipeline in one VMEM pass.
 
+Every level body runs under ``jax.named_scope("tw.level")``, and inside
+it the chunk's expansion, dedup and append under ``tw.expand``,
+``tw.dedup`` and ``tw.append``: the scopes land in each HLO op's
+``op_name`` metadata, so a profiler trace says which part of the level a
+device op (or the fusion it ended up in) belongs to (DESIGN.md §14).
+
 One ``fused_decide`` call therefore issues exactly one dispatch and one
 device→host transfer per k, versus O(levels × chunks) for the host loop.
 The host path survives as ``engine="host"`` (reconstruction needs per-level
@@ -116,8 +122,9 @@ class DispatchHandle:
         """Block for the verdict: one host sync, then cached.  The sync
         and the launch→result wall-clock land on the handle's tracker."""
         if not self._done:
-            host = jax.device_get(self.arrays)
             tr = telemetry.get(self.tracker)
+            with tr.span("tw.wait"):
+                host = jax.device_get(self.arrays)
             tr.count(host_syncs=1)
             tr.timing("dispatch_wall_s", time.perf_counter() - self._t0)
             self._result = self.finalize(host)
@@ -180,27 +187,29 @@ def expand_chunk(adj, states_chunk, chunk_valid, k, out, ocount, dropped,
     the reference implementations in ``core/*``.
     """
     w = adj.shape[-1]
-    children, feas = backend_lib.get_op("wavefront_expand", backend)(
-        adj, states_chunk, chunk_valid, k, allowed, n=n, schedule=schedule,
-        use_mmw=use_mmw, use_simplicial=use_simplicial)
+    with jax.named_scope("tw.expand"):
+        children, feas = backend_lib.get_op("wavefront_expand", backend)(
+            adj, states_chunk, chunk_valid, k, allowed, n=n,
+            schedule=schedule, use_mmw=use_mmw,
+            use_simplicial=use_simplicial)
+        flat = children.reshape(block * n, w)
+        fmask = feas.reshape(block * n)
 
-    flat = children.reshape(block * n, w)
-    fmask = feas.reshape(block * n)
+    with jax.named_scope("tw.dedup"):
+        # intra-chunk exact dedup (paper: mutex-striped atomic inserts)
+        skeys, keep = backend_lib.get_op("sort_dedup", backend)(flat, fmask)
+        if mode == "bloom":
+            keep, filt = backend_lib.get_op("bloom_query_insert", backend)(
+                filt, skeys, keep, m_bits=m_bits, k_hashes=k_hashes)
 
-    # intra-chunk exact dedup (paper: mutex-striped atomic inserts)
-    skeys, keep = backend_lib.get_op("sort_dedup", backend)(flat, fmask)
-
-    if mode == "bloom":
-        keep, filt = backend_lib.get_op("bloom_query_insert", backend)(
-            filt, skeys, keep, m_bits=m_bits, k_hashes=k_hashes)
-
-    pos = ocount + jnp.cumsum(keep.astype(jnp.int32)) - 1
-    write = keep & (pos < cap)
-    out = out.at[jnp.where(write, pos, cap)].set(skeys, mode="drop")
-    n_keep = jnp.sum(keep.astype(jnp.int32))
-    written = jnp.minimum(n_keep, jnp.maximum(0, cap - ocount))
-    dropped = dropped + (n_keep - written)
-    ocount = ocount + written
+    with jax.named_scope("tw.append"):
+        pos = ocount + jnp.cumsum(keep.astype(jnp.int32)) - 1
+        write = keep & (pos < cap)
+        out = out.at[jnp.where(write, pos, cap)].set(skeys, mode="drop")
+        n_keep = jnp.sum(keep.astype(jnp.int32))
+        written = jnp.minimum(n_keep, jnp.maximum(0, cap - ocount))
+        dropped = dropped + (n_keep - written)
+        ocount = ocount + written
     return out, ocount, dropped, filt
 
 
@@ -264,9 +273,10 @@ def chunk_sweep(adj, allowed, k, states, count_, blk, *, n, cap, mode,
         # the full-``cap`` sort is the priciest op in the level, so the
         # gate matters.  Drop-neutral: n_keep <= ocount <= cap, drop2 == 0.
         def _cross_dedup():
-            valid = jnp.arange(cap, dtype=jnp.int32) < ocount
-            buf, written, drop2 = dedup.dedup_compact(out, valid, cap)
-            return buf, written, dropped + drop2
+            with jax.named_scope("tw.dedup"):
+                valid = jnp.arange(cap, dtype=jnp.int32) < ocount
+                buf, written, drop2 = dedup.dedup_compact(out, valid, cap)
+                return buf, written, dropped + drop2
 
         out, ocount, dropped = jax.lax.cond(
             count_ > blk, _cross_dedup, lambda: (out, ocount, dropped))
@@ -330,13 +340,15 @@ def decide_loop(adj, allowed, k, target, fr, *, n, cap, block, mode,
         return (level < target) & (fr.count > 0)
 
     def body(carry):
-        fr, level, expanded, dropped = carry
-        expanded = expanded + fr.count
-        new_fr = _level_step(adj, allowed, k, fr, n=n, cap=cap, block=block,
-                             mode=mode, use_mmw=use_mmw, m_bits=m_bits,
-                             k_hashes=k_hashes, schedule=schedule,
-                             backend=backend, use_simplicial=use_simplicial)
-        return new_fr, level + 1, expanded, dropped + new_fr.dropped
+        with jax.named_scope("tw.level"):
+            fr, level, expanded, dropped = carry
+            expanded = expanded + fr.count
+            new_fr = _level_step(adj, allowed, k, fr, n=n, cap=cap,
+                                 block=block, mode=mode, use_mmw=use_mmw,
+                                 m_bits=m_bits, k_hashes=k_hashes,
+                                 schedule=schedule, backend=backend,
+                                 use_simplicial=use_simplicial)
+            return new_fr, level + 1, expanded, dropped + new_fr.dropped
 
     fr, level, expanded, dropped = jax.lax.while_loop(
         cond, body, (fr, zero, zero, zero))

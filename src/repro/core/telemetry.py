@@ -22,6 +22,10 @@ while the main thread read.  This module replaces that with a tree of
     into ``timings[name] = {calls, total_s, max_s}``; ``timing(name, s)``
     is the direct form for spans measured by hand (e.g. launch→result of
     a ``DispatchHandle``).  Timings roll up like counters.
+  * ``span(name)`` — ``time_block`` that also opens a
+    ``jax.profiler.TraceAnnotation(name)``, so the same span shows on the
+    host timeline of a profiler trace, on the device trace's clock.  The
+    solve path's ``tw.*`` spans (DESIGN.md §14) are made with it.
   * ``child(scope)`` — a sub-scope sharing the tree's single lock.
     ``child`` is idempotent per name; ``drop_child`` detaches a finished
     scope (its contributions remain in the ancestors' totals).
@@ -52,6 +56,8 @@ import sys
 import threading
 import time
 from typing import Any, Dict, IO, Iterator, List, Mapping, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 # ------------------------------------------------------------------ sinks
@@ -134,6 +140,23 @@ class _TimeBlock:
 
     def __exit__(self, *exc: Any) -> None:
         self._tracker.timing(self._name, time.perf_counter() - self._t0)
+
+
+class _Span(_TimeBlock):
+    """Context manager created by ``Tracker.span``: a ``_TimeBlock`` inside
+    a profiler ``TraceAnnotation`` of the same name (a no-op unless a
+    trace is being taken)."""
+
+    __slots__ = ("_ann",)
+
+    def __enter__(self) -> "_Span":
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc: Any) -> None:
+        super().__exit__(*exc)
+        self._ann.__exit__(*exc)
 
 
 class _NullCtx:
@@ -253,6 +276,11 @@ class Tracker:
     def time_block(self, name: str) -> _TimeBlock:
         return _TimeBlock(self, name)
 
+    def span(self, name: str) -> _Span:
+        """``time_block(name)`` that also shows as a host span named
+        ``name`` in a profiler trace."""
+        return _Span(self, name)
+
     # -- reads
 
     def value(self, name: str, default: float = 0) -> float:
@@ -346,6 +374,9 @@ class NullTracker:
         pass
 
     def time_block(self, name: str) -> _NullCtx:
+        return _NULL_CTX
+
+    def span(self, name: str) -> _NullCtx:
         return _NULL_CTX
 
     def value(self, name: str, default: float = 0) -> float:

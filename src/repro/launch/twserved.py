@@ -269,7 +269,7 @@ class TwServer:
                 self._stop.wait(timeout=0.5)    # never a hot error loop
                 continue
             if not stepped:
-                with self._wake:
+                with self.sched.tracker.span("tw.idle"), self._wake:
                     self._wake.wait(timeout=0.2)
         # drain: finish what was admitted before the shutdown request
         try:

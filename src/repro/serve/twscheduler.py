@@ -110,6 +110,7 @@ persistent process and ``repro.serve.client`` for its client)::
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import threading
 import time
@@ -206,6 +207,18 @@ DEFAULT_HEURISTIC_ROUNDS = 16
 # "done" and "timeout" carry a result in ``done[rid]``, "error" carries a
 # message in ``errors[rid]``, "cancelled" carries neither
 TERMINAL_STATES = ("done", "timeout", "cancelled", "error")
+
+
+def _spanned(name: str):
+    """Method decorator: run the method inside ``self.tracker.span(name)``
+    (a pool timing that is also a host span of a profiler trace)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(self, *args, **kwargs):
+            with self.tracker.span(name):
+                return fn(self, *args, **kwargs)
+        return wrapper
+    return deco
 
 
 def _round32(n: int) -> int:
@@ -619,6 +632,7 @@ class TwScheduler:
         kw = self._effective_kw(req)
         return tuple(sorted(kw.items())) + (("cap", req.cap),)
 
+    @_spanned("tw.admit")
     def _start(self, req: SolveRequest):
         """Admission: build the request's deepening state (preprocess +
         bounds + first block plan — host-only work, safe to overlap with
@@ -916,6 +930,7 @@ class TwScheduler:
 
     # ----------------------------------------------------------- the engine
 
+    @_spanned("tw.launch")
     def launch(self) -> bool:
         """Admit, pack every occupied lane's next rung(s), and enqueue
         the dispatches **without waiting for their verdicts** (JAX async
@@ -1036,11 +1051,13 @@ class TwScheduler:
                     # its dispatch count and donation/occupancy stats are
                     # attributable — they land in the request's child
                     # scope and roll up to the pool totals
-                    handle = shard_lib.decide_sharded_async(
-                        run.plan.graph_at(kk), kk, tuple(run.plan.clique),
-                        shards=req.shards, cap=cap, n_pad=self._n_pad,
-                        donate_ratio=self.donate_ratio,
-                        tracker=req.tracker or self.tracker, **kw)
+                    with self.tracker.span("tw.enqueue"):
+                        handle = shard_lib.decide_sharded_async(
+                            run.plan.graph_at(kk), kk,
+                            tuple(run.plan.clique), shards=req.shards,
+                            cap=cap, n_pad=self._n_pad,
+                            donate_ratio=self.donate_ratio,
+                            tracker=req.tracker or self.tracker, **kw)
                     # one-element metas: the handle finalizes to a single
                     # LaneResult, so sync()'s zip feeds it like any lane
                     handles.append((handle, [meta]))
@@ -1050,10 +1067,11 @@ class TwScheduler:
                     # the matching lb contraction runs host-side at apply
                     # time.  Metas are tagged "heur" so sync() routes
                     # them through _apply_improvement, not feed
-                    handle = bounds_engine.ub_orders_async(
-                        [g for _i, _r, _s, _run, g, _sd in heur],
-                        [sd for _i, _r, _s, _run, _g, sd in heur],
-                        tracker=self.tracker)
+                    with self.tracker.span("tw.enqueue"):
+                        handle = bounds_engine.ub_orders_async(
+                            [g for _i, _r, _s, _run, g, _sd in heur],
+                            [sd for _i, _r, _s, _run, _g, sd in heur],
+                            tracker=self.tracker)
                     handles.append((handle,
                                     [("heur", i, req, inst, run, sd)
                                      for i, req, inst, run, _g, sd
@@ -1120,6 +1138,7 @@ class TwScheduler:
             out.append((i, req, inst, run, target, seed))
         return out
 
+    @_spanned("tw.improve")
     def _apply_improvement(self, i: int, req: SolveRequest, inst,
                            run, seed: int, width: int, order: list):
         """Sync-side half of one improver round (under the lock): pair
@@ -1158,6 +1177,7 @@ class TwScheduler:
             self.pool.release(i)
             self._cursor.pop(rid, None)
 
+    @_spanned("tw.poll")
     def poll_admissions(self) -> None:
         """Overlap bookkeeping: admit and plan newly arrived requests
         into free slots while the launched dispatches are still in
@@ -1169,6 +1189,7 @@ class TwScheduler:
             self.pool.admit(self._start)
         self._flush_events()
 
+    @_spanned("tw.sync")
     def sync(self) -> bool:
         """Block for the *oldest* in-flight round's verdicts, feed them
         through each request's ``InstanceState`` in rung order, emit
@@ -1185,7 +1206,7 @@ class TwScheduler:
             no, parts, t_launch = self._rounds.pop(0)
         for handle, metas in parts:
             results = handle.result()          # device wait — no lock held
-            with self._lock:
+            with self.tracker.span("tw.feed"), self._lock:
                 if metas and metas[0][0] == "heur":
                     # improver lanes: apply, don't feed (bounds can move
                     # and rungs can be skipped, but no rung is counted)
@@ -1228,6 +1249,7 @@ class TwScheduler:
         self._flush_events()
         return True
 
+    @_spanned("tw.step")
     def step(self) -> bool:
         """One overlapped scheduler step: launch the next round's shared
         dispatches, run admission/planning for new arrivals while the

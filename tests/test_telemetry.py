@@ -72,6 +72,29 @@ def test_timing_accumulates_and_rolls_up():
         assert t["max_s"] >= 0.5
 
 
+def test_span_rolls_up_like_timing():
+    """``span`` is a ``time_block`` that also opens a profiler annotation:
+    its timing lands under the same name and rolls up the same way."""
+    root = Tracker()
+    child = root.child("c")
+    child.timing("tw.step", 0.5)
+    with child.span("tw.step"):
+        pass
+    for tr in (child, root):
+        t = tr.snapshot()["timings"]["tw.step"]
+        assert t["calls"] == 2
+        assert t["total_s"] >= 0.5
+        assert t["max_s"] >= 0.5
+
+
+def test_span_records_on_exception():
+    tr = Tracker()
+    with pytest.raises(RuntimeError):
+        with tr.span("tw.sync"):
+            raise RuntimeError("boom")
+    assert tr.snapshot()["timings"]["tw.sync"]["calls"] == 1
+
+
 def test_child_is_idempotent_per_name():
     root = Tracker()
     assert root.child("x") is root.child("x")
@@ -277,6 +300,14 @@ def test_null_tracker_is_inert():
         pass
     assert n["a"] == 0 and n.counters() == {}
     assert n.snapshot()["counters"] == {}
+
+
+def test_null_tracker_span_is_the_shared_noop():
+    n = telemetry.NULL
+    assert n.span("tw.step") is n.time_block("tw.step")
+    with n.span("tw.step"):
+        pass
+    assert n.snapshot()["timings"] == {}
 
 
 def test_null_tracker_leaves_solo_solve_counters_unchanged():

@@ -113,3 +113,36 @@ def test_bloom_filter_bound_is_the_vmem_limit(one_chip):
     assert "tpu_custom_call" in _bloom_text(one_chip, PALLAS_BLOOM_MAX_BITS)
     with pytest.raises(Exception, match="vmem"):
         _bloom_text(one_chip, 2 * PALLAS_BLOOM_MAX_BITS)
+
+
+def test_pool_program_keeps_the_kernel_name_under_scopes(one_chip,
+                                                         monkeypatch):
+    """The lane pool's program with the native kernel: the ``tw.*`` named
+    scopes reach the kernel call's op metadata, while the call itself is
+    still named ``wavefront_pallas.<i>`` — the name the benchmark's trace
+    reduction finds the kernel by."""
+    import re
+    from repro.core import batch, frontier
+    from repro.kernels.wavefront import ops as wavefront_ops
+    monkeypatch.setattr(wavefront_ops, "default_interpret", lambda: False)
+    lanes, n, cap = 2, 24, 2048           # shapes no other test traces
+    w = bitset.n_words(n)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fr = frontier.Frontier(spec((lanes, cap, w), jnp.uint32),
+                           spec((lanes,), jnp.int32),
+                           spec((lanes,), jnp.int32))
+    text = batch._lanes_decide.lower(
+        spec((lanes, n, w), jnp.uint32), spec((lanes, w), jnp.uint32),
+        spec((lanes,), jnp.int32), spec((lanes,), jnp.int32), fr, n=n,
+        cap=cap, block=128, mode="sort", use_mmw=False, m_bits=1 << 24,
+        k_hashes=4, schedule="doubling", backend="pallas",
+        use_simplicial=False).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert calls
+    for ln in calls:
+        assert re.match(r"\s*(ROOT )?%wavefront_pallas\.\d+ = ", ln), ln
+        assert "/tw.level/" in ln and "/tw.expand/" in ln
