@@ -41,20 +41,46 @@ def unique_mask(sorted_keys: jnp.ndarray, sorted_valid: jnp.ndarray):
     return first & sorted_valid
 
 
-def compact(rows: jnp.ndarray, keep: jnp.ndarray, cap: int, offset=0):
-    """Scatter kept rows into a (cap, W) buffer starting at ``offset``.
+def compact(rows: jnp.ndarray, keep: jnp.ndarray, out: jnp.ndarray,
+            offset=0):
+    """Write the kept rows of ``rows``, in order, into ``out`` at ``offset``.
 
-    Returns (buffer_update (cap, W), n_kept, n_dropped).  Rows that would land
-    past ``cap`` are dropped (the paper's list-overflow semantics)."""
-    w = rows.shape[-1]
-    pos = jnp.cumsum(keep.astype(jnp.int32)) - 1 + offset
+    Returns (out, n_written, n_dropped).  Kept rows that would land past
+    the buffer are dropped and counted (the paper's list-overflow
+    semantics).  Rows below ``offset`` stay; the ``len(rows)``-row window
+    from ``offset`` is zero after the written rows, as frontier buffers
+    are past their count.
+
+    No per-row index: a sort keyed on the row number (kept rows) or
+    ``len(rows)`` (the rest) moves the kept rows to the front in order,
+    and one ``dynamic_update_slice`` writes them as a window (under
+    ``vmap``, one index per lane).  A window that would run past the
+    buffer starts earlier, with the rows shifted down behind the rows of
+    ``out`` it covers."""
+    m, w = rows.shape
+    cap = out.shape[0]
+    mw = min(m, cap)                      # rows past cap never land
+    offset = jnp.asarray(offset, jnp.int32)
     n_keep = jnp.sum(keep.astype(jnp.int32))
-    idx = jnp.where(keep & (pos < cap), pos, cap)           # cap == drop slot
-    buf = jnp.zeros((cap, w), dtype=U32)
-    buf = buf.at[idx].set(rows, mode="drop")
     written = jnp.minimum(n_keep, jnp.maximum(0, cap - offset))
     dropped = n_keep - written
-    return buf, written, dropped
+
+    key = jnp.where(keep, jnp.arange(m, dtype=jnp.int32), m)
+    cols = jax.lax.sort((key,) + tuple(rows[:, j] for j in range(w)),
+                        dimension=0, num_keys=1, is_stable=False)
+    packed = jnp.where((cols[0][:mw] < m)[:, None],
+                       jnp.stack([c[:mw] for c in cols[1:]], axis=1), 0)
+
+    start = jnp.clip(offset, 0, cap - mw)
+    shift = jnp.clip(offset - start, 0, mw)
+    padded = jnp.concatenate([jnp.zeros((mw, w), out.dtype),
+                              packed.astype(out.dtype)])
+    shifted = jax.lax.dynamic_slice(padded, (mw - shift, 0), (mw, w))
+    old = jax.lax.dynamic_slice(out, (start, 0), (mw, w))
+    below = (jnp.arange(mw, dtype=jnp.int32) < shift)[:, None]
+    window = jnp.where(below, old, shifted)
+    out = jax.lax.dynamic_update_slice(out, window, (start, 0))
+    return out, written, dropped
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
@@ -64,5 +90,5 @@ def dedup_compact(keys: jnp.ndarray, valid: jnp.ndarray, cap: int):
     Returns (buffer, count, dropped)."""
     sk, sv = sort_states(keys, valid)
     keep = unique_mask(sk, sv)
-    buf, written, dropped = compact(sk, keep, cap)
-    return buf, written, dropped
+    w = keys.shape[-1]
+    return compact(sk, keep, jnp.zeros((cap, w), dtype=U32))

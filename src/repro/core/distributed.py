@@ -129,9 +129,7 @@ def _donate(buf, cnt, counts_all, me, *, ndev, cap_local, cap_send, w,
     rval = j < rcnt[eidx]
     mask_keep = jnp.arange(cap_local, dtype=jnp.int32) < keep
     buf = jnp.where(mask_keep[:, None], buf, 0)
-    pos = keep + jnp.cumsum(rval.astype(jnp.int32)) - 1
-    dest = jnp.where(rval, pos, cap_local)
-    buf = buf.at[dest].set(rrows, mode="drop")
+    buf, _, _ = dedup.compact(rrows, rval, buf, keep)
     cnt = keep + jnp.sum(rcnt)
 
     stats = jnp.stack([trig.astype(jnp.int32), jnp.sum(t_mat),

@@ -171,7 +171,7 @@ def validate_geometry(cap: int, block: int, *, adaptive: bool = False) -> int:
 # ------------------------------------------------------------- chunk kernel
 
 def expand_chunk(adj, states_chunk, chunk_valid, k, out, ocount, dropped,
-                 filt, allowed, *, n, cap, block, mode, use_mmw, m_bits,
+                 filt, allowed, *, n, block, mode, use_mmw, m_bits,
                  k_hashes, schedule, backend, use_simplicial=False):
     """Expand one chunk of states and append deduped children to ``out``.
 
@@ -203,12 +203,8 @@ def expand_chunk(adj, states_chunk, chunk_valid, k, out, ocount, dropped,
                 filt, skeys, keep, m_bits=m_bits, k_hashes=k_hashes)
 
     with jax.named_scope("tw.append"):
-        pos = ocount + jnp.cumsum(keep.astype(jnp.int32)) - 1
-        write = keep & (pos < cap)
-        out = out.at[jnp.where(write, pos, cap)].set(skeys, mode="drop")
-        n_keep = jnp.sum(keep.astype(jnp.int32))
-        written = jnp.minimum(n_keep, jnp.maximum(0, cap - ocount))
-        dropped = dropped + (n_keep - written)
+        out, written, drop = dedup.compact(skeys, keep, out, ocount)
+        dropped = dropped + drop
         ocount = ocount + written
     return out, ocount, dropped, filt
 
@@ -259,7 +255,7 @@ def chunk_sweep(adj, allowed, k, states, count_, blk, *, n, cap, mode,
         chunk_valid = (jnp.arange(blk, dtype=jnp.int32) + lo) < count_
         out, ocount, dropped, filt = expand_chunk(
             adj, states_chunk, chunk_valid, k, out, ocount, dropped, filt,
-            allowed, n=n, cap=cap, block=blk, mode=mode, use_mmw=use_mmw,
+            allowed, n=n, block=blk, mode=mode, use_mmw=use_mmw,
             m_bits=m_bits, k_hashes=k_hashes, schedule=schedule,
             backend=backend, use_simplicial=use_simplicial)
         return ci + 1, out, ocount, dropped, filt

@@ -195,7 +195,8 @@ def sharded_decide_loop(adj, allowed, k, target, fr, *, shards, n, cap,
             # filter shard, and only rows it owns are probed against it
             keep, filt = query_insert(filt, rows, keep, m_bits=m_bits,
                                       k_hashes=k_hashes)
-            buf, written, _ = dedup.compact(rows, keep, cap)
+            buf, written, _ = dedup.compact(
+                rows, keep, jnp.zeros((cap, w), dtype=U32))
             return buf, written, filt
 
     def cond(c):

@@ -44,12 +44,12 @@ U32 = jnp.uint32
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n", "cap", "block", "mode", "use_mmw", "m_bits",
+    static_argnames=("n", "block", "mode", "use_mmw", "m_bits",
                      "k_hashes", "schedule", "backend", "use_simplicial"),
     donate_argnums=(4, 7),
 )
 def _chunk_step(adj, states_chunk, chunk_valid, k, out, ocount, dropped,
-                filt, allowed, *, n, cap, block, mode, use_mmw, m_bits,
+                filt, allowed, *, n, block, mode, use_mmw, m_bits,
                 k_hashes, schedule, backend, use_simplicial=False):
     """Expand one chunk of states and append deduped children to ``out``.
 
@@ -58,7 +58,7 @@ def _chunk_step(adj, states_chunk, chunk_valid, k, out, ocount, dropped,
     device-resident engine and the distributed solver)."""
     return engine_lib.expand_chunk(
         adj, states_chunk, chunk_valid, k, out, ocount, dropped, filt,
-        allowed, n=n, cap=cap, block=block, mode=mode, use_mmw=use_mmw,
+        allowed, n=n, block=block, mode=mode, use_mmw=use_mmw,
         m_bits=m_bits, k_hashes=k_hashes, schedule=schedule,
         backend=backend, use_simplicial=use_simplicial)
 
@@ -121,7 +121,7 @@ def run_level(adj_dev, fr: frontier_lib.Frontier, k: int, allowed_dev,
         chunk_valid = (jnp.arange(block, dtype=jnp.int32) + lo) < fr.count
         out, ocount, dropped, filt = _chunk_step(
             adj_dev, states_chunk, chunk_valid, kdev, out, ocount, dropped,
-            filt, allowed_dev, n=n, cap=cap, block=block, mode=mode,
+            filt, allowed_dev, n=n, block=block, mode=mode,
             use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
             schedule=schedule, backend=backend,
             use_simplicial=use_simplicial)

@@ -13,6 +13,7 @@ here).
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -146,3 +147,58 @@ def test_pool_program_keeps_the_kernel_name_under_scopes(one_chip,
     for ln in calls:
         assert re.match(r"\s*(ROOT )?%wavefront_pallas\.\d+ = ", ln), ln
         assert "/tw.level/" in ln and "/tw.expand/" in ln
+
+
+def _index_shapes(text):
+    """{(op, index operand shape)} of every scatter and gather in a
+    compiled program's text."""
+    import re
+    shapes = dict(re.findall(r"%(\S+) = (\w+\[[\d,]*\])", text))
+    return {(op, shapes[idx]) for op, idx in re.findall(
+        r"= \S+ (scatter|gather)\(%[^,]+, %([^,)]+)", text)}
+
+
+@pytest.mark.parametrize("chip", [False, True], ids=["cpu", "v5e"])
+def test_lane_program_has_no_per_row_scatter(request, monkeypatch, chip):
+    """The lane pool's program compacts rows by sort and window write:
+    the scatters and gathers left in it take one index per lane, so
+    their index shapes stay the same as ``block`` and ``cap`` grow.  A
+    row-wise ``.at[idx].set`` in the append or the dedup would bring an
+    index per row back.  On the CPU the program composes the jax ops (a
+    separate trace from the chip's: the kernel's mode is read while
+    tracing), for the described chip it holds the native kernel."""
+    from repro.core import batch, frontier
+    sharding, backend = None, "jax"
+    if chip:
+        backend = "pallas"
+        from repro.kernels.wavefront import ops as wavefront_ops
+        sharding = request.getfixturevalue("one_chip")
+        monkeypatch.setattr(wavefront_ops, "default_interpret",
+                            lambda: False)
+    lanes, n = 2, 24
+    w = bitset.n_words(n)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def index_shapes(cap, block):
+        fr = frontier.Frontier(spec((lanes, cap, w), jnp.uint32),
+                               spec((lanes,), jnp.int32),
+                               spec((lanes,), jnp.int32))
+        text = batch._lanes_decide.lower(
+            spec((lanes, n, w), jnp.uint32), spec((lanes, w), jnp.uint32),
+            spec((lanes,), jnp.int32), spec((lanes,), jnp.int32), fr, n=n,
+            cap=cap, block=block, mode="sort", use_mmw=False,
+            m_bits=1 << 24, k_hashes=4, schedule="doubling",
+            backend=backend, use_simplicial=False).compile().as_text()
+        return _index_shapes(text)
+
+    sizes = [(2048, 256), (4096, 256), (4096, 512)]      # (cap, block)
+    found = [index_shapes(cap, block) for cap, block in sizes]
+    assert found[0], "the lane program lost its window slices"
+    for got, (cap, block) in zip(found[1:], sizes[1:]):
+        assert got == found[0], (cap, block, got, found[0])
+    for _op, shape in found[0]:
+        dims = [int(d) for d in shape[shape.index("[") + 1:-1].split(",")
+                if d]
+        assert np.prod(dims) < 256, shape       # per lane, not per row
