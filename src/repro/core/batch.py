@@ -159,6 +159,9 @@ def _pow2_floor(x: int) -> int:
     return p
 
 
+LANE_AXIS = "lanes"
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n", "cap", "block", "mode", "use_mmw", "m_bits",
@@ -169,14 +172,17 @@ def _lanes_decide(adj, allowed, k, target, fr, *, n, cap, block, mode,
     """``engine.decide_loop`` vmapped over the leading lane axis.
 
     adj (B, n, W) / allowed (B, W) / k, target (B,) / fr with lane-leading
-    leaves.  One compiled program, one launch, B verdicts."""
+    leaves.  One compiled program, one launch, B verdicts.  The axis is
+    named (``LANE_AXIS``) so that the mid-level refill's predicate is
+    one value for every lane (``engine.refill``)."""
     def one_lane(a, al, kk, tt, f):
         return engine_lib.decide_loop(
             a, al, kk, tt, f, n=n, cap=cap, block=block, mode=mode,
             use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
             schedule=schedule, backend=backend,
-            use_simplicial=use_simplicial)
-    return jax.vmap(one_lane)(adj, allowed, k, target, fr)
+            use_simplicial=use_simplicial, lane_axis=LANE_AXIS)
+    return jax.vmap(one_lane, axis_name=LANE_AXIS)(adj, allowed, k, target,
+                                                   fr)
 
 
 def _pack_lanes(lanes: Sequence[Lane], n_max: int, w: int):
@@ -270,7 +276,7 @@ def decide_lanes_async(lanes: Sequence[Lane], *, cap: Optional[int] = None,
                 jnp.asarray(targets),
                 frontier_lib.lane_frontiers(slots, cap, w))
     with tr.span("tw.enqueue"):
-        out_fr, levels, expanded, dropped = _lanes_decide(
+        out_fr, levels, expanded, dropped, refills, appended = _lanes_decide(
             *args, n=n_max, cap=cap, block=block, mode=mode,
             use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
             schedule=schedule, backend=backend,
@@ -278,22 +284,27 @@ def decide_lanes_async(lanes: Sequence[Lane], *, cap: Optional[int] = None,
     tr.count(dispatches=1)
 
     def finalize(host):
-        counts_h, exp_h, drop_h, lev_h = host
+        counts_h, exp_h, drop_h, lev_h, ref_h, app_h = host
         out = [LaneResult(bool(counts_h[i] > 0), bool(drop_h[i] > 0),
                           int(exp_h[i])) for i in range(live)]
         # per-lane work accounting for the batch layer: how many real
         # lanes this dispatch decided out of the lane slots it padded
         # them to, the states they expanded against the frontier rows
-        # their full-cap buffers held (levels x cap), and how many hit
-        # the overflow (inexact) path
+        # their full-cap buffers held (levels x cap), the levels they
+        # ran, the mid-level refills and appended rows of those levels,
+        # and how many hit the overflow (inexact) path
+        lane_levels = int(np.sum(lev_h[:live]))
         tr.count(lanes_decided=live, lane_slots=slots,
                  lane_expanded=sum(r.expanded for r in out),
-                 lane_row_slots=cap * int(np.sum(lev_h[:live])),
+                 lane_row_slots=cap * lane_levels, lane_levels=lane_levels,
+                 refills=int(np.sum(ref_h[:live])),
+                 appended_rows=int(np.sum(app_h[:live])),
                  lane_overflows=sum(1 for r in out if r.inexact))
         return out
 
     return engine_lib.DispatchHandle(
-        (out_fr.count, expanded, dropped, levels), finalize, tracker=tr)
+        (out_fr.count, expanded, dropped, levels, refills, appended),
+        finalize, tracker=tr)
 
 
 def decide_lanes(lanes: Sequence[Lane], *, cap: Optional[int] = None,

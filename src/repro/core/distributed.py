@@ -73,7 +73,7 @@ def _local_expand(adj, states, count, k, allowed, *, n, cap_local, block,
         adj, allowed, k, states, count, block, n=n, cap=cap_local,
         mode="sort", use_mmw=use_mmw, m_bits=1, k_hashes=1,
         schedule=schedule, backend=backend, use_simplicial=use_simplicial,
-        max_chunks=-(-cap_local // block), cross_dedup=False)
+        max_chunks=-(-cap_local // block), cross_dedup=False)[:3]
 
 
 def _build_buckets(rows, count, ndev, cap_send, w):

@@ -24,9 +24,10 @@ against.  This module fuses both loops:
 
 Every level body runs under ``jax.named_scope("tw.level")``, and inside
 it the chunk's expansion, dedup and append under ``tw.expand``,
-``tw.dedup`` and ``tw.append``: the scopes land in each HLO op's
-``op_name`` metadata, so a profiler trace says which part of the level a
-device op (or the fusion it ended up in) belongs to (DESIGN.md §14).
+``tw.dedup`` and ``tw.append``, and a mid-level refill under
+``tw.refill``: the scopes land in each HLO op's ``op_name`` metadata, so
+a profiler trace says which part of the level a device op (or the fusion
+it ended up in) belongs to (DESIGN.md §14).
 
 One ``fused_decide`` call therefore issues exactly one dispatch and one
 device→host transfer per k, versus O(levels × chunks) for the host loop.
@@ -209,6 +210,50 @@ def expand_chunk(adj, states_chunk, chunk_valid, k, out, ocount, dropped,
     return out, ocount, dropped, filt
 
 
+def refill_needed(ocount, live_rows, allowed, cap: int):
+    """Could the children of a chunk's ``live_rows`` states overflow a
+    buffer that holds ``ocount`` rows?  A state has at most one child per
+    candidate vertex (``allowed``).  A refill of an empty buffer would
+    change nothing."""
+    cands = jnp.sum(jax.lax.population_count(allowed)).astype(jnp.int32)
+    return (ocount > 0) & (ocount + live_rows * cands > cap)
+
+
+def any_lane(flag, lane_axis=None):
+    """``flag`` of some lane of the ``vmap`` named ``lane_axis``: one value
+    for every lane (``flag`` itself without a lane axis)."""
+    if lane_axis is None:
+        return flag
+    return jax.lax.pmax(flag.astype(jnp.int32), lane_axis) > 0
+
+
+def refill(out, ocount, need, *, lane_axis=None):
+    """Sort-dedup and compact the ``ocount`` rows of ``out`` in place,
+    when ``need``: the mid-level refill that keeps a level's append
+    stream within one buffer (DESIGN.md §2).  Returns (out, ocount,
+    refilled).
+
+    Drop-neutral (the distinct rows of a buffer fit in it), and it only
+    reorders a set that the level's cross-chunk dedup sorts anyway, so a
+    refill leaves the level's frontier as it was.  Under the lane
+    ``vmap`` (``lane_axis`` names its axis) the predicate is the max over
+    the lanes: a ``cond`` on a per-lane value would become a ``select``
+    that sorts every lane's whole buffer on every chunk, while a
+    lane-uniform one stays a conditional taken only when some lane
+    needs it."""
+    need = any_lane(need, lane_axis)
+
+    def _refill():
+        with jax.named_scope("tw.refill"):
+            cap = out.shape[0]
+            valid = jnp.arange(cap, dtype=jnp.int32) < ocount
+            buf, count, _ = dedup.dedup_compact(out, valid, cap)
+            return buf, count
+
+    out, ocount = jax.lax.cond(need, _refill, lambda: (out, ocount))
+    return out, ocount, need.astype(jnp.int32)
+
+
 # ------------------------------------------------------------- fused level
 
 # below this frontier size a level runs as one narrow chunk instead of a
@@ -220,14 +265,24 @@ SMALL_BLOCK = 128
 
 def chunk_sweep(adj, allowed, k, states, count_, blk, *, n, cap, mode,
                 use_mmw, m_bits, k_hashes, schedule, backend,
-                use_simplicial, max_chunks=None, cross_dedup=True):
+                use_simplicial, max_chunks=None, cross_dedup=True,
+                lane_axis=None):
     """Expand ``count_`` rows of ``states`` in ``blk``-row chunks, on device.
 
     The data-dependent chunk loop shared by the fused level step and the
     distributed per-device expansion (which passes ``cross_dedup=False`` —
     its cross-chunk dedup happens at the owner after routing — and a
     ``max_chunks`` bound from its local capacity).  Returns
-    (out, ocount, dropped).
+    (out, ocount, dropped, refills, appended): the last two count the
+    mid-level refills and the rows the chunks appended.
+
+    With exact cross-chunk dedup (sort mode, ``cross_dedup``), a chunk
+    whose children could overflow the buffer is preceded by a
+    ``refill``, so the buffer holds the level's distinct rows plus one
+    chunk's children rather than its whole append stream.  ``lane_axis``
+    names the lane ``vmap``'s axis: the chunk loop stops for every lane
+    before a chunk that could overflow some lane's buffer, and the
+    refill, lane-uniform, runs between chunk loops.
 
     Lane-aware by construction: nothing here reads the true vertex count —
     ``n`` only sizes the (static) candidate axis, while which vertices
@@ -241,29 +296,56 @@ def chunk_sweep(adj, allowed, k, states, count_, blk, *, n, cap, mode,
     out = jnp.zeros((cap, w), dtype=U32)
     filt = backend_lib.get_op("bloom_make_filter", backend)(
         m_bits if mode == "bloom" else None)
+    exact_cross = mode == "sort" and cross_dedup
 
-    def chunk_cond(c):
+    def live(c):
         more = c[0] * blk < count_
         if max_chunks is not None:
             more = more & (c[0] < max_chunks)
         return more
 
     def chunk_body(c):
-        ci, out, ocount, dropped, filt = c
+        ci, out, ocount, dropped, filt, refills, appended, _fresh = c
         lo = ci * blk
         states_chunk = jax.lax.dynamic_slice(states, (lo, zero), (blk, w))
         chunk_valid = (jnp.arange(blk, dtype=jnp.int32) + lo) < count_
+        before = ocount
         out, ocount, dropped, filt = expand_chunk(
             adj, states_chunk, chunk_valid, k, out, ocount, dropped, filt,
             allowed, n=n, block=blk, mode=mode, use_mmw=use_mmw,
             m_bits=m_bits, k_hashes=k_hashes, schedule=schedule,
             backend=backend, use_simplicial=use_simplicial)
-        return ci + 1, out, ocount, dropped, filt
+        return (ci + 1, out, ocount, dropped, filt, refills,
+                appended + ocount - before, jnp.asarray(False))
 
-    _, out, ocount, dropped, _ = jax.lax.while_loop(
-        chunk_cond, chunk_body, (zero, out, zero, zero, filt))
+    carry = (zero, out, zero, zero, filt, zero, zero, jnp.asarray(False))
+    if not exact_cross:
+        carry = jax.lax.while_loop(live, chunk_body, carry)
+    else:
+        # The chunk loop stops before a chunk that could overflow some
+        # lane's buffer, and the refill runs between chunk loops: a
+        # conditional inside the chunk loop would keep the loop's buffers
+        # out of the chip's fast memory.  ``fresh`` lets a just-refilled
+        # buffer take its next chunk even if it could still overflow.
+        def needs_refill(c):
+            rows = jnp.clip(count_ - c[0] * blk, 0, blk)
+            return live(c) & refill_needed(c[2], rows, allowed, cap)
 
-    if mode == "sort" and cross_dedup:
+        def chunk_cond(c):
+            return live(c) & (c[7] | ~any_lane(needs_refill(c), lane_axis))
+
+        def segment(c):
+            c = jax.lax.while_loop(chunk_cond, chunk_body, c)
+            ci, out, ocount, dropped, filt, refills, appended, _ = c
+            out, ocount, did = refill(out, ocount, needs_refill(c),
+                                      lane_axis=lane_axis)
+            return (ci, out, ocount, dropped, filt, refills + did, appended,
+                    did > 0)
+
+        carry = jax.lax.while_loop(live, segment, carry)
+    _, out, ocount, dropped, _, refills, appended, _ = carry
+
+    if exact_cross:
         # cross-chunk exact dedup, only when the level actually spanned
         # multiple chunks (single-chunk output is already sorted-unique);
         # the full-``cap`` sort is the priciest op in the level, so the
@@ -276,11 +358,12 @@ def chunk_sweep(adj, allowed, k, states, count_, blk, *, n, cap, mode,
 
         out, ocount, dropped = jax.lax.cond(
             count_ > blk, _cross_dedup, lambda: (out, ocount, dropped))
-    return out, ocount, dropped
+    return out, ocount, dropped, refills, appended
 
 
 def _level_step(adj, allowed, k, fr, *, n, cap, block, mode, use_mmw,
-                m_bits, k_hashes, schedule, backend, use_simplicial):
+                m_bits, k_hashes, schedule, backend, use_simplicial,
+                lane_axis=None, active=True):
     """One wavefront level, fully on device.  Traced inside the while body.
 
     Chunk trip count is ``ceil(count / block)`` with the count read from the
@@ -288,67 +371,81 @@ def _level_step(adj, allowed, k, fr, *, n, cap, block, mode, use_mmw,
     for one chunk, not ``cap / block``.  Levels whose whole frontier fits in
     ``SMALL_BLOCK`` rows take a narrow single-chunk branch instead
     (``lax.cond`` — both branches compiled once, runtime picks per level).
+    Returns (frontier, refills, appended).
+
+    Under the lane ``vmap`` the ``cond`` is a ``select`` that runs both
+    sweeps, and a finished lane (``active`` false) still runs the level
+    body: each sweep is therefore given only the rows of the lanes that
+    take it, so the narrow sweep walks at most one chunk and a finished
+    lane none.  The results it discards are all it changes.
     """
     small = min(block, SMALL_BLOCK)
-    count_ = fr.count
+    count_ = jnp.where(active, fr.count, 0)
     kwargs = dict(n=n, cap=cap, mode=mode, use_mmw=use_mmw, m_bits=m_bits,
                   k_hashes=k_hashes, schedule=schedule, backend=backend,
-                  use_simplicial=use_simplicial)
+                  use_simplicial=use_simplicial, lane_axis=lane_axis)
 
     if small == block:
-        out, ocount, dropped = chunk_sweep(adj, allowed, k, fr.states,
-                                           count_, block, **kwargs)
+        out, ocount, dropped, refills, appended = chunk_sweep(
+            adj, allowed, k, fr.states, count_, block, **kwargs)
     else:
-        out, ocount, dropped = jax.lax.cond(
-            count_ <= small,
-            lambda: chunk_sweep(adj, allowed, k, fr.states, count_, small,
+        narrow = count_ <= small
+        out, ocount, dropped, refills, appended = jax.lax.cond(
+            narrow,
+            lambda: chunk_sweep(adj, allowed, k, fr.states,
+                                jnp.where(narrow, count_, 0), small,
                                 **kwargs),
-            lambda: chunk_sweep(adj, allowed, k, fr.states, count_, block,
+            lambda: chunk_sweep(adj, allowed, k, fr.states,
+                                jnp.where(narrow, 0, count_), block,
                                 **kwargs))
 
-    return frontier_lib.Frontier(out, ocount.astype(jnp.int32),
-                                 dropped.astype(jnp.int32))
+    return (frontier_lib.Frontier(out, ocount.astype(jnp.int32),
+                                  dropped.astype(jnp.int32)),
+            refills, appended)
 
 
 def decide_loop(adj, allowed, k, target, fr, *, n, cap, block, mode,
                 use_mmw, m_bits, k_hashes, schedule, backend,
-                use_simplicial):
+                use_simplicial, lane_axis=None):
     """Run up to ``target`` wavefront levels; stop early on emptiness.
 
-    Returns (frontier, levels_run, expanded, dropped_total) — all on
-    device.  Feasibility is ``frontier.count > 0`` (the loop only stops
-    short of ``target`` when a level produced no states).
+    Returns (frontier, levels_run, expanded, dropped_total, refills,
+    appended) — all on device.  Feasibility is ``frontier.count > 0``
+    (the loop only stops short of ``target`` when a level produced no
+    states); ``refills`` and ``appended`` count the mid-level refills and
+    the rows the levels appended (``chunk_sweep``).
 
     Undecorated on purpose: ``fused_decide`` jits it for the single-lane
     path, and the multi-lane engine (``core.batch``) vmaps it over a
-    leading lane axis.  Under vmap the two data-dependent ``while_loop``s
-    become masked loops — a lane whose condition goes false has its carry
-    frozen by the batching rule's ``select`` while other lanes keep
-    stepping, which is exactly the per-lane early exit the batched engine
-    needs (and why batched results stay bit-identical per lane).  ``n`` is
-    the (static) padded lane width; a lane's true vertex count is carried
-    dynamically by its ``allowed`` mask and ``target``.
+    leading lane axis, named by ``lane_axis``.  Under vmap the two
+    data-dependent ``while_loop``s become masked loops — a lane whose
+    condition goes false has its carry frozen by the batching rule's
+    ``select`` while other lanes keep stepping, which is exactly the
+    per-lane early exit the batched engine needs (and why batched results
+    stay bit-identical per lane).  ``n`` is the (static) padded lane
+    width; a lane's true vertex count is carried dynamically by its
+    ``allowed`` mask and ``target``.
     """
     zero = jnp.asarray(0, jnp.int32)
 
     def cond(carry):
-        fr, level, _expanded, _dropped = carry
+        fr, level = carry[:2]
         return (level < target) & (fr.count > 0)
 
     def body(carry):
         with jax.named_scope("tw.level"):
-            fr, level, expanded, dropped = carry
+            fr, level, expanded, dropped, refills, appended = carry
             expanded = expanded + fr.count
-            new_fr = _level_step(adj, allowed, k, fr, n=n, cap=cap,
-                                 block=block, mode=mode, use_mmw=use_mmw,
-                                 m_bits=m_bits, k_hashes=k_hashes,
-                                 schedule=schedule, backend=backend,
-                                 use_simplicial=use_simplicial)
-            return new_fr, level + 1, expanded, dropped + new_fr.dropped
+            new_fr, r, a = _level_step(
+                adj, allowed, k, fr, n=n, cap=cap, block=block, mode=mode,
+                use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
+                schedule=schedule, backend=backend,
+                use_simplicial=use_simplicial, lane_axis=lane_axis,
+                active=cond(carry))
+            return (new_fr, level + 1, expanded, dropped + new_fr.dropped,
+                    refills + r, appended + a)
 
-    fr, level, expanded, dropped = jax.lax.while_loop(
-        cond, body, (fr, zero, zero, zero))
-    return fr, level, expanded, dropped
+    return jax.lax.while_loop(cond, body, (fr,) + (zero,) * 5)
 
 
 _fused_decide = functools.partial(
@@ -382,7 +479,7 @@ def fused_decide_launch(adj_dev, allowed_dev, k: int, target, *, n, cap,
     kdev = jnp.asarray(k, dtype=jnp.int32)
     tdev = jnp.asarray(levels, dtype=jnp.int32)
 
-    fr, level, expanded, dropped = _fused_decide(
+    fr, level, expanded, dropped, refills, appended = _fused_decide(
         adj_dev, allowed_dev, kdev, tdev, fr, n=n, cap=cap, block=block,
         mode=mode, use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
         schedule=schedule, backend=backend, use_simplicial=use_simplicial)
@@ -390,16 +487,19 @@ def fused_decide_launch(adj_dev, allowed_dev, k: int, target, *, n, cap,
     tr.count(dispatches=1)
 
     def finalize(host):
-        states_h, count_h, expanded_h, dropped_h = host
+        states_h, count_h, expanded_h, dropped_h, level_h, refills_h, \
+            appended_h = host
         feasible = int(count_h) > 0
         inexact = int(dropped_h) > 0
         fr_host = frontier_lib.Frontier(np.asarray(states_h),
                                         np.asarray(count_h),
                                         np.asarray(dropped_h))
+        tr.count(refills=int(refills_h), appended_rows=int(appended_h),
+                 lane_levels=int(level_h))
         return feasible, inexact, int(expanded_h), fr_host
 
-    return DispatchHandle((fr.states, fr.count, expanded, dropped),
-                          finalize, tracker=tr)
+    return DispatchHandle((fr.states, fr.count, expanded, dropped, level,
+                           refills, appended), finalize, tracker=tr)
 
 
 def fused_decide(adj_dev, allowed_dev, k: int, target, *, n, cap, block,

@@ -182,7 +182,7 @@ def sharded_decide_loop(adj, allowed, k, target, fr, *, shards, n, cap,
             adj, allowed, k, st, c, block, n=n, cap=cap, mode="sort",
             use_mmw=use_mmw, m_bits=1, k_hashes=1, schedule=schedule,
             backend=backend, use_simplicial=use_simplicial,
-            max_chunks=max_chunks, cross_dedup=False)
+            max_chunks=max_chunks, cross_dedup=False)[:3]
 
     def owner_dedup(rows, rvalid):
         return dedup.dedup_compact(rows, rvalid, cap)

@@ -49,18 +49,29 @@ U32 = jnp.uint32
     donate_argnums=(4, 7),
 )
 def _chunk_step(adj, states_chunk, chunk_valid, k, out, ocount, dropped,
-                filt, allowed, *, n, block, mode, use_mmw, m_bits,
-                k_hashes, schedule, backend, use_simplicial=False):
+                filt, allowed, refills, appended, *, n, block, mode,
+                use_mmw, m_bits, k_hashes, schedule, backend,
+                use_simplicial=False):
     """Expand one chunk of states and append deduped children to ``out``.
 
     Thin jitted wrapper over ``engine.expand_chunk`` — the single shared
     implementation of the Listing-1 inner loop (also used by the fused
-    device-resident engine and the distributed solver)."""
-    return engine_lib.expand_chunk(
+    device-resident engine and the distributed solver) — behind the
+    fused sweep's refill rule in sort mode (``engine.refill``), with its
+    ``refills`` and ``appended`` rows counted on device."""
+    if mode == "sort":
+        live_rows = jnp.sum(chunk_valid.astype(jnp.int32))
+        out, ocount, did = engine_lib.refill(
+            out, ocount, engine_lib.refill_needed(ocount, live_rows, allowed,
+                                                  out.shape[0]))
+        refills = refills + did
+    before = ocount
+    out, ocount, dropped, filt = engine_lib.expand_chunk(
         adj, states_chunk, chunk_valid, k, out, ocount, dropped, filt,
         allowed, n=n, block=block, mode=mode, use_mmw=use_mmw,
         m_bits=m_bits, k_hashes=k_hashes, schedule=schedule,
         backend=backend, use_simplicial=use_simplicial)
+    return out, ocount, dropped, filt, refills, appended + ocount - before
 
 
 @functools.partial(jax.jit, static_argnames=("cap",), donate_argnums=(0,))
@@ -108,8 +119,7 @@ def run_level(adj_dev, fr: frontier_lib.Frontier, k: int, allowed_dev,
         # would silently re-expand earlier rows with the wrong valid mask
         raise ValueError(f"block ({block}) must divide cap ({cap})")
     out = jnp.zeros((cap, w), dtype=U32)
-    ocount = jnp.asarray(0, dtype=jnp.int32)
-    dropped = jnp.asarray(0, dtype=jnp.int32)
+    ocount = dropped = refills = appended = jnp.asarray(0, dtype=jnp.int32)
     filt = backend_lib.get_op("bloom_make_filter", backend)(
         m_bits if mode == "bloom" else None)
     kdev = jnp.asarray(k, dtype=jnp.int32)
@@ -119,9 +129,9 @@ def run_level(adj_dev, fr: frontier_lib.Frontier, k: int, allowed_dev,
         lo = c * block
         states_chunk = jax.lax.dynamic_slice(fr.states, (lo, 0), (block, w))
         chunk_valid = (jnp.arange(block, dtype=jnp.int32) + lo) < fr.count
-        out, ocount, dropped, filt = _chunk_step(
+        out, ocount, dropped, filt, refills, appended = _chunk_step(
             adj_dev, states_chunk, chunk_valid, kdev, out, ocount, dropped,
-            filt, allowed_dev, n=n, block=block, mode=mode,
+            filt, allowed_dev, refills, appended, n=n, block=block, mode=mode,
             use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
             schedule=schedule, backend=backend,
             use_simplicial=use_simplicial)
@@ -134,9 +144,12 @@ def run_level(adj_dev, fr: frontier_lib.Frontier, k: int, allowed_dev,
         tr.count(dispatches=1)
 
     new_fr = frontier_lib.Frontier(out, ocount, dropped)
-    stats = LevelStats(expanded=count, generated=int(ocount),
-                       dropped=int(dropped))
-    tr.count(host_syncs=2)
+    generated, dropped_h, refills_h, appended_h = (
+        int(x) for x in jax.device_get((ocount, dropped, refills, appended)))
+    stats = LevelStats(expanded=count, generated=generated,
+                       dropped=dropped_h)
+    tr.count(host_syncs=2, refills=refills_h, appended_rows=appended_h,
+             lane_levels=1)
     # occupancy vs the planned capacity: how full the frontier buffer
     # actually got (the host loop sees every level, so this is the true
     # per-level peak; compare against the ``frontier_cap`` gauge)

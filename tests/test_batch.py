@@ -97,6 +97,43 @@ def test_decide_lanes_cross_n_padding():
             (ref.feasible, ref.inexact, ref.expanded), (lane.g.name, lane.k)
 
 
+@pytest.mark.parametrize("k0,cap", [(4, 1 << 12), (5, 1 << 16)],
+                         ids=["desargues-drops", "no-drops"])
+def test_lanes_refill_at_different_levels_bit_identical(k0, cap):
+    """Lanes whose buffers fill at different levels, or not at all, under
+    one lane-uniform refill: each lane's frontier, counts and drops are
+    bit-identical to its single-lane run, though the pool refills a lane
+    whenever another lane needs it.  At the smaller cap the first lane's
+    widest levels overflow, so the identity holds with drops too."""
+    from repro.core import frontier
+    lanes = [batch.Lane(graph.REGISTRY["desargues"](), k0),
+             batch.Lane(graph.REGISTRY["desargues"](), k0 - 1),
+             batch.Lane(graph.petersen(), 4), batch.Lane(graph.myciel(3), 5)]
+    n_pad, w = 32, 1
+    kw = dict(n=n_pad, cap=cap, block=BLOCK, mode="sort", use_mmw=False,
+              m_bits=1, k_hashes=1, schedule="doubling", backend="jax",
+              use_simplicial=False)
+
+    def args(ls):
+        adj, allowed, ks, targets = batch._pack_lanes(ls, n_pad, w)
+        return (adj, allowed, ks, targets,
+                frontier.lane_frontiers(len(ls), cap, w))
+
+    fr, lev, exp, drop, ref, app = batch._lanes_decide(*args(lanes), **kw)
+    alone = [batch._lanes_decide(*args([lane]), **kw) for lane in lanes]
+    for i, (fr1, lev1, exp1, drop1, ref1, app1) in enumerate(alone):
+        assert (int(lev[i]), int(exp[i]), int(drop[i]), int(app[i])) == \
+            (int(lev1[0]), int(exp1[0]), int(drop1[0]), int(app1[0])), i
+        assert int(fr.count[i]) == int(fr1.count[0])
+        np.testing.assert_array_equal(np.asarray(fr.states[i]),
+                                      np.asarray(fr1.states[0]))
+        assert int(ref[i]) >= int(ref1[0])
+    alone_refills = [int(a[4][0]) for a in alone]
+    assert alone_refills[0] > 0 and alone_refills[2:] == [0, 0]
+    assert (int(drop[0]) > 0) == (cap < 1 << 15)
+    assert int(drop[1]) == 0
+
+
 def test_decide_lanes_trivial_target_matches_decide_early_return():
     """k+1 >= n lanes are trivially feasible with zero expansion, exactly
     like solver.decide's target<=0 early return."""

@@ -231,7 +231,7 @@ def test_lane_engine_frontier_bit_parity(cfg):
     adj_b = jnp.broadcast_to(adj, (b,) + adj.shape)
     al_b = jnp.broadcast_to(allowed, (b,) + allowed.shape)
     fr_b = fr_lib.lane_frontiers(b, cap, adj.shape[-1])
-    out_fr, _lvl, exp_b, drop_b = batch._lanes_decide(
+    out_fr, _lvl, exp_b, drop_b, _ref, _app = batch._lanes_decide(
         adj_b, al_b, jnp.asarray(ks, jnp.int32),
         jnp.asarray([n - (k + 1) for k in ks], jnp.int32), fr_b, **kw)
     for i, k in enumerate(ks):
@@ -306,13 +306,107 @@ def test_lane_engine_backend_parity_multi_step_chunks(cfg):
             jnp.asarray(ks, jnp.int32),
             jnp.asarray([n - (k + 1) for k in ks], jnp.int32),
             fr_lib.lane_frontiers(b, cap, adj.shape[-1]), **kw)
-    (fr_j, lvl_j, exp_j, drop_j) = out["jax"]
-    (fr_p, lvl_p, exp_p, drop_p) = out["pallas"]
+    (fr_j, lvl_j, exp_j, drop_j, ref_j, app_j) = out["jax"]
+    (fr_p, lvl_p, exp_p, drop_p, ref_p, app_p) = out["pallas"]
     np.testing.assert_array_equal(np.asarray(lvl_j), np.asarray(lvl_p))
     np.testing.assert_array_equal(np.asarray(exp_j), np.asarray(exp_p))
     np.testing.assert_array_equal(np.asarray(drop_j), np.asarray(drop_p))
+    np.testing.assert_array_equal(np.asarray(ref_j), np.asarray(ref_p))
+    np.testing.assert_array_equal(np.asarray(app_j), np.asarray(app_p))
     np.testing.assert_array_equal(np.asarray(fr_j.count),
                                   np.asarray(fr_p.count))
     np.testing.assert_array_equal(np.asarray(fr_j.states),
                                   np.asarray(fr_p.states))
     assert int(np.asarray(exp_j).sum()) > 0
+
+
+# ------------------------------------------------------ mid-level refill
+
+def _golden(name):
+    return oracle.golden_widths()[name]["tw"]
+
+
+# (graph, k, refill cap, cap that never refills): at the refill cap a
+# level's append stream passes the buffer while its distinct states fit
+REFILL_LEVELS = [("petersen", 4, 128, 1 << 12),
+                 ("desargues", 5, 1 << 16, 1 << 19)]
+
+
+@pytest.mark.parametrize("name,k,cap,wide", REFILL_LEVELS,
+                         ids=[c[0] for c in REFILL_LEVELS])
+def test_refill_keeps_frontiers_level_by_level(name, k, cap, wide):
+    """One level at a time: the fused engine at the refill cap, the host
+    loop at the same cap and the fused engine at a cap that never refills
+    hold the same frontier after every level, with nothing dropped."""
+    from repro.core.telemetry import Tracker
+    g = graph.REGISTRY[name]()
+    adj, allowed = _devify(g)
+    kw = dict(n=g.n, block=BLOCK, mode="sort", use_mmw=False, m_bits=1,
+              k_hashes=1, schedule="doubling", backend="jax")
+    fr_f = frontier_lib.empty_frontier(cap, 1)
+    fr_h = frontier_lib.empty_frontier(cap, 1)
+    fr_w = frontier_lib.empty_frontier(wide, 1)
+    tr_f, tr_h, tr_w = Tracker(), Tracker(), Tracker()
+    widest_stream = 0
+    for _level in range(g.n - (k + 1)):
+        before = tr_f.counters().get("appended_rows", 0)
+        _, inexact, _, fr_f = engine.fused_decide(
+            adj, allowed, k, 1, cap=cap, fr=fr_f, tracker=tr_f, **kw)
+        widest_stream = max(widest_stream,
+                            tr_f.counters()["appended_rows"] - before)
+        _, inexact_w, _, fr_w = engine.fused_decide(
+            adj, allowed, k, 1, cap=wide, fr=fr_w, tracker=tr_w, **kw)
+        fr_h, stats = solver.run_level(adj, fr_h, k, allowed, cap=cap,
+                                       tracker=tr_h, **kw)
+        count = int(fr_f.count)
+        assert not inexact and not inexact_w and stats.dropped == 0
+        assert count == int(fr_w.count) == int(fr_h.count)
+        np.testing.assert_array_equal(np.asarray(fr_f.states),
+                                      np.asarray(fr_h.states))
+        np.testing.assert_array_equal(np.asarray(fr_f.states)[:count],
+                                      np.asarray(fr_w.states)[:count])
+        if count == 0:
+            break
+    assert widest_stream > cap          # the append stream did not fit
+    assert tr_f.counters()["refills"] == tr_h.counters()["refills"] > 0
+    assert tr_w.counters()["refills"] == 0
+    assert tr_f.counters()["appended_rows"] == \
+        tr_w.counters()["appended_rows"]
+
+
+@pytest.mark.parametrize("eng", ["host", "fused"])
+@pytest.mark.parametrize("name,cap", [("petersen", 64),
+                                      ("desargues", 1 << 15)],
+                         ids=["petersen", "desargues"])
+def test_refill_solve_matches_reference(name, cap, eng):
+    """At a cap the levels' append streams overflow, solve() refills and
+    answers exactly, with the golden width (and the Held-Karp oracle's
+    where it reaches), and no rung drops a state."""
+    from repro.core.telemetry import Tracker
+    g = graph.REGISTRY[name]()
+    tr = Tracker()
+    res = solver.solve(g, cap=cap, block=BLOCK, engine=eng, tracker=tr)
+    assert res.exact and res.width == _golden(name)
+    if g.n <= 12:
+        assert res.width == oracle.tw_oracle(g)
+    assert tr.counters()["refills"] > 0
+    assert not any(rung["inexact"] for block in res.per_k.values()
+                   for rung in block.values())
+
+
+def test_refill_cannot_save_a_level_past_the_cap():
+    """A level whose distinct states pass the cap still drops and counts
+    it, refills or not: the decide is inexact, and so is the solve at a
+    cap far below the widest level."""
+    from repro.core.telemetry import Tracker
+    g = graph.REGISTRY["desargues"]()
+    adj, allowed = _devify(g)
+    tr = Tracker()
+    _, inexact, _, fr = engine.fused_decide(
+        adj, allowed, 5, g.n - 6, n=g.n, cap=1 << 14, block=BLOCK,
+        mode="sort", use_mmw=False, m_bits=1, k_hashes=1,
+        schedule="doubling", tracker=tr)
+    assert inexact and int(fr.dropped) > 0
+    assert tr.counters()["refills"] > 0
+    res = solver.solve(g, cap=1 << 10, block=BLOCK)
+    assert not res.exact and res.lb <= _golden("desargues") <= res.ub

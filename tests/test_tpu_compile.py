@@ -202,3 +202,92 @@ def test_lane_program_has_no_per_row_scatter(request, monkeypatch, chip):
         dims = [int(d) for d in shape[shape.index("[") + 1:-1].split(",")
                 if d]
         assert np.prod(dims) < 256, shape       # per lane, not per row
+
+
+def _computations(text):
+    """{computation name: its instruction lines} of a program's text."""
+    import re
+    comps, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{\s*$", ln)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(ln)
+    return comps
+
+
+def _lane_program_text(request, monkeypatch, chip):
+    """The lane pool's program, compiled on the CPU (jax ops) or for the
+    described chip (native kernel), at a ``block`` above 128 so that it
+    holds both level sweeps."""
+    from repro.core import batch, frontier
+    sharding, backend = None, "jax"
+    if chip:
+        backend = "pallas"
+        from repro.kernels.wavefront import ops as wavefront_ops
+        sharding = request.getfixturevalue("one_chip")
+        monkeypatch.setattr(wavefront_ops, "default_interpret",
+                            lambda: False)
+    lanes, n, cap, block = 2, 24, 4096, 256
+    w = bitset.n_words(n)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    fr = frontier.Frontier(spec((lanes, cap, w), jnp.uint32),
+                           spec((lanes,), jnp.int32),
+                           spec((lanes,), jnp.int32))
+    return batch._lanes_decide.lower(
+        spec((lanes, n, w), jnp.uint32), spec((lanes, w), jnp.uint32),
+        spec((lanes,), jnp.int32), spec((lanes,), jnp.int32), fr, n=n,
+        cap=cap, block=block, mode="sort", use_mmw=False, m_bits=1 << 24,
+        k_hashes=4, schedule="doubling", backend=backend,
+        use_simplicial=False).compile().as_text()
+
+
+def _under_conditionals(comps):
+    """Computations reached from a conditional's branches through calls,
+    fusions and nested conditionals (a loop body inside a branch would
+    not do)."""
+    import re
+    calls = r"calls|true_computation|false_computation"
+    todo = []
+    for lines in comps.values():
+        for ln in lines:
+            if " conditional(" in ln:
+                todo += re.findall(
+                    r"(?:true_computation|false_computation)=%([\w.\-]+)",
+                    ln)
+                for grp in re.findall(r"branch_computations=\{([^}]*)\}",
+                                      ln):
+                    todo += [x.strip().lstrip("%") for x in grp.split(",")]
+    inside = set()
+    while todo:
+        c = todo.pop()
+        if c in inside or c not in comps:
+            continue
+        inside.add(c)
+        for ln in comps[c]:
+            todo += re.findall(rf"(?:{calls})=%([\w.\-]+)", ln)
+            for grp in re.findall(r"branch_computations=\{([^}]*)\}", ln):
+                todo += [x.strip().lstrip("%") for x in grp.split(",")]
+    return inside
+
+
+@pytest.mark.parametrize("chip", [False, True], ids=["cpu", "v5e"])
+def test_lane_program_refills_under_a_conditional(request, monkeypatch,
+                                                  chip):
+    """The mid-level refill's sorts sit in the branches of a conditional
+    (reached through nothing but calls, fusions and conditionals), not in
+    the chunk loop's body behind a ``select``: under the lane ``vmap``
+    its predicate is one value for every lane, so the full-buffer sorts
+    run only on the chunks where some lane needs them."""
+    comps = _computations(_lane_program_text(request, monkeypatch, chip))
+    inside = _under_conditionals(comps)
+    refill_sorts = [(c, ln) for c, lines in comps.items() for ln in lines
+                    if " sort(" in ln and "/tw.refill/" in ln]
+    assert len(refill_sorts) >= 2, "the lane program lost its refill"
+    for c, ln in refill_sorts:
+        assert c in inside, (c, ln.strip()[:200])

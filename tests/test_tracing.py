@@ -142,10 +142,12 @@ def test_device_scopes_name_the_dedup_sorts_and_the_append_scatter():
                 for ln in text.splitlines() if f" {kind}(" in ln]
 
     sorts, scatters = op_names("sort"), op_names("scatter")
-    # the dedup sorts, and the compaction sort that packs the append
+    # the dedup sorts, the compaction sort that packs the append, and the
+    # mid-level refill's dedup and compaction sorts
     assert any("/tw.dedup/" in o for o in sorts)
-    assert all("/tw.level/" in o and ("/tw.dedup/" in o
-                                      or "/tw.append/" in o)
+    assert sum("/tw.refill/" in o for o in sorts) == 2
+    assert all("/tw.level/" in o and ("/tw.dedup/" in o or "/tw.append/" in o
+                                      or "/tw.refill/" in o)
                for o in sorts)
     assert any("/tw.append/" in o for o in scatters)
     assert all("/tw.append/" in o or "/tw.dedup/" in o for o in scatters)
@@ -165,7 +167,7 @@ def test_fill_counters_of_one_padded_dispatch():
     for lane in lanes:
         args = _lanes_args([lane], 32, cap)
         fr = frontier.empty_frontier(cap, bitset.n_words(32))
-        _fr, level, expanded, _d = engine._fused_decide(
+        _fr, level, expanded, _d, _r, _a = engine._fused_decide(
             args[0][0], args[1][0], args[2][0], args[3][0], fr, n=32,
             cap=cap, **DECIDE)
         levels.append(int(level))
