@@ -532,6 +532,8 @@ class InstanceState:
             if self.use_pre and self.fold.skip(part):
                 continue
             plan = self.solver.plan_block(part, **self.plan_kw)
+            self.tracker.count(paths_flows=plan.paths_flows,
+                               paths_pairs=plan.paths_pairs)
             if plan.result is not None:
                 self._fold(plan.result, part.name, idx)
                 continue

@@ -140,65 +140,71 @@ def lower_bound(g: Graph, seed: int = 0) -> int:
     return lb
 
 
-def disjoint_paths_matrix(g: Graph, cap: int = 64) -> np.ndarray:
-    """P[u, v] = number of internally-vertex-disjoint u-v paths (capped).
+def paths_candidates(g: Graph, k_min: int) -> np.ndarray:
+    """Pairs ``u < v`` whose disjoint-path count can reach ``k_min + 1``.
 
-    Vertex-capacity max-flow via BFS augmentation on the standard split
-    graph (v_in -> v_out).  Used for the paper's rule: if P[u,v] >= k+1 the
-    edge uv may be added when testing width k [Clautiaux et al.].
-    Runs once per instance on the host.
-    """
+    Only non-adjacent pairs feed the rule, and a pair has at most
+    ``min(deg u, deg v)`` internally-disjoint paths (Menger), so a pair
+    of lower degree can add no edge at any width ``k >= k_min``."""
+    deg = g.degrees()
+    ok = deg >= k_min + 1
+    return np.triu(ok[:, None] & ok[None, :] & ~g.adj, 1)
+
+
+def disjoint_paths_matrix(g: Graph, cap: int = 64, *,
+                          k_min: int = 0) -> np.ndarray:
+    """P[u, v] = number of internally-vertex-disjoint u-v paths, capped at
+    ``cap``, where it can reach ``k_min + 1``; 0 for every other pair.
+
+    Used for the paper's rule: if P[u,v] >= k+1 the edge uv may be added
+    when testing width k [Clautiaux et al.].  ``paths_edges`` reads the
+    matrix only at ``k_min <= k < cap``, where it equals the full count's
+    answer: the skipped pairs (``paths_candidates``) never reach the
+    threshold, and no threshold there exceeds ``cap``.
+
+    Vertex-capacity max-flow by BFS augmentation on the split graph
+    (node 2v = v_in, 2v+1 = v_out; v_in -> v_out and u_out -> v_in arcs
+    of capacity 1), built once: a u-v flow runs from u_out to v_in, so
+    the terminals' own split arcs never carry it.  Runs once per
+    instance on the host."""
     n = g.n
     out = np.zeros((n, n), dtype=np.int32)
-    nbrs = [list(np.nonzero(g.adj[v])[0]) for v in range(n)]
-
-    def maxflow(s: int, t: int, limit: int) -> int:
-        # node-split network: node 2v = v_in, 2v+1 = v_out
-        # edges: v_in->v_out cap 1 (inf for s,t), uv edge: u_out->v_in cap 1
+    # arc e and its residual twin e ^ 1
+    head, cap0, arcs = [], [], [[] for _ in range(2 * n)]
+    for a, b in [(2 * v, 2 * v + 1) for v in range(n)] + \
+            [(2 * u + 1, 2 * v) for u, v in np.argwhere(g.adj)]:
+        arcs[a].append(len(head))
+        head.append(b)
+        cap0.append(1)
+        arcs[b].append(len(head))
+        head.append(a)
+        cap0.append(0)
+    for u, v in np.argwhere(paths_candidates(g, k_min)):
+        res = list(cap0)
+        src, snk = 2 * u + 1, 2 * v
         flow = 0
-        # residual as dict-of-dict is slow; use adjacency with capacity map
-        capm = {}
-
-        def add(a, b, c):
-            capm[(a, b)] = capm.get((a, b), 0) + c
-            capm.setdefault((b, a), 0)
-
-        for v in range(n):
-            add(2 * v, 2 * v + 1, 1 if v not in (s, t) else limit + 1)
-        for u in range(n):
-            for v in nbrs[u]:
-                add(2 * u + 1, 2 * v, 1)
-        adjn = [[] for _ in range(2 * n)]
-        for (a, b) in capm:
-            adjn[a].append(b)
-        src, snk = 2 * s + 1, 2 * t
-        while flow <= limit:
-            # BFS for augmenting path
-            parent = {src: None}
+        while flow < cap:
+            via = {src: None}            # node -> arc that reached it
             q = [src]
-            while q and snk not in parent:
+            while q and snk not in via:
                 nq = []
                 for a in q:
-                    for b in adjn[a]:
-                        if b not in parent and capm[(a, b)] > 0:
-                            parent[b] = a
+                    for e in arcs[a]:
+                        b = head[e]
+                        if res[e] and b not in via:
+                            via[b] = e
                             nq.append(b)
                 q = nq
-            if snk not in parent:
+            if snk not in via:
                 break
             b = snk
-            while parent[b] is not None:
-                a = parent[b]
-                capm[(a, b)] -= 1
-                capm[(b, a)] += 1
-                b = a
+            while via[b] is not None:
+                e = via[b]
+                res[e] -= 1
+                res[e ^ 1] += 1
+                b = head[e ^ 1]
             flow += 1
-        return flow
-
-    for u in range(n):
-        for v in range(u + 1, n):
-            f = maxflow(u, v, cap)
-            out[u, v] = out[v, u] = f
+        out[u, v] = out[v, u] = flow
     return out
 
 

@@ -480,7 +480,8 @@ def solve_distributed(g: Graph, mesh: Mesh, *, cap_local: int = 1 << 14,
         if lb >= ub:
             width = max(width, ub)
             continue
-        paths = bounds.disjoint_paths_matrix(part, cap=ub) if use_paths else None
+        paths = bounds.disjoint_paths_matrix(part, cap=ub, k_min=lb) \
+            if use_paths else None
         found = ub
         any_inexact = False
         for k in range(lb, ub):

@@ -319,9 +319,17 @@ class BlockPlan:
     k0: int              # first k of the deepening ladder
     forced: bool         # k0 was pushed above the genuine lower bound
     result: Optional[SolveResult] = None
+    paths_flows: int = 0     # max-flows the paths rule ran
+    paths_pairs: int = 0     # vertex pairs of the block it covered
 
     def graph_at(self, k: int) -> Graph:
-        """G_k: the paper's rule-2 graph (improved edges for width k)."""
+        """G_k: the paper's rule-2 graph (improved edges for width k).
+
+        Defined on the ladder only, ``k0 <= k < ub``: ``paths`` holds no
+        threshold outside it."""
+        if not self.k0 <= k < self.ub:
+            raise ValueError(f"G_k for k={k} outside the ladder "
+                             f"[{self.k0}, {self.ub}) of {self.g.name}")
         if self.paths is None:
             return self.g
         return self.g.with_edges(bounds.paths_edges(self.g, self.paths, k))
@@ -380,8 +388,15 @@ def plan_block(g: Graph, *, use_clique: bool, use_paths: bool,
             return BlockPlan(g, clique, lb, ub, ub_order, None, k0, forced,
                              SolveResult(ub, False, lb, ub, 0, 0.0,
                                          ub_order, {}))
-    paths = bounds.disjoint_paths_matrix(g, cap=ub) if use_paths else None
-    return BlockPlan(g, clique, lb, ub, ub_order, paths, k0, forced)
+    if not use_paths:
+        return BlockPlan(g, clique, lb, ub, ub_order, None, k0, forced)
+    # the ladder reads G_k for k0 <= k < ub only: pairs that cannot reach
+    # k0 + 1 paths are skipped, and no flow runs past ub
+    return BlockPlan(
+        g, clique, lb, ub, ub_order,
+        bounds.disjoint_paths_matrix(g, cap=ub, k_min=k0), k0, forced,
+        paths_flows=int(np.count_nonzero(bounds.paths_candidates(g, k0))),
+        paths_pairs=g.n * (g.n - 1) // 2)
 
 
 def solve_block(g: Graph, *, cap: Optional[int], block: int, mode: str,
@@ -423,6 +438,7 @@ def solve_block(g: Graph, *, cap: Optional[int], block: int, mode: str,
     tr = telemetry.get(tracker)
     plan = plan_block(g, use_clique=use_clique, use_paths=use_paths,
                       start_k=start_k, heuristics=heuristics, seed=seed)
+    tr.count(paths_flows=plan.paths_flows, paths_pairs=plan.paths_pairs)
     if plan.result is not None:
         return dataclasses.replace(plan.result, time_sec=time.time() - t0)
     if cap is None:
