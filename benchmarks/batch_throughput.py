@@ -6,10 +6,10 @@ multi-lane engine (``repro.core.batch``) packs the unfinished instances'
 current deepening rungs into shared dispatches.  For the suite this bench
 reports wall-clock, dispatch and host-sync counts for
 
-  * ``sequential`` — ``[solver.solve(g) for g in suite]``
-  * ``lanes=L``    — ``batch.solve_many(suite, lanes=L)``
+  * ``sequential`` — ``[ladder.solve(g) for g in suite]``
+  * ``lanes=L``    — ``ladder.solve_many(suite, lanes=L)``
   * ``spec=S``     — per-instance speculative deepening
-                     (``solver.solve(g, lanes=S)``), the single-instance
+                     (``ladder.solve(g, lanes=S)``), the single-instance
                      counterpart
 
 and asserts width/exactness parity between all of them (expanded parity
@@ -25,7 +25,7 @@ real TPU hardware).
 """
 from __future__ import annotations
 
-from repro.core import batch, engine as engine_lib, solver
+from repro.core import engine as engine_lib, ladder
 
 from .common import SUITE_FAST, SUITE_FULL, Timer, emit, get_instance
 
@@ -46,18 +46,18 @@ def run(full: bool = False, quick: bool = False, lanes: int = 8,
 
     engine_lib.reset_counters()
     with Timer() as t_seq:
-        seq = [solver.solve(g, **kw) for g in gs]
+        seq = [ladder.solve(g, **kw) for g in gs]
     rows["sequential"] = (t_seq.seconds, dict(engine_lib.COUNTERS), seq)
 
     engine_lib.reset_counters()
     with Timer() as t_spec:
-        spec = [solver.solve(g, lanes=speculate, **kw) for g in gs]
+        spec = [ladder.solve(g, lanes=speculate, **kw) for g in gs]
     rows[f"spec={speculate}"] = (t_spec.seconds, dict(engine_lib.COUNTERS),
                                  spec)
 
     engine_lib.reset_counters()
     with Timer() as t_many:
-        many = batch.solve_many(gs, lanes=lanes, **kw)
+        many = ladder.solve_many(gs, lanes=lanes, **kw)
     rows[f"lanes={lanes}"] = (t_many.seconds, dict(engine_lib.COUNTERS),
                               many)
 

@@ -1,27 +1,25 @@
-"""Host-sync microbench: engine (host vs fused) x backend (jax vs pallas)
-x lanes (sequential vs speculative deepening) x shards (scale-out).
+"""Host-sync microbench: backend (jax vs pallas) x lanes (sequential vs
+speculative deepening) x shards (scale-out).
 
 The paper's §3 design point is that the Held-Karp frontier never leaves the
-GPU; the cost of not doing that is kernel-dispatch serialisation.  This
-bench quantifies it on the Table 1 instances: for each graph it runs the
-full iterative-deepening solve under each engine x backend combination and
-reports wall-clock, jitted-program dispatches, and blocking device→host
-transfers (counted by a per-measurement ``repro.core.telemetry.Tracker``
-— a detached scope, so concurrent process-global accounting never leaks
-into a row).
+GPU; the cost of not doing that is kernel-dispatch serialisation.  Every
+rung of ``ladder.solve`` is one dispatch of a device-resident program, so
+this bench reports, on the Table 1 instances, the wall-clock, jitted-program
+dispatches and blocking device→host transfers of the full iterative-
+deepening solve per backend x lanes x shards combination (counted by a
+per-measurement ``repro.core.telemetry.Tracker`` — a detached scope, so
+concurrent process-global accounting never leaks into a row).
 
 The backend column tracks the fused pallas wavefront kernel against the
-jax reference composition from day one (ISSUE 2).  The lanes column
-(ISSUE 3) runs the fused engine through speculative deepening
-(``solver.solve(lanes=4)`` -> ``core.batch``): one multi-lane dispatch
+jax reference composition.  The lanes column runs speculative deepening
+(``ladder.solve(lanes=4)`` -> ``core.batch``): one multi-lane dispatch
 per ladder window instead of one per k; ``benchmarks/batch_throughput.py``
-covers the cross-instance ``solve_many`` axis.  The shards column
-(ISSUE 7) runs the fused engine through intra-request scale-out
-(``solver.solve(shards=2)`` -> ``core.shard``): the frontier split
-across vmapped shard lanes with work donation — the shard-health
-counters (donations, donated rows, idle shard-steps, peak occupancy)
-land in the same tracker scope.  On CPU the pallas rows run in
-interpret mode, so their absolute times measure the interpreter, not
+covers the cross-instance ``solve_many`` axis.  The shards column runs
+intra-request scale-out (``ladder.solve(shards=2)`` -> ``core.shard``):
+the frontier split across vmapped shard lanes with work donation — the
+shard-health counters (donations, donated rows, idle shard-steps, peak
+occupancy) land in the same tracker scope.  On CPU the pallas rows run
+in interpret mode, so their absolute times measure the interpreter, not
 the kernel — the dispatch/sync counts and the bit-for-bit width/
 expanded parity asserts are what carry; wall-clock becomes meaningful on
 real TPU hardware.
@@ -33,22 +31,18 @@ real TPU hardware.
 """
 from __future__ import annotations
 
-from repro.core import solver, telemetry
+from repro.core import ladder, telemetry
 
 from .common import SUITE_FAST, SUITE_FULL, Timer, emit, get_instance
 
 SUITE_QUICK = [("myciel3", 5), ("petersen", 4), ("desargues", 6)]
 
-# (backend, engine, lanes, shards) rows per instance; host/pallas adds
-# nothing the others don't already cover (host-loop overhead is
-# backend-independent).  The lanes=4 row runs the same fused engine
-# through the multi-lane speculative-deepening path (core.batch); the
-# shards=2 row through the sharded scale-out path (core.shard) — both
+# (backend, lanes, shards) rows per instance.  The lanes=4 row runs the
+# ladder through the multi-lane speculative-deepening path (core.batch);
+# the shards=2 row through the sharded scale-out path (core.shard) — both
 # extra columns of the dispatch/sync accounting, and both must stay
-# bit-identical to the sequential fused row.
-COMBOS = [("jax", "host", 1, 1), ("jax", "fused", 1, 1),
-          ("jax", "fused", 4, 1), ("jax", "fused", 1, 2),
-          ("pallas", "fused", 1, 1)]
+# bit-identical to the sequential row.
+COMBOS = [("jax", 1, 1), ("jax", 4, 1), ("jax", 1, 2), ("pallas", 1, 1)]
 
 SHARD_KEYS = ("shard_donations", "shard_donated_rows",
               "shard_idle_steps", "shard_peak_occupancy")
@@ -59,32 +53,31 @@ def run(full: bool = False, quick: bool = False, pallas: bool = True,
     suite = SUITE_FULL if full else (SUITE_QUICK if quick else SUITE_FAST)
     combos = [c for c in COMBOS if pallas or c[0] != "pallas"]
     rows = []
-    header = (f"{'instance':<12} {'backend':<7} {'engine':<6} {'lanes':>5} "
+    header = (f"{'instance':<12} {'backend':<7} {'lanes':>5} "
               f"{'shards':>6} {'tw':>3} {'time_s':>8} {'dispatches':>10} "
               f"{'host_syncs':>10}")
     print(header, flush=True)
     for key, want in suite:
         g = get_instance(key)
         per_combo = {}
-        for backend, engine, lanes, shards in combos:
+        for backend, lanes, shards in combos:
             # fresh detached tracker per measurement: isolates this run's
             # counters from the process-global accounting
             tr = telemetry.Tracker()
             with Timer() as t:
-                res = solver.solve(g, cap=cap, block=block, engine=engine,
-                                   backend=backend, schedule="doubling",
-                                   lanes=lanes, shards=shards, tracker=tr)
+                res = ladder.solve(g, cap=cap, block=block, backend=backend,
+                                   schedule="doubling", lanes=lanes,
+                                   shards=shards, tracker=tr)
             c = {k: int(tr[k]) for k in telemetry.LEGACY_KEYS}
             ok = (want is None) or (res.width == want)
-            per_combo[(backend, engine, lanes, shards)] = \
-                (res, c, t.seconds, ok)
-            rows.append((key, backend, engine, lanes, shards, res.width,
+            per_combo[(backend, lanes, shards)] = (res, c, t.seconds, ok)
+            rows.append((key, backend, lanes, shards, res.width,
                          t.seconds, c["dispatches"], c["host_syncs"], ok))
-            print(f"{key:<12} {backend:<7} {engine:<6} {lanes:>5} "
+            print(f"{key:<12} {backend:<7} {lanes:>5} "
                   f"{shards:>6} {res.width:>3} {t.seconds:>8.2f} "
                   f"{c['dispatches']:>10} {c['host_syncs']:>10}",
                   flush=True)
-            emit(f"engine_sync/{key}/{backend}/{engine}/lanes{lanes}"
+            emit(f"engine_sync/{key}/{backend}/lanes{lanes}"
                  f"/shards{shards}",
                  t.seconds,
                  f"tw={res.width};dispatches={c['dispatches']};"
@@ -98,20 +91,12 @@ def run(full: bool = False, quick: bool = False, pallas: bool = True,
             assert r.width == base.width, (key, r.width, base.width)
             assert r.expanded == base.expanded, \
                 (key, r.expanded, base.expanded)
-        (rh, ch, th, _) = per_combo[("jax", "host", 1, 1)]
-        (rf, cf, tf, _) = per_combo[("jax", "fused", 1, 1)]
-        speedup = th / max(tf, 1e-9)
-        sync_ratio = ch["host_syncs"] / max(cf["host_syncs"], 1)
-        emit(f"engine_sync/{key}/summary", tf,
-             f"speedup={speedup:.2f}x;sync_reduction={sync_ratio:.0f}x")
-        print(f"{key:<12} -> fused speedup {speedup:.2f}x, "
-              f"{ch['host_syncs']} -> {cf['host_syncs']} syncs "
-              f"({sync_ratio:.0f}x fewer)", flush=True)
-        (rb, cb, tb, _) = per_combo[("jax", "fused", 4, 1)]
+        (rf, cf, tf, _) = per_combo[("jax", 1, 1)]
+        (rb, cb, tb, _) = per_combo[("jax", 4, 1)]
         emit(f"engine_sync/{key}/batch_summary", tb,
-             f"fused_dispatches={cf['dispatches']};"
+             f"seq_dispatches={cf['dispatches']};"
              f"lanes4_dispatches={cb['dispatches']};parity=exact")
-        (rs, cs, ts, _) = per_combo[("jax", "fused", 1, 2)]
+        (rs, cs, ts, _) = per_combo[("jax", 1, 2)]
         shard_health = ";".join(f"{k}={cs[k]}" for k in SHARD_KEYS)
         emit(f"engine_sync/{key}/shard_summary", ts,
              f"seq_s={tf:.3f};shards2_s={ts:.3f};{shard_health};"
@@ -121,11 +106,10 @@ def run(full: bool = False, quick: bool = False, pallas: bool = True,
               f"({cs['shard_donated_rows']} rows), "
               f"{cs['shard_idle_steps']} idle shard-steps, "
               f"peak occupancy {cs['shard_peak_occupancy']}", flush=True)
-        if ("pallas", "fused", 1, 1) in per_combo:
-            (rp, cp, tp, _) = per_combo[("pallas", "fused", 1, 1)]
+        if ("pallas", 1, 1) in per_combo:
+            (rp, cp, tp, _) = per_combo[("pallas", 1, 1)]
             emit(f"engine_sync/{key}/backend_summary", tp,
-                 f"jax_fused_s={tf:.3f};pallas_fused_s={tp:.3f};"
-                 f"parity=exact")
+                 f"jax_s={tf:.3f};pallas_s={tp:.3f};parity=exact")
     return rows
 
 

@@ -19,7 +19,7 @@ def main() -> None:
     from . import table1_general
     table1_general.run(full=full, json_path="BENCH_solver.json")
 
-    print("# engine_sync: fused vs host-loop engine (dispatches + syncs)",
+    print("# engine_sync: dispatches + syncs per backend x lanes x shards",
           flush=True)
     from . import engine_sync
     engine_sync.run(full=full)

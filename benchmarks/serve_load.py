@@ -15,7 +15,7 @@ scope snapshots returned by the ``metrics`` wire op carry a
 
 Printed per run: p50/p95/p99 submit→done latency, mean admission wait,
 pool-level dispatch/sync totals — and every result is parity-asserted
-against a sequential ``solver.solve`` of the same instance, so the
+against a sequential ``ladder.solve`` of the same instance, so the
 driver doubles as an end-to-end wire correctness check.
 
     python -m benchmarks.serve_load               # fast trace (16 reqs)
@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import time
 
-from repro.core import solver
+from repro.core import ladder
 from repro.core.canon import graph_key
 from repro.launch.twserved import TwServer
 from repro.serve.client import TwClient
@@ -65,7 +65,7 @@ def run(quick: bool = False, lanes: int = 4, block: int = 1 << 10,
         jsonl_path: str = None):
     trace = TRACE_QUICK if quick else TRACE
     keys = sorted({k for k, _t in trace})
-    refs = {k: solver.solve(get_instance(k), block=block) for k in keys}
+    refs = {k: ladder.solve(get_instance(k), block=block) for k in keys}
 
     srv = TwServer(port=0, lanes=lanes, block=block,
                    metrics_jsonl=jsonl_path)
@@ -142,7 +142,7 @@ def run_trace(arrivals, lanes: int = 4, block: int = 1 << 10,
 
     Open-loop, like ``run``; additionally exercises and checks the
     result cache: every arrival's verdict is parity-asserted against a
-    reference ``solver.solve`` deduped by (canonical graph, result-
+    reference ``ladder.solve`` deduped by (canonical graph, result-
     relevant knobs) — relabeled duplicates (``iso``) check
     ``width``/``exact`` (the verdict is label-invariant; the plan
     heuristics' greedy tie-breaks and therefore ``expanded`` are not) —
@@ -163,7 +163,7 @@ def run_trace(arrivals, lanes: int = 4, block: int = 1 << 10,
                tuple((k, a.knobs.get(k)) for k in _REF_KNOBS))
         if key not in refs:
             kn = {k: a.knobs[k] for k in _REF_KNOBS if k in a.knobs}
-            refs[key] = solver.solve(g, block=block, **kn)
+            refs[key] = ladder.solve(g, block=block, **kn)
         a._ref = refs[key]      # noqa: SLF001 — driver-local annotation
 
     srv = TwServer(port=0, lanes=lanes, block=block, cache=cache,

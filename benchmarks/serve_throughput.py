@@ -12,7 +12,7 @@ scheduler additionally admits requests arriving *mid-flight* into the
 very next dispatch instead of waiting for an idle pool.  This bench
 pushes a mixed Table-1 instance stream through
 
-  * ``sequential`` — ``[solver.solve(g) for g in stream]`` (per-request)
+  * ``sequential`` — ``[ladder.solve(g) for g in stream]`` (per-request)
   * ``service=L``  — ``TwScheduler(lanes=L)``, blocking drain
   * ``async=L``    — the same stream with its second half arriving while
     the first dispatch is in flight, vs the blocking two-phase pattern
@@ -52,7 +52,7 @@ trajectory across PRs.
 """
 from __future__ import annotations
 
-from repro.core import batch, solver, telemetry
+from repro.core import batch, ladder, telemetry
 from repro.core import bitset, frontier
 from repro.serve.twscheduler import TwScheduler
 
@@ -90,7 +90,7 @@ def run(full: bool = False, quick: bool = False, lanes: int = 8,
     # (each mode gets a fresh detached tracker — isolated measurement)
     tr_seq = telemetry.Tracker()
     with Timer() as t_seq:
-        seq = [solver.solve(g, cap=batch.DEFAULT_CAP, block=block,
+        seq = [ladder.solve(g, cap=batch.DEFAULT_CAP, block=block,
                             tracker=tr_seq)
                for g in gs]
     n_max = max(g.n for g in gs)
@@ -158,7 +158,7 @@ def run_overlap(keys, gs, seq, *, lanes: int, block: int):
     mid-flight burst without waiting for pool idle, in fewer scheduler
     rounds than the blocking two-phase pattern, with per-request results
     (incl. the per_k reassembled from streamed events) bit-identical to
-    sequential ``solver.solve``."""
+    sequential ``ladder.solve``."""
     # keep the early phase below the pool width so the mid-flight burst
     # has free slots to land in (a full pool admits FIFO as slots free —
     # correct, but the next-dispatch evidence needs free lanes)
@@ -242,7 +242,7 @@ def run_pipeline(keys, gs, seq, *, lanes: int, block: int):
     fewer idle host-sync gaps than depth-1 serving of the same stream
     (every depth-2 sync past the first finds the next round already in
     flight), with per-request results bit-identical to depth 1 and to
-    sequential ``solver.solve``."""
+    sequential ``ladder.solve``."""
     records, stats = [], {}
     for depth in (1, 2):
         tr = telemetry.Tracker()
@@ -293,13 +293,13 @@ def run_shards(*, lanes: int, block: int, quick: bool = False):
     finishes in measurably fewer scheduler rounds than the identical
     request with ``shards=1``, while the concurrent small requests keep
     completing.  Every request's result is asserted bit-identical to
-    sequential ``solver.solve`` in both runs."""
+    sequential ``ladder.solve`` in both runs."""
     heavy_key = "myciel4" if quick else "queen5_5"
     heavy = get_instance(heavy_key)
     small_keys = ["myciel3", "petersen", "myciel3"]
     smalls = [get_instance(k) for k in small_keys]
-    ref_h = solver.solve(heavy, block=block)
-    ref_s = [solver.solve(g, block=block) for g in smalls]
+    ref_h = ladder.solve(heavy, block=block)
+    ref_s = [ladder.solve(g, block=block) for g in smalls]
 
     records, done_rounds = [], {}
     for s in (1, 4):

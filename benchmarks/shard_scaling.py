@@ -1,9 +1,9 @@
 """Intra-request scale-out bench: sequential solve vs sharded solve.
 
 ISSUE 7's perf trajectory: for each of the heavier Table 1 instances,
-run the fused-engine ladder sequentially (``solver.solve``) and then
+run the fused-engine ladder sequentially (``ladder.solve``) and then
 with the frontier split across S vmapped shard lanes
-(``solver.solve(shards=S)`` -> ``core.shard``: owner-hash routing +
+(``ladder.solve(shards=S)`` -> ``core.shard``: owner-hash routing +
 per-level work donation).  Every sharded run is asserted bit-identical
 to the sequential baseline — width, exactness, states expanded, and the
 per-rung feasibility trace — so the table measures pure partitioning
@@ -26,7 +26,7 @@ can archive the trajectory next to ``BENCH_serve.json``.
 """
 from __future__ import annotations
 
-from repro.core import solver, telemetry
+from repro.core import ladder, telemetry
 
 from .common import Timer, emit, get_instance
 
@@ -54,7 +54,7 @@ def run(full: bool = False, quick: bool = False, block: int = 1 << 10,
         g = get_instance(key)
         tr0 = telemetry.Tracker()
         with Timer() as t0:
-            ref = solver.solve(g, block=block, tracker=tr0)
+            ref = ladder.solve(g, block=block, tracker=tr0)
         c0 = {k: int(tr0[k]) for k in telemetry.LEGACY_KEYS}
         assert want is None or ref.width == want, (key, ref.width, want)
         print(f"{key:<12} {1:>6} {ref.width:>3} {t0.seconds:>8.2f} "
@@ -68,7 +68,7 @@ def run(full: bool = False, quick: bool = False, block: int = 1 << 10,
         for s in SHARDS:
             tr = telemetry.Tracker()
             with Timer() as t:
-                res = solver.solve(g, block=block, shards=s, tracker=tr)
+                res = ladder.solve(g, block=block, shards=s, tracker=tr)
             c = {k: int(tr[k]) for k in telemetry.LEGACY_KEYS}
             # bit-for-bit parity with the sequential ladder: sharding
             # repartitions the frontier, it never re-expands or prunes

@@ -18,7 +18,7 @@ and ``BENCH_shard.json``.
 """
 from __future__ import annotations
 
-from repro.core import solver, telemetry
+from repro.core import ladder, telemetry
 
 from .common import SUITE_FAST, SUITE_FULL, Timer, emit, get_instance
 
@@ -33,7 +33,7 @@ def run(full: bool = False, quick: bool = False, cap: int = 1 << 18,
         g = get_instance(key)
         tr = telemetry.Tracker()
         with Timer() as t:
-            res = solver.solve(g, cap=cap, block=block, tracker=tr)
+            res = ladder.solve(g, cap=cap, block=block, tracker=tr)
         ok = (want is None) or (res.width == want)
         rows.append((key, g.n, res.width, res.exact, res.expanded,
                      t.seconds, ok))

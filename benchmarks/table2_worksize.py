@@ -11,7 +11,7 @@ vs shared memory.  The TPU analogues:
 """
 from __future__ import annotations
 
-from repro.core import solver
+from repro.core import ladder
 
 from .common import Timer, emit, get_instance
 
@@ -27,7 +27,7 @@ def run(pallas: bool = False):
         for backend in backends:
             for block in BLOCKS:
                 with Timer() as t:
-                    res = solver.solve(g, cap=1 << 16, block=block,
+                    res = ladder.solve(g, cap=1 << 16, block=block,
                                        backend=backend)
                 tag = "S" if backend == "pallas" else "G"
                 base = base or res.width

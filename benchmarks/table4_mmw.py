@@ -5,7 +5,7 @@ bounds) while costing 2-3x runtime; this benchmark reproduces that
 trade-off measurement on the generatable suite."""
 from __future__ import annotations
 
-from repro.core import solver
+from repro.core import ladder
 
 from .common import Timer, emit, get_instance
 
@@ -18,7 +18,7 @@ def run():
         res = {}
         for mmw in (False, True):
             with Timer() as t:
-                r = solver.solve(g, cap=1 << 16, block=1 << 9, use_mmw=mmw)
+                r = ladder.solve(g, cap=1 << 16, block=1 << 9, use_mmw=mmw)
             res[mmw] = (r, t.seconds)
             emit(f"table4/{key}/{'mmw' if mmw else 'none'}", t.seconds,
                  f"tw={r.width};exp={r.expanded}")

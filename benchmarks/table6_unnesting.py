@@ -14,7 +14,7 @@ analogous default and the sweep verifies the same ordering holds.
 """
 from __future__ import annotations
 
-from repro.core import solver
+from repro.core import ladder
 
 from .common import Timer, emit, get_instance
 
@@ -28,7 +28,7 @@ def run():
         widths = set()
         for sched in SCHEDULES:
             with Timer() as t:
-                r = solver.solve(g, cap=1 << 16, block=1 << 9,
+                r = ladder.solve(g, cap=1 << 16, block=1 << 9,
                                  schedule=sched)
             widths.add(r.width)
             emit(f"table6/{key}/{sched}", t.seconds,

@@ -2,7 +2,7 @@
 as future work).  States explored with/without branch collapsing."""
 from __future__ import annotations
 
-from repro.core import solver
+from repro.core import ladder
 
 from .common import Timer, emit, get_instance
 
@@ -15,7 +15,7 @@ def run():
         res = {}
         for simp in (False, True):
             with Timer() as t:
-                r = solver.solve(g, cap=1 << 16, block=1 << 9,
+                r = ladder.solve(g, cap=1 << 16, block=1 << 9,
                                  use_simplicial=simp)
             res[simp] = (r, t.seconds)
             emit(f"simplicial/{key}/{'on' if simp else 'off'}", t.seconds,

@@ -17,8 +17,8 @@ compile and persistent-cache lookup (``jax.monitoring`` events), the
 entries of the compile cache before and after, each pool program's
 on-chip memory analysis, and ``peak_bytes_in_use``.
 
-Four chips: ``distributed.solve_distributed`` over the mesh of all chips,
-compared with a one-chip ``solver.solve`` in the same process, with the
+Four chips: ``ladder.solve_distributed`` over the mesh of all chips,
+compared with a one-chip ``ladder.solve`` in the same process, with the
 frontier shown to be spread over every chip.
 
 Every check that fails exits non-zero before the last line is printed.
@@ -300,7 +300,7 @@ def one_chip(golden: dict, cache_dir: str) -> None:
 
 def four_chips(golden: dict) -> None:
     import jax
-    from repro.core import bitset, distributed, graph, solver
+    from repro.core import bitset, distributed, graph, ladder
 
     devs = jax.devices()
     check(len(devs) == 4, f"--four-chips needs 4 devices, found {len(devs)}")
@@ -330,12 +330,12 @@ def four_chips(golden: dict) -> None:
         g = graph.REGISTRY[name]()
         peak0 = peaks()
         t0 = time.perf_counter()
-        dist = distributed.solve_distributed(g, mesh, cap_local=cap_local,
-                                             block=1 << 10)
+        dist = ladder.solve_distributed(g, mesh, cap_local=cap_local,
+                                        block=1 << 10)
         dist_s = time.perf_counter() - t0
         grew = [p - q for p, q in zip(peaks(), peak0)]
         t0 = time.perf_counter()
-        one = solver.solve(g, block=1 << 10)
+        one = ladder.solve(g, block=1 << 10)
         one_s = time.perf_counter() - t0
         log(f"[mesh] {name}: 4 chips width={dist.width} exact={dist.exact} "
             f"expanded={dist.expanded} ({dist_s:.3f}s); 1 chip "

@@ -9,14 +9,14 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax                                          # noqa: E402
-from repro.core import bounds, distributed, graph   # noqa: E402
+from repro.core import bounds, distributed, graph, ladder   # noqa: E402
 
 g = graph.queen(5)
 mesh = distributed.make_solver_mesh()
 print(f"mesh: {mesh.devices.size} devices | graph {g.name} n={g.n}")
 
-res = distributed.solve_distributed(g, mesh, cap_local=1 << 12,
-                                    block=1 << 7, verbose=True)
+res = ladder.solve_distributed(g, mesh, cap_local=1 << 12,
+                               block=1 << 7, verbose=True)
 print(f"treewidth = {res.width} (exact={res.exact}, "
       f"states={res.expanded})")
 

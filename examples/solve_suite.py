@@ -4,7 +4,7 @@ print a Table-1 style report.
     PYTHONPATH=src python examples/solve_suite.py [--full] [--batch [LANES]]
 
 ``--batch`` solves the whole suite through the multi-lane engine
-(``repro.core.batch.solve_many``): instead of one dispatch per
+(``repro.core.ladder.solve_many``): instead of one dispatch per
 (instance, k), every scheduler round packs the current deepening rung of
 every unfinished instance into shared multi-lane dispatches.  Same
 widths/exactness, far fewer dispatches — the report prints both counters.
@@ -12,7 +12,7 @@ widths/exactness, far fewer dispatches — the report prints both counters.
 import sys
 import time
 
-from repro.core import batch, engine, graph, solver
+from repro.core import batch, engine, graph, ladder
 
 SUITE = [("myciel3", 5), ("petersen", 4), ("queen5_5", 18),
          ("queen6_6", 25), ("myciel4", 10), ("desargues", 6)]
@@ -47,14 +47,14 @@ def main(argv):
     engine.reset_counters()
     t0 = time.time()
     if lanes:
-        results = batch.solve_many(gs, lanes=lanes, **kw)
+        results = ladder.solve_many(gs, lanes=lanes, **kw)
         times = [None] * len(gs)       # lanes overlap; per-instance wall
         total_t = time.time() - t0     # time is the suite wall-clock
     else:
         results, times = [], []
         for g in gs:
             t1 = time.time()
-            results.append(solver.solve(g, **kw))
+            results.append(ladder.solve(g, **kw))
             times.append(time.time() - t1)
         total_t = time.time() - t0
     counters = dict(engine.COUNTERS)

@@ -9,9 +9,9 @@ This module collapses that split into one dispatch table:
   * each op — fused expand+prune, sort dedup, Bloom query-and-insert, and
     the standalone degree/MMW/simplicial pieces — is registered under a
     (op, backend) key with a uniform signature;
-  * the solver paths (``solver.decide``, ``engine.fused_decide``,
-    ``distributed``) and the CLI select implementations with a single
-    ``backend=`` knob;
+  * the solver paths (``solver.decide``, ``batch.decide_lanes``,
+    ``shard``, ``distributed``) and the CLI select implementations with a
+    single ``backend=`` knob;
   * unsupported combinations fail **at dispatch time** with a
     ``BackendCapabilityError`` naming the op, the backends that do support
     it, and the fix — never with a bare TypeError deep inside a jit.
@@ -64,6 +64,13 @@ PALLAS_BLOOM_MAX_BITS = 1 << 29
 # backend whose kernels lack a batching rule must be left out of this set
 # so ``validate(lanes=...)`` rejects it at entry instead of mid-trace.
 BATCHED_BACKENDS: Tuple[str, ...] = ("jax", "pallas")
+
+
+def default_schedule(backend: str) -> str:
+    """The closure schedule a backend runs when the caller names none:
+    the static "doubling" baked into the pallas kernels, the
+    early-exiting "while" loop of the jax reference ops."""
+    return "doubling" if backend == "pallas" else "while"
 
 
 class BackendCapabilityError(ValueError):
@@ -174,7 +181,7 @@ def validate(backend: str, *, mode: str = "sort",
              shards: int = 1) -> None:
     """Fail fast on solver configurations the backend cannot run.
 
-    Called at every entry point (``solver.decide``, ``engine.fused_decide``,
+    Called at every entry point (``solver.decide``, ``ladder.solve``,
     ``distributed.decide_distributed``, ``batch.decide_lanes``, the CLI) so
     an unsupported combo surfaces as one actionable error before any
     tracing starts.  ``lanes > 1`` and ``shards > 1`` additionally require

@@ -1,9 +1,8 @@
-"""Batched multi-lane decide engine: one dispatch decides B subproblems.
+"""Lane dispatch: one compiled program decides B rungs at once.
 
-The fused engine (``core.engine``) already keeps a single ``decide(g, k)``
-on device, but the iterative-deepening driver and suite workloads still
-issue every decide as its own program — early levels and small instances
-leave the device nearly idle.  This module adds the missing batching axis
+The single-device decide program of the repository.  ``decide_loop``
+(``core.engine``) keeps a whole rung on device; this module runs it under
+a leading lane axis, so one dispatch decides B independent subproblems
 (component-aware parallel branching in the GPU-vertex-cover sense: run
 independent subproblems concurrently until each saturates the device):
 
@@ -12,21 +11,19 @@ independent subproblems concurrently until each saturates the device):
     and ``Frontier`` slice; the while_loop batching rule folds per-lane
     early exit into the masked loop condition (a finished lane's carry is
     frozen by ``select`` while the others keep stepping), so every lane's
-    result is bit-identical to running it alone.
-  * ``decide_lanes`` is the host entry: pad, pack, one dispatch, one sync.
-  * ``decide_batch(g, ks)`` — speculative deepening: decide
-    ``k, k+1, ..`` for one graph concurrently (used by
-    ``solver.solve_block(lanes=...)``; smallest feasible rung wins).
-  * ``solve_many(graphs)`` — suite driver: pads instances/biconnected
-    blocks to a common ``(n_max, W)`` and schedules lanes across the whole
-    suite, replicating ``solver.solve``'s per-instance semantics exactly
-    (same ``plan_block`` bounds, same skip rule, same accounting).
-  * ``InstanceState`` — the per-request unit those drivers (and the serve
-    scheduler, ``repro.serve.twscheduler``) advance rung by rung.
+    result is bit-identical to running it alone.  One lane is the
+    single-device rung of ``solver.decide``.
+  * ``decide_lanes_async`` is the host entry: pad, pack, one dispatch,
+    and a handle whose one host sync copies counts, never a frontier;
+    ``decide_lanes`` is its blocking form.
   * ``plan_capacity`` — the memory model: right-sizes per-lane frontier
     buffers from the block's state space, the chunk geometry and an
     optional device-memory budget instead of the fixed worst-case ``cap``
     (DESIGN.md §10).
+
+The deepening ladder that decides which rungs to pack (``core.ladder``)
+and the serve scheduler (``repro.serve.twscheduler``) sit above this
+module; nothing here knows about ladders, blocks or requests.
 
 Padding semantics: a lane of true size ``n_g`` is embedded at the bottom
 of the common ``n_max`` index space; padding vertices are isolated in
@@ -47,7 +44,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import time
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -55,10 +51,9 @@ import jax
 import jax.numpy as jnp
 
 from . import backend as backend_lib
-from . import bitset, bloom
+from . import bitset
 from . import engine as engine_lib
 from . import frontier as frontier_lib
-from . import preprocess as preprocess_lib
 from . import telemetry
 from .graph import Graph
 
@@ -69,7 +64,7 @@ U32 = jnp.uint32
 # (B * cap * W words resident per dispatch)
 DEFAULT_MAX_LANES = 8
 
-# the historical fixed frontier capacity (solver.solve's old default).
+# the historical fixed frontier capacity (the solver's old default).
 # ``cap=None`` everywhere now means "plan_capacity, clamped to this":
 # callers that want the old behaviour pass the constant explicitly.
 DEFAULT_CAP = 1 << 17
@@ -116,8 +111,8 @@ def plan_capacity(n: int, w: Optional[int] = None, *, lanes: int = 1,
     else:
         need = n * math.comb(n, n // 2) + 1
     cap_hi = _pow2_floor(cap_max)      # an explicit cap_max is a ceiling:
-    cap = min(_pow2_at_least(need), cap_hi)   # round DOWN, never past it
-    cap = max(cap, 32, _pow2_at_least(min(block, cap_hi)))
+    cap = min(pow2_at_least(need), cap_hi)    # round DOWN, never past it
+    cap = max(cap, 32, pow2_at_least(min(block, cap_hi)))
     if budget_bytes == "auto":
         budget_bytes = backend_lib.device_memory_budget()
     if budget_bytes is not None:
@@ -145,7 +140,7 @@ class LaneResult:
     expanded: int
 
 
-def _pow2_at_least(x: int) -> int:
+def pow2_at_least(x: int) -> int:
     p = 1
     while p < x:
         p *= 2
@@ -337,430 +332,3 @@ def decide_lanes(lanes: Sequence[Lane], *, cap: Optional[int] = None,
         backend=backend, use_simplicial=use_simplicial, n_pad=n_pad,
         lane_pad=lane_pad, cap_max=cap_max,
         budget_bytes=budget_bytes, tracker=tracker).result()
-
-
-def decide_batch(g: Graph, ks: Sequence[int], clique: Sequence[int] = (),
-                 *, graphs: Optional[Sequence[Graph]] = None,
-                 cap: Optional[int] = None,
-                 block: int, mode: str, use_mmw: bool, m_bits: int,
-                 k_hashes: int, schedule: str, backend: str = "jax",
-                 use_simplicial: bool = False,
-                 tracker=None) -> List[LaneResult]:
-    """Speculative deepening primitive: decide tw(g) <= k for several k in
-    one dispatch.
-
-    ``graphs`` optionally overrides the graph per rung — the deepening
-    driver passes the paths-rule-augmented ``G_k`` for each k (rule 2
-    admits more edges at higher k, so the lanes genuinely differ).  All
-    lanes share the true ``n``, so results are bit-identical to the
-    sequential ``decide`` loop for every mode/pruning combination."""
-    if graphs is not None and len(graphs) != len(ks):
-        raise ValueError("graphs must align with ks")
-    lanes = [Lane(graphs[i] if graphs is not None else g, int(k),
-                  tuple(clique)) for i, k in enumerate(ks)]
-    return decide_lanes(lanes, cap=cap, block=block, mode=mode,
-                        use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
-                        schedule=schedule, backend=backend,
-                        use_simplicial=use_simplicial, tracker=tracker)
-
-
-# ----------------------------------------------------------- suite driver
-
-@dataclasses.dataclass
-class _Run:
-    """Iterative deepening in progress on one block (mirrors the ladder
-    state of ``solver.solve_block``)."""
-    plan: object                  # solver.BlockPlan
-    k: int
-    idx: int = 0                  # index into the preprocess block list
-    expanded: int = 0
-    any_inexact: bool = False
-    per_k: dict = dataclasses.field(default_factory=dict)
-
-
-class InstanceState:
-    """One input graph's scheduler state: the solve()-shaped fold over its
-    preprocessed blocks (``solver.SuiteFold`` — the same accumulator
-    ``solve`` uses, so the two drivers cannot drift), advanced block by
-    block as lane verdicts are fed back.
-
-    This is the per-request unit of both lane drivers: ``solve_many``
-    walks a whole suite of them, and the serve scheduler
-    (``repro.serve.twscheduler``) keeps one per admitted request, feeding
-    each slot's rung verdict after every shared dispatch.  ``result`` is
-    set (a ``solver.SolveResult``) once the instance is decided; until
-    then ``run`` names the block rung currently occupying a lane.
-
-    ``reconstruct=True`` additionally certifies the result with an
-    elimination order: when a block's winning rung is found, that single
-    rung is replayed once on the host engine (``keep_levels=True``) to
-    snapshot its levels — the replay is *not* counted into ``expanded``,
-    which keeps the accounting bit-identical to ``solver.solve`` (the
-    sequential path also expands the winning rung exactly once) — and the
-    block orders are stitched through the preprocess maps exactly like
-    ``solve(reconstruct=True)``.  ``recon_kw`` carries the decide kwargs
-    for that replay (``cap=None`` re-plans per block via
-    ``plan_capacity``, matching the sequential auto-sizing)."""
-
-    def __init__(self, g: Graph, solver_lib, *, use_preprocess: bool,
-                 plan_kw: dict, reconstruct: bool = False,
-                 recon_kw: Optional[dict] = None, tracker=None):
-        self.g = g
-        self.solver = solver_lib
-        self.plan_kw = plan_kw
-        # per-request telemetry scope (the serve scheduler passes each
-        # request's child tracker so rung/expanded counts attribute to it
-        # and roll up into the pool totals); NULL here, not the root —
-        # suite drivers opt in explicitly
-        self.tracker = telemetry.NULL if tracker is None else tracker
-        self.reconstruct = reconstruct
-        self.recon_kw = dict(recon_kw or {})
-        self.t0 = time.time()
-        self.result: Optional[object] = None     # solver.SolveResult
-        self.run: Optional[_Run] = None
-        self.pre = None                          # preprocess.Preprocessed
-        self.use_pre = use_preprocess
-        self.bi = 0
-        if g.n == 0:
-            self.parts: list = []
-            self.fold = None
-            self.block_orders: list = []
-            self.result = solver_lib.SolveResult(0, True, 0, 0, 0, 0.0,
-                                                 [], {})
-            return
-        if use_preprocess:
-            self.pre = preprocess_lib.preprocess(g)
-            self.parts = [b.g for b in self.pre.blocks]
-            self.fold = solver_lib.SuiteFold.start(self.pre.lb)
-        else:
-            self.parts = [g]
-            self.fold = None      # single block: adopt its result wholesale
-        self.block_orders = [None] * len(self.parts)
-        self._advance()
-
-    def max_n(self) -> int:
-        return max([p.n for p in self.parts], default=1)
-
-    # ------------------------------------------------- anytime accounting
-
-    def bounds(self) -> tuple:
-        """Running instance-level ``(lb, ub)`` — the anytime contract.
-
-        lb sources (each a true lower bound on tw(g)): the preprocess
-        bound, the fold of finished blocks (their exact widths), the
-        current block's ``plan.lb``, and its refuted rungs (k0..k-1
-        infeasible ⇒ tw ≥ k — only when k0 was not forced above the
-        genuine bound and no state was dropped).  ub sources (each a
-        true upper bound per part; the instance ub is their max):
-        finished blocks' widths (folded), the current block's heuristic
-        ``plan.ub``, and n-1 for blocks not yet planned.  The serve
-        scheduler clamps these monotone against the previously streamed
-        pair; the deadline/cancel paths resolve with them directly."""
-        lb = self.pre.lb if self.pre is not None else 0
-        ub_parts = [0]
-        if self.fold is not None:
-            lb = max(lb, self.fold.lbs)
-            if self.fold.exact:
-                lb = max(lb, self.fold.width)
-            ub_parts.append(self.fold.width)
-        run = self.run
-        if run is not None:
-            lb = max(lb, run.plan.lb)
-            if not run.plan.forced and not run.any_inexact:
-                lb = max(lb, run.k)
-            ub_parts.append(run.plan.ub)
-        ub_parts.extend(p.n - 1 for p in self.parts[self.bi:])
-        return lb, max(ub_parts)
-
-    def partial(self) -> tuple:
-        """``(expanded, per_k)`` accounted so far: finished blocks' fold
-        plus the current block's in-progress ladder — the best-so-far
-        work accounting a preempted (deadline) or abandoned (cancel)
-        request reports instead of nothing."""
-        run = self.run
-        if self.fold is None:          # use_preprocess=False: solve_block
-            if run is None:            # shape — per_k keyed directly by k
-                return 0, {}
-            return run.expanded, dict(run.per_k)
-        expanded = self.fold.expanded
-        per_k = dict(self.fold.per_k)
-        if run is not None:
-            expanded += run.expanded
-            per_k[run.plan.g.name] = dict(run.per_k)
-        return expanded, per_k
-
-    def anytime_result(self, lb: Optional[int] = None,
-                       ub: Optional[int] = None):
-        """Resolve the instance *now* with its monotone best-so-far
-        bounds (Tamaki's anytime framing, PAPERS.md): ``width=ub``
-        (a heuristic order of that width exists), ``exact=False``, and
-        the partial ``expanded``/``per_k``.  ``lb``/``ub`` default to
-        ``bounds()``; the scheduler passes its stream-clamped pair so
-        the terminal result agrees with the streamed events."""
-        b_lb, b_ub = self.bounds()
-        lb = b_lb if lb is None else lb
-        ub = b_ub if ub is None else ub
-        expanded, per_k = self.partial()
-        return self.solver.SolveResult(ub, False, lb, ub, expanded,
-                                       time.time() - self.t0, None, per_k)
-
-    def _fold(self, bres, name: str, idx: int):
-        if self.reconstruct:
-            self.block_orders[idx] = bres.order
-        if not self.use_pre:
-            self.result = dataclasses.replace(
-                bres, time_sec=time.time() - self.t0)
-            return
-        self.fold.add(name, bres)
-
-    def _advance(self):
-        """Start the next runnable block, or finish the instance."""
-        while self.run is None and self.result is None:
-            if self.bi >= len(self.parts):
-                if self.use_pre:
-                    order = None
-                    if self.reconstruct:
-                        order = self.solver.stitch_and_verify(
-                            self.g, self.pre, self.block_orders,
-                            self.fold.width)
-                    self.result = self.fold.result(
-                        time.time() - self.t0, order)
-                return
-            part = self.parts[self.bi]
-            idx = self.bi
-            self.bi += 1
-            if self.use_pre and self.fold.skip(part):
-                continue
-            plan = self.solver.plan_block(part, **self.plan_kw)
-            self.tracker.count(paths_flows=plan.paths_flows,
-                               paths_pairs=plan.paths_pairs)
-            if plan.result is not None:
-                self._fold(plan.result, part.name, idx)
-                continue
-            self.run = _Run(plan, k=plan.k0, idx=idx)
-
-    def _certify(self, plan, k: int) -> Optional[list]:
-        """Replay the winning rung on the host engine for level snapshots
-        and backtrack an elimination order (uncounted — see class doc)."""
-        kw = dict(self.recon_kw)
-        if kw.get("cap") is None:
-            kw["cap"] = plan_capacity(plan.g.n, block=kw.get("block", 32),
-                                      cap_max=kw.pop("cap_max", DEFAULT_CAP))
-        else:
-            kw.pop("cap_max", None)
-        res = self.solver.decide(plan.graph_at(k), k, plan.clique,
-                                 keep_levels=True, engine="host", **kw)
-        return self.solver.reconstruct_order(plan.graph_at(k), k,
-                                             plan.clique, res.levels)
-
-    def finish_block(self, k_found: Optional[int]):
-        run = self.run
-        plan = run.plan
-        if k_found is not None:
-            order = (self._certify(plan, k_found)
-                     if self.reconstruct else None)
-            bres = self.solver.SolveResult(
-                k_found, plan.exact_at(k_found, run.any_inexact), plan.lb,
-                plan.ub, run.expanded, 0.0, order, run.per_k)
-        else:
-            bres = self.solver.SolveResult(
-                plan.ub, not run.any_inexact, plan.lb, plan.ub,
-                run.expanded, 0.0, plan.ub_order, run.per_k)
-        self.run = None
-        self._fold(bres, plan.g.name, run.idx)
-        self._advance()
-
-    def feed(self, k: int, res: LaneResult) -> bool:
-        """Consume one rung verdict with sequential-ladder accounting.
-
-        Returns ``False`` once the block finished on this verdict (a
-        speculative caller must discard its remaining rungs *uncounted* —
-        the sequential ladder never ran them), ``True`` while the ladder
-        continues.  This is the single accounting path shared by
-        ``solve_many`` and the serve scheduler, so ``expanded``/``per_k``
-        cannot drift from ``solver.solve_block``'s."""
-        run = self.run
-        run.expanded += res.expanded
-        run.per_k[k] = {"feasible": res.feasible, "inexact": res.inexact,
-                        "expanded": res.expanded}
-        counts = dict(rungs_decided=1, expanded=res.expanded)
-        if res.inexact:
-            counts["rung_overflows"] = 1
-        self.tracker.count(**counts)
-        if res.feasible:
-            self.finish_block(k)
-            return False
-        if res.inexact:
-            run.any_inexact = True
-        run.k = k + 1
-        if run.k >= run.plan.ub:
-            self.finish_block(None)
-            return False
-        return True
-
-    def improve_bounds(self, lb: Optional[int] = None,
-                       ub: Optional[int] = None,
-                       ub_order: Optional[list] = None) -> dict:
-        """Clamp anytime heuristic bounds into the current block's ladder
-        (``core.bounds_engine`` improvers; monotone tighten only).
-
-        A tighter ub (with its replayable order certificate) shortens the
-        remaining ladder; a tighter lb skips rungs the minor argument has
-        already refuted — ``run.k`` jumps forward and the skipped rungs
-        are never dispatched, exactly as if ``plan_block`` had known the
-        bound at admission.  Neither side can change the final verdict:
-        when the clamped ladder closes (``run.k >= plan.ub``) the block
-        resolves through the same ``finish_block(None)`` path the
-        exhausted ladder uses, with both sides certificate-backed.
-        Returns ``{lb_improved, ub_improved, rungs_skipped, finished}``
-        (``finished`` = the whole *instance* resolved); hints without a
-        certificate order, stale hints, and loosenings are ignored."""
-        out = dict(lb_improved=False, ub_improved=False, rungs_skipped=0,
-                   finished=False)
-        run = self.run
-        if run is None or self.result is not None:
-            return out
-        plan = run.plan
-        if ub is not None and ub_order is not None and int(ub) < plan.ub:
-            out["rungs_skipped"] += plan.ub - max(int(ub), run.k)
-            plan.ub = int(ub)
-            plan.ub_order = list(ub_order)
-            out["ub_improved"] = True
-        if lb is not None and int(lb) > plan.lb:
-            plan.lb = min(int(lb), plan.ub)
-            out["lb_improved"] = True
-            if plan.lb > run.k:
-                out["rungs_skipped"] += plan.lb - run.k
-                run.k = plan.lb
-        if run.k >= plan.ub:
-            self.finish_block(None)
-        out["finished"] = self.result is not None
-        return out
-
-
-def solve_many(graphs: Sequence[Graph], *, cap: Optional[int] = None,
-               block: int = 1 << 11, mode: str = "sort",
-               use_mmw: bool = False, m_bits: int = 1 << 24,
-               k_hashes: int = bloom.DEFAULT_K,
-               schedule: Optional[str] = None, use_clique: bool = True,
-               use_paths: bool = True, use_preprocess: bool = True,
-               reconstruct: bool = False,
-               start_k: Optional[int] = None, verbose: bool = False,
-               backend: str = "jax", use_simplicial: bool = False,
-               lanes: int = DEFAULT_MAX_LANES,
-               speculate: int = 1,
-               budget_bytes=None) -> List[object]:
-    """Solve a whole suite with cross-instance lane batching.
-
-    Returns one ``solver.SolveResult`` per input, in input order, with the
-    exact widths/exactness/bounds/``per_k``/``expanded`` the sequential
-    ``[solve(g) for g in graphs]`` loop produces — subject to the two
-    padding caveats in the module docstring: under ``use_mmw=True`` the
-    padded lanes may expand a superset (verdicts unchanged), and under
-    ``mode="bloom"`` a lane padded into a larger word count than its
-    sequential run (instances straddling a multiple of 32 vertices) draws
-    a different Monte-Carlo false-positive set, so its width/exactness
-    carry the usual Bloom-mode probabilistic guarantee rather than
-    bit-parity with the sequential run.  The default configuration
-    (sort-mode dedup, no MMW) is exactly parity-pinned.  Instead of one
-    dispatch per (instance, k), every scheduler round packs all
-    instances' current deepening rungs into multi-lane dispatches of up to
-    ``lanes`` lanes.  ``speculate > 1`` additionally lets each instance
-    occupy that many consecutive-k lanes per round.
-
-    ``cap=None`` (default) sizes each dispatch's shared per-lane buffer
-    with ``plan_capacity`` (drop-free bound of the largest lane, clamped
-    to ``DEFAULT_CAP`` / ``budget_bytes``) instead of one fixed
-    worst-case buffer — small preprocessed blocks stop paying for 2^17
-    rows they can never fill, and the parity guarantees above still hold.
-
-    ``reconstruct=True`` certifies every result with a stitched
-    elimination order exactly like ``solver.solve(reconstruct=True)``:
-    each block's winning rung is replayed once on the host engine for
-    level snapshots (uncounted, so ``expanded`` parity is preserved).
-
-    Runnable example (suite batching; for a *concurrent request stream*
-    with per-request knobs and streaming, use the serve scheduler —
-    DESIGN.md §10/§11)::
-
-        from repro.core import batch, graph
-        res = batch.solve_many([graph.myciel(4), graph.petersen()],
-                               lanes=8)
-        [r.width for r in res]            # -> [10, 4]
-    """
-    from . import solver as solver_lib   # lazy: solver imports this module
-
-    if schedule is None:
-        schedule = "doubling" if backend == "pallas" else "while"
-    lanes = int(lanes)
-    speculate = max(1, int(speculate))
-    backend_lib.validate(backend, mode=mode, schedule=schedule,
-                         use_mmw=use_mmw, use_simplicial=use_simplicial,
-                         m_bits=m_bits, lanes=lanes)
-    decide_kw = dict(cap=cap, block=block, mode=mode, use_mmw=use_mmw,
-                     m_bits=m_bits, k_hashes=k_hashes, schedule=schedule,
-                     backend=backend, use_simplicial=use_simplicial,
-                     budget_bytes=budget_bytes)
-    plan_kw = dict(use_clique=use_clique, use_paths=use_paths,
-                   start_k=start_k)
-    recon_kw = dict(cap=cap, block=block, mode=mode, use_mmw=use_mmw,
-                    m_bits=m_bits, k_hashes=k_hashes, schedule=schedule,
-                    backend=backend, use_simplicial=use_simplicial)
-
-    insts = [InstanceState(g, solver_lib, use_preprocess=use_preprocess,
-                           plan_kw=plan_kw, reconstruct=reconstruct,
-                           recon_kw=recon_kw) for g in graphs]
-    n_pad = max([i.max_n() for i in insts], default=1)
-    if cap is None:
-        # resolve ONE plan for the whole suite (largest block wins)
-        # instead of per dispatch group: per-group caps would mint a new
-        # jit signature every time group membership changes, and the
-        # vmapped lane program is expensive to compile.  Still <= the old
-        # fixed default, and all-small suites keep the full footprint cut.
-        w = bitset.n_words(n_pad)
-        decide_kw["cap"] = max(plan_capacity(
-            p.n, w, lanes=lanes, block=block, budget_bytes=budget_bytes)
-            for i in insts for p in i.parts) if any(i.parts for i in insts) \
-            else 32
-
-    rnd = 0
-    while True:
-        live = [inst for inst in insts if inst.run is not None]
-        if not live:
-            break
-        sched = []
-        lane_list: list = []
-        for inst in live:
-            run = inst.run
-            ks = list(range(run.k, min(run.k + speculate, run.plan.ub)))
-            sched.append((inst, ks))
-            lane_list.extend(
-                Lane(run.plan.graph_at(kk), kk, tuple(run.plan.clique))
-                for kk in ks)
-        if verbose:
-            print(f"[solve_many] round {rnd}: {len(lane_list)} lanes over "
-                  f"{len(live)} instances", flush=True)
-        results: list = []
-        for lo in range(0, len(lane_list), lanes):
-            group = lane_list[lo:lo + lanes]
-            results.extend(decide_lanes(
-                group, n_pad=n_pad,
-                lane_pad=min(lanes, _pow2_at_least(len(group))),
-                **decide_kw))
-        pos = 0
-        for inst, ks in sched:
-            name = inst.run.plan.g.name
-            rungs = results[pos:pos + len(ks)]
-            pos += len(ks)
-            for kk, res in zip(ks, rungs):
-                if verbose:
-                    print(f"  [{name}] k={kk} "
-                          f"feasible={res.feasible} "
-                          f"expanded={res.expanded} "
-                          f"inexact={res.inexact}", flush=True)
-                if not inst.feed(kk, res):
-                    # block finished on this rung: rungs above it were
-                    # never run sequentially — discard them uncounted
-                    break
-        rnd += 1
-    return [inst.result for inst in insts]

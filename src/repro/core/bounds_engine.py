@@ -37,6 +37,7 @@ import numpy as np
 
 from . import bounds, telemetry
 from .graph import Graph
+from .solver import SolveResult
 
 _MIX = 1000003  # seed mixer: keeps per-round streams disjoint
 
@@ -257,7 +258,7 @@ def ub_orders_async(graphs: Sequence[Graph], seeds: Sequence[int], *,
 class HeuristicState:
     """Bounds-only request state: no exact rungs, just improver rounds.
 
-    Mirrors the slice of `batch.InstanceState` the scheduler touches
+    Mirrors the slice of `ladder.InstanceState` the scheduler touches
     (``run``/``result``/``bounds``/``partial``/``anytime_result``/
     ``improve_bounds``), with ``run`` pinned to None so the launch loop
     never packs DP rungs for it.  Terminates when lb meets ub (then the
@@ -267,10 +268,9 @@ class HeuristicState:
 
     run = None  # never holds a DP ladder
 
-    def __init__(self, g: Graph, solver_lib, *, seed: int = 0,
+    def __init__(self, g: Graph, *, seed: int = 0,
                  max_rounds: int = 16, tracker=None):
         self.g = g
-        self.solver = solver_lib
         self.seed = int(seed)
         self.max_rounds = max(1, int(max_rounds))
         self.rounds_done = 0
@@ -295,9 +295,8 @@ class HeuristicState:
     def anytime_result(self, lb=None, ub=None):
         lb = self.lb if lb is None else max(lb, self.lb)
         ub = self.ub if ub is None else min(ub, self.ub)
-        return self.solver.SolveResult(ub, lb == ub, lb, ub, 0,
-                                       time.time() - self.t0,
-                                       order=self.ub_order, per_k={})
+        return SolveResult(ub, lb == ub, lb, ub, 0, time.time() - self.t0,
+                           order=self.ub_order, per_k={})
 
     def improve_bounds(self, lb=None, ub=None, ub_order=None) -> dict:
         """Clamp in an improver result; monotone tighten only."""
@@ -324,6 +323,6 @@ class HeuristicState:
         return self.result is not None
 
     def _finalize(self):
-        self.result = self.solver.SolveResult(
+        self.result = SolveResult(
             self.ub, self.lb == self.ub, self.lb, self.ub, 0,
             time.time() - self.t0, order=self.ub_order, per_k={})

@@ -29,8 +29,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
-import warnings
 from typing import Optional
 
 import numpy as np
@@ -39,15 +37,90 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import backend as backend_lib
-from . import bitset, bounds, dedup
+from . import batch as batch_lib
+from . import bitset, bloom, dedup
 from . import engine as engine_lib
-from . import preprocess as preprocess_lib
-from . import shard as shard_lib
 from . import telemetry
 from .graph import Graph
-from .solver import SolveResult
 
 U32 = jnp.uint32
+
+# donate when max shard occupancy exceeds ratio × mean occupancy.  1.5
+# tolerates the multinomial noise of hash ownership on healthy levels but
+# fires on genuine skew (and on the tiny early levels, where idle shards
+# are guaranteed); <= 1.0 rebalances every level.
+DEFAULT_DONATE_RATIO = 1.5
+
+
+# --------------------------------------------------------------- ownership
+
+def route_states(rows: jnp.ndarray, valid: jnp.ndarray, nshards: int,
+                 cap_recv: int):
+    """Partition valid rows to their owner shard (murmur3 mod S).
+
+    Returns (recv (S, cap_recv, W), counts (S,), dropped).  Rows are
+    sorted by (owner, words) first, so each owner's bucket arrives
+    lexicographically sorted.  Shared by the mesh program here (whose
+    buckets feed a real all_to_all) and the single-device sharded engine
+    in ``core.shard`` (scatter = the degenerate all_to_all).
+    """
+    m, w = rows.shape
+    owner = (bloom.murmur3_words(rows, bloom.SEED1) % np.uint32(nshards)) \
+        .astype(jnp.int32)
+    owner = jnp.where(valid, owner, nshards)       # invalid rows sort last
+    cols = (owner,) + tuple(rows[:, j] for j in range(w))
+    srt = jax.lax.sort(cols, dimension=0, num_keys=1 + w)
+    owner_s = srt[0]
+    rows_s = jnp.stack(srt[1:], axis=1)
+    counts = jnp.bincount(owner, length=nshards + 1)[:nshards] \
+        .astype(jnp.int32)
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              jnp.cumsum(counts)[:-1].astype(jnp.int32)])
+    idx = jnp.arange(m, dtype=jnp.int32)
+    safe_owner = jnp.minimum(owner_s, nshards - 1)
+    pos = idx - starts[safe_owner]
+    ok = (owner_s < nshards) & (pos < cap_recv)
+    dest = jnp.where(ok, safe_owner * cap_recv + pos, nshards * cap_recv)
+    recv = jnp.zeros((nshards * cap_recv, w), dtype=U32)
+    recv = recv.at[dest].set(rows_s, mode="drop")
+    rcounts = jnp.minimum(counts, cap_recv)
+    dropped = jnp.sum(counts - rcounts)
+    return recv.reshape(nshards, cap_recv, w), rcounts, dropped
+
+
+# ---------------------------------------------------------------- donation
+
+def donation_plan(counts: jnp.ndarray, ratio: float):
+    """Water-filling donation targets for per-shard occupancies.
+
+    Returns (targets (S,), triggered (bool), moved (rows leaving their
+    shard)).  Targets are the balanced occupancy ``total // S`` (+1 for
+    the first ``total % S`` shards), so ``sum(targets) == sum(counts)``
+    and no row is ever dropped by a donation.  ``triggered`` fires when
+    ``max(counts) * S > ratio * total`` — pure arithmetic on the counts
+    vector, so every shard (or mesh device) computes the identical plan.
+    """
+    s = counts.shape[0]
+    total = jnp.sum(counts)
+    base = total // s
+    rem = total - base * s
+    targets = (base + (jnp.arange(s, dtype=jnp.int32) < rem)) \
+        .astype(jnp.int32)
+    trig = (total > 0) & (jnp.max(counts).astype(jnp.float32) * s
+                          > float(ratio) * total.astype(jnp.float32))
+    moved = jnp.sum(jnp.maximum(counts - targets, 0))
+    return targets, trig, moved
+
+
+def record_stats(stats_h, tracker=None) -> None:
+    """Count one rung's shard-health vector ``[donation_events,
+    donated_rows, idle_shard_steps, peak_shard_occupancy]`` (host ints)
+    into the tracker; shared by the mesh and the vmapped-shard rungs."""
+    ev, moved, idle, peak = (int(x) for x in stats_h)
+    tr = telemetry.get(tracker)
+    tr.count(shard_donations=ev, shard_donated_rows=moved,
+             shard_idle_steps=idle)
+    tr.gauge_max("shard_peak_occupancy", peak)
 
 
 def make_solver_mesh(devices=None) -> Mesh:
@@ -79,11 +152,11 @@ def _local_expand(adj, states, count, k, allowed, *, n, cap_local, block,
 def _build_buckets(rows, count, ndev, cap_send, w):
     """Group valid rows by owner device -> (send (ndev, cap_send, W),
     send_counts (ndev,), dropped).  Thin prefix-count adapter over the
-    shared ownership router in ``core.shard`` (same hash, same sort/scatter
+    shared ownership router ``route_states`` (same hash, same sort/scatter
     on a single device's shards and on the mesh)."""
     del w
     valid = jnp.arange(rows.shape[0], dtype=jnp.int32) < count
-    return shard_lib.route_states(rows, valid, ndev, cap_send)
+    return route_states(rows, valid, ndev, cap_send)
 
 
 def _donate(buf, cnt, counts_all, me, *, ndev, cap_local, cap_send, w,
@@ -91,7 +164,7 @@ def _donate(buf, cnt, counts_all, me, *, ndev, cap_local, cap_send, w,
     """Mesh work donation: rebalance post-dedup rows across devices.
 
     Every device computes the identical water-filling plan from the
-    all-gathered counts (``shard.donation_plan``), so the transfer matrix
+    all-gathered counts (``donation_plan``), so the transfer matrix
     ``T[d, e]`` needs no negotiation: device d sends its surplus rows
     (beyond its keep target) in contiguous runs to the deficit devices via
     a second ``all_to_all``, and reads its own receive counts from
@@ -101,7 +174,7 @@ def _donate(buf, cnt, counts_all, me, *, ndev, cap_local, cap_send, w,
     (buf, cnt, stats (4,) [triggered, rows_moved, idle, peak]) with stats
     identical on every device (pure functions of ``counts_all``).
     """
-    targets, trig, _moved = shard_lib.donation_plan(counts_all, donate_ratio)
+    targets, trig, _moved = donation_plan(counts_all, donate_ratio)
     give = jnp.maximum(counts_all - targets, 0)
     take = jnp.maximum(targets - counts_all, 0)
     zero1 = jnp.zeros((1,), jnp.int32)
@@ -212,8 +285,8 @@ def _dist_fns(mesh, *, n, cap_local, block, cap_send, use_mmw,
         use_mmw=use_mmw, use_simplicial=use_simplicial, schedule=schedule,
         backend=backend, donate_ratio=donate_ratio)
 
-    def fused_decide_fn(adj, states, counts, k, target, allowed):
-        """Whole decide loop device-resident: mirrors engine._fused_decide
+    def mesh_decide_fn(adj, states, counts, k, target, allowed):
+        """Whole decide loop device-resident: mirrors engine.decide_loop
         with the level step replaced by the sharded SPMD program."""
         zero = jnp.asarray(0, jnp.int32)
 
@@ -237,7 +310,7 @@ def _dist_fns(mesh, *, n, cap_local, block, cap_send, use_mmw,
                                             zero, jnp.zeros((4,), jnp.int32)))
         return jnp.sum(counts) > 0, dropped, expanded, stats
 
-    fns = (jax.jit(level_sm), jax.jit(fused_decide_fn))
+    fns = (jax.jit(level_sm), jax.jit(mesh_decide_fn))
     _DIST_FN_CACHE[key] = fns
     return fns
 
@@ -271,7 +344,7 @@ def decide_launch(g: Graph, k: int, clique, mesh: Mesh, *,
                   use_simplicial: bool = False,
                   schedule: str = "doubling", backend: str = "jax",
                   donate_ratio: Optional[float]
-                  = shard_lib.DEFAULT_DONATE_RATIO,
+                  = DEFAULT_DONATE_RATIO,
                   resume: Optional[dict] = None,
                   tracker=None) -> engine_lib.DispatchHandle:
     """Enqueue one fused mesh-sharded decide; return its in-flight handle.
@@ -284,8 +357,6 @@ def decide_launch(g: Graph, k: int, clique, mesh: Mesh, *,
     vmapped shard group.  This is the path that unifies the distributed
     solver with the serving pool: ``decide_distributed(engine="fused")``
     is launch + immediate ``result()``."""
-    from . import batch as batch_lib
-
     backend_lib.validate(backend, mode="sort", schedule=schedule,
                          use_mmw=use_mmw, use_simplicial=use_simplicial)
     n = g.n
@@ -321,7 +392,7 @@ def decide_launch(g: Graph, k: int, clique, mesh: Mesh, *,
 
     def finalize(host):
         feas, drop, exp, stats = host
-        shard_lib._record_stats(stats, tracker=tr)
+        record_stats(stats, tracker=tr)
         return [batch_lib.LaneResult(bool(feas),
                                      inexact0 or int(drop) > 0,
                                      expanded0 + int(exp))]
@@ -344,12 +415,12 @@ def decide_distributed(g: Graph, k: int, clique: list, mesh: Mesh, *,
                        checkpoint_cb=None, resume: Optional[dict] = None,
                        engine: str = "fused",
                        donate_ratio: Optional[float]
-                       = shard_lib.DEFAULT_DONATE_RATIO,
+                       = DEFAULT_DONATE_RATIO,
                        tracker=None):
     """Distributed decision: is tw(g) <= k?  Mirrors solver.decide.
 
     ``engine="fused"`` runs the whole level loop as one device-resident
-    program (the sharded analogue of ``engine.fused_decide``): zero host
+    program (the sharded analogue of ``engine.decide_loop``): zero host
     syncs until the verdict.  Per-level checkpointing needs host snapshots,
     so a ``checkpoint_cb`` forces the host loop.  ``donate_ratio`` tunes
     the per-level work donation (None disables it)."""
@@ -404,7 +475,7 @@ def decide_distributed(g: Graph, k: int, clique: list, mesh: Mesh, *,
             tr.count(host_syncs=2)
         # frontier occupancy across the mesh vs the planned local capacity
         tr.gauge_max("frontier_peak_rows", total)
-        shard_lib._record_stats(np.asarray(stats), tracker=tr)
+        record_stats(np.asarray(stats), tracker=tr)
         if checkpoint_cb is not None:
             checkpoint_cb(dict(level=level + 1, k=k, expanded=expanded,
                                inexact=inexact,
@@ -439,69 +510,3 @@ def _restore(mesh, ckpt: dict, cap_local: int, w: int):
     sh = NamedSharding(mesh, P(axes))
     return (jax.device_put(jnp.asarray(states), sh),
             jax.device_put(jnp.asarray(counts), sh))
-
-
-def solve_distributed(g: Graph, mesh: Mesh, *, cap_local: int = 1 << 14,
-                      block: int = 1 << 8, use_mmw: bool = False,
-                      use_simplicial: bool = False,
-                      schedule: str = "doubling", backend: str = "jax",
-                      use_clique: bool = True, use_paths: bool = True,
-                      use_preprocess: bool = True,
-                      checkpoint_cb=None, verbose: bool = False,
-                      engine: str = "fused",
-                      donate_ratio: Optional[float]
-                      = shard_lib.DEFAULT_DONATE_RATIO,
-                      impl: Optional[str] = None,
-                      tracker=None) -> SolveResult:
-    """Distributed analogue of solver.solve (width only, no reconstruction)."""
-    t0 = time.time()
-    if impl is not None:
-        warnings.warn("solve_distributed(impl=...) is deprecated; use "
-                      "backend=...", DeprecationWarning, stacklevel=2)
-        backend = impl
-    if g.n == 0:
-        return SolveResult(0, True, 0, 0, 0, 0.0, [], {})
-
-    parts = [g]
-    base_lb = 0
-    if use_preprocess:
-        pre = preprocess_lib.preprocess(g)
-        parts, base_lb = [b.g for b in pre.blocks], pre.lb
-
-    width, exact, expanded = base_lb, True, 0
-    lbs = ubs = base_lb
-    for part in parts:
-        if part.n - 1 <= width:
-            continue
-        clique = bounds.greedy_max_clique(part) if use_clique else []
-        lb = max(bounds.lower_bound(part), len(clique) - 1)
-        ub, _ = bounds.upper_bound(part)
-        lbs, ubs = max(lbs, lb), max(ubs, ub)
-        if lb >= ub:
-            width = max(width, ub)
-            continue
-        paths = bounds.disjoint_paths_matrix(part, cap=ub, k_min=lb) \
-            if use_paths else None
-        found = ub
-        any_inexact = False
-        for k in range(lb, ub):
-            gk = part.with_edges(bounds.paths_edges(part, paths, k)) \
-                if use_paths else part
-            feasible, inexact, exp = decide_distributed(
-                gk, k, clique, mesh, cap_local=cap_local, block=block,
-                use_mmw=use_mmw, use_simplicial=use_simplicial,
-                schedule=schedule, backend=backend,
-                checkpoint_cb=checkpoint_cb, engine=engine,
-                donate_ratio=donate_ratio, tracker=tracker)
-            expanded += exp
-            any_inexact |= inexact
-            if verbose:
-                print(f"  [dist:{part.name}] k={k} feasible={feasible} "
-                      f"exp={exp} inexact={inexact}", flush=True)
-            if feasible:
-                found = k
-                break
-        width = max(width, found)
-        exact &= not any_inexact
-    return SolveResult(width, exact, lbs, max(ubs, width), expanded,
-                       time.time() - t0, None, None)

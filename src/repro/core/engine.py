@@ -29,23 +29,23 @@ it the chunk's expansion, dedup and append under ``tw.expand``,
 a profiler trace says which part of the level a device op (or the fusion
 it ended up in) belongs to (DESIGN.md §14).
 
-One ``fused_decide`` call therefore issues exactly one dispatch and one
-device→host transfer per k, versus O(levels × chunks) for the host loop.
-The host path survives as ``engine="host"`` (reconstruction needs per-level
-snapshots, checkpointing needs per-level host callbacks).
+``decide_loop`` is compiled in one place, under the lane ``vmap`` of
+``core.batch``: a single-device rung, one lane or eight, is one dispatch
+and one device→host transfer of counts, versus O(levels × chunks) for the
+host reference loop (``solver.run_level``), which survives for the
+per-level snapshots that reconstruction needs and as the tests'
+reference.  The sharded and mesh programs (``core.shard``,
+``core.distributed``) run their own level loops over ``chunk_sweep``.
 
-``COUNTERS`` tracks dispatches and host syncs for both engines so
-``benchmarks/engine_sync.py`` can report the difference on the Table 1
-instances.
+``COUNTERS`` is a deprecated read-only view of the root tracker's
+dispatch and host-sync counts (``core.telemetry``).
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Any, Callable
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -102,9 +102,9 @@ class DispatchHandle:
     converts through ``finalize``, and caches — calling it again is free.
     ``ready()`` is a non-blocking poll of the underlying arrays.
 
-        h = fused_decide_launch(adj, allowed, k, target, n=n, cap=cap, ...)
+        h = batch.decide_lanes_async(lanes, block=block, mode=mode, ...)
         ...                       # host free while the device works
-        feasible, inexact, expanded, fr = h.result()   # the only sync
+        verdicts = h.result()     # the only sync
     """
     arrays: Any                     # pytree of in-flight device arrays
     finalize: Callable[[Any], Any]  # host values -> caller-shaped result
@@ -415,9 +415,9 @@ def decide_loop(adj, allowed, k, target, fr, *, n, cap, block, mode,
     states); ``refills`` and ``appended`` count the mid-level refills and
     the rows the levels appended (``chunk_sweep``).
 
-    Undecorated on purpose: ``fused_decide`` jits it for the single-lane
-    path, and the multi-lane engine (``core.batch``) vmaps it over a
-    leading lane axis, named by ``lane_axis``.  Under vmap the two
+    Undecorated on purpose: its one compiled user is the lane engine
+    (``batch._lanes_decide``), which vmaps it over a leading lane axis,
+    named by ``lane_axis``, for one lane or many.  Under vmap the two
     data-dependent ``while_loop``s become masked loops — a lane whose
     condition goes false has its carry frozen by the batching rule's
     ``select`` while other lanes keep stepping, which is exactly the
@@ -446,80 +446,3 @@ def decide_loop(adj, allowed, k, target, fr, *, n, cap, block, mode,
                     refills + r, appended + a)
 
     return jax.lax.while_loop(cond, body, (fr,) + (zero,) * 5)
-
-
-_fused_decide = functools.partial(
-    jax.jit,
-    static_argnames=("n", "cap", "block", "mode", "use_mmw", "m_bits",
-                     "k_hashes", "schedule", "backend",
-                     "use_simplicial"))(decide_loop)
-
-
-def fused_decide_launch(adj_dev, allowed_dev, k: int, target, *, n, cap,
-                        block, mode, use_mmw, m_bits, k_hashes, schedule,
-                        backend="jax", use_simplicial=False, fr=None,
-                        max_levels=None, tracker=None) -> DispatchHandle:
-    """Enqueue one fused decide; return its in-flight ``DispatchHandle``.
-
-    The program is dispatched (counted) but the host does NOT wait: the
-    returned handle holds the device arrays, and ``handle.result()``
-    performs the single deferred sync, yielding the same
-    ``(feasible, inexact, expanded, frontier_host)`` tuple
-    ``fused_decide`` returns.  Callers that have other host work — the
-    async solve service packing its next dispatch — do it between the
-    two."""
-    backend_lib.validate(backend, mode=mode, schedule=schedule,
-                         use_mmw=use_mmw, use_simplicial=use_simplicial,
-                         m_bits=m_bits)
-    block = validate_geometry(cap, block)
-    w = adj_dev.shape[-1]
-    if fr is None:
-        fr = frontier_lib.empty_frontier(cap, w)
-    levels = target if max_levels is None else min(target, max_levels)
-    kdev = jnp.asarray(k, dtype=jnp.int32)
-    tdev = jnp.asarray(levels, dtype=jnp.int32)
-
-    fr, level, expanded, dropped, refills, appended = _fused_decide(
-        adj_dev, allowed_dev, kdev, tdev, fr, n=n, cap=cap, block=block,
-        mode=mode, use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
-        schedule=schedule, backend=backend, use_simplicial=use_simplicial)
-    tr = telemetry.get(tracker)
-    tr.count(dispatches=1)
-
-    def finalize(host):
-        states_h, count_h, expanded_h, dropped_h, level_h, refills_h, \
-            appended_h = host
-        feasible = int(count_h) > 0
-        inexact = int(dropped_h) > 0
-        fr_host = frontier_lib.Frontier(np.asarray(states_h),
-                                        np.asarray(count_h),
-                                        np.asarray(dropped_h))
-        tr.count(refills=int(refills_h), appended_rows=int(appended_h),
-                 lane_levels=int(level_h))
-        return feasible, inexact, int(expanded_h), fr_host
-
-    return DispatchHandle((fr.states, fr.count, expanded, dropped, level,
-                           refills, appended), finalize, tracker=tr)
-
-
-def fused_decide(adj_dev, allowed_dev, k: int, target, *, n, cap, block,
-                 mode, use_mmw, m_bits, k_hashes, schedule, backend="jax",
-                 use_simplicial=False, fr=None, max_levels=None,
-                 tracker=None):
-    """Host entry point: one dispatch, one sync, full verdict.
-
-    ``fr`` seeds the frontier (defaults to the DP root {∅}); ``max_levels``
-    truncates the run (used by the parity tests to compare intermediate
-    frontiers against the host loop level by level).
-
-    Returns (feasible, inexact, expanded, frontier_host) where
-    ``frontier_host`` is the final (states, count, dropped_total) pulled to
-    the host in the same single transfer as the verdict.  This is the
-    blocking form of ``fused_decide_launch`` — launch + immediate
-    ``result()``.
-    """
-    return fused_decide_launch(
-        adj_dev, allowed_dev, k, target, n=n, cap=cap, block=block,
-        mode=mode, use_mmw=use_mmw, m_bits=m_bits, k_hashes=k_hashes,
-        schedule=schedule, backend=backend, use_simplicial=use_simplicial,
-        fr=fr, max_levels=max_levels, tracker=tracker).result()

@@ -26,15 +26,15 @@ rung concurrently (DESIGN.md §13):
 Because the union of the per-shard post-dedup frontiers equals the
 single-lane post-dedup frontier level by level (sort mode, no
 overflow), the sharded verdict, ``expanded`` count and deepening ladder
-are bit-identical to ``engine="fused"`` single-lane — see
+are bit-identical to one lane of the lane program — see
 ``tests/test_shard.py``.  Per-shard capacity equals the single-lane
 planned capacity, so a drop-free single-lane plan stays drop-free
 sharded (each shard's chunk stream and each owner's receive set are
 subsets of what the single-lane buffer provably holds).
 
-The mesh path (``mesh=``) delegates to ``core.distributed``, which
-routes through the same ``route_states`` / ``donation_plan`` helpers —
-the distributed solver and the serving pool are one engine path.
+The mesh path (``mesh=``) delegates to ``core.distributed``, whose
+``route_states`` / ``donation_plan`` helpers this module shares — the
+distributed solver and the serving pool are one engine path.
 """
 from __future__ import annotations
 
@@ -46,80 +46,19 @@ import jax
 import jax.numpy as jnp
 
 from . import backend as backend_lib
+from . import batch as batch_lib
 from . import bitset, bloom, dedup
+from . import distributed as dist_lib
 from . import engine as engine_lib
 from . import frontier as frontier_lib
 from . import telemetry
+from .distributed import (DEFAULT_DONATE_RATIO, donation_plan, record_stats,
+                          route_states)
 from .graph import Graph
 
 U32 = jnp.uint32
 
-# donate when max shard occupancy exceeds ratio × mean occupancy.  1.5
-# tolerates the multinomial noise of hash ownership on healthy levels but
-# fires on genuine skew (and on the tiny early levels, where idle shards
-# are guaranteed); <= 1.0 rebalances every level.
-DEFAULT_DONATE_RATIO = 1.5
-
-
-# --------------------------------------------------------------- ownership
-
-def route_states(rows: jnp.ndarray, valid: jnp.ndarray, nshards: int,
-                 cap_recv: int):
-    """Partition valid rows to their owner shard (murmur3 mod S).
-
-    Returns (recv (S, cap_recv, W), counts (S,), dropped).  Rows are
-    sorted by (owner, words) first, so each owner's bucket arrives
-    lexicographically sorted.  Shared by the single-device sharded engine
-    (scatter = the degenerate all_to_all) and the mesh solver in
-    ``core.distributed`` (whose buckets feed a real all_to_all).
-    """
-    m, w = rows.shape
-    owner = (bloom.murmur3_words(rows, bloom.SEED1) % np.uint32(nshards)) \
-        .astype(jnp.int32)
-    owner = jnp.where(valid, owner, nshards)       # invalid rows sort last
-    cols = (owner,) + tuple(rows[:, j] for j in range(w))
-    srt = jax.lax.sort(cols, dimension=0, num_keys=1 + w)
-    owner_s = srt[0]
-    rows_s = jnp.stack(srt[1:], axis=1)
-    counts = jnp.bincount(owner, length=nshards + 1)[:nshards] \
-        .astype(jnp.int32)
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                              jnp.cumsum(counts)[:-1].astype(jnp.int32)])
-    idx = jnp.arange(m, dtype=jnp.int32)
-    safe_owner = jnp.minimum(owner_s, nshards - 1)
-    pos = idx - starts[safe_owner]
-    ok = (owner_s < nshards) & (pos < cap_recv)
-    dest = jnp.where(ok, safe_owner * cap_recv + pos, nshards * cap_recv)
-    recv = jnp.zeros((nshards * cap_recv, w), dtype=U32)
-    recv = recv.at[dest].set(rows_s, mode="drop")
-    rcounts = jnp.minimum(counts, cap_recv)
-    dropped = jnp.sum(counts - rcounts)
-    return recv.reshape(nshards, cap_recv, w), rcounts, dropped
-
-
 # ---------------------------------------------------------------- donation
-
-def donation_plan(counts: jnp.ndarray, ratio: float):
-    """Water-filling donation targets for per-shard occupancies.
-
-    Returns (targets (S,), triggered (bool), moved (rows leaving their
-    shard)).  Targets are the balanced occupancy ``total // S`` (+1 for
-    the first ``total % S`` shards), so ``sum(targets) == sum(counts)``
-    and no row is ever dropped by a donation.  ``triggered`` fires when
-    ``max(counts) * S > ratio * total`` — pure arithmetic on the counts
-    vector, so every shard (or mesh device) computes the identical plan.
-    """
-    s = counts.shape[0]
-    total = jnp.sum(counts)
-    base = total // s
-    rem = total - base * s
-    targets = (base + (jnp.arange(s, dtype=jnp.int32) < rem)) \
-        .astype(jnp.int32)
-    trig = (total > 0) & (jnp.max(counts).astype(jnp.float32) * s
-                          > float(ratio) * total.astype(jnp.float32))
-    moved = jnp.sum(jnp.maximum(counts - targets, 0))
-    return targets, trig, moved
-
 
 def _repack(states: jnp.ndarray, counts: jnp.ndarray,
             targets: jnp.ndarray) -> jnp.ndarray:
@@ -252,14 +191,6 @@ _sharded_decide = functools.partial(
 
 # ------------------------------------------------------------ host wrappers
 
-def _record_stats(stats_h, tracker=None) -> None:
-    ev, moved, idle, peak = (int(x) for x in stats_h)
-    tr = telemetry.get(tracker)
-    tr.count(shard_donations=ev, shard_donated_rows=moved,
-             shard_idle_steps=idle)
-    tr.gauge_max("shard_peak_occupancy", peak)
-
-
 def decide_sharded_async(g: Graph, k: int, clique=(), *, shards: int,
                          mesh=None, cap: Optional[int] = None,
                          block: int = 1 << 11, mode: str = "sort",
@@ -285,11 +216,9 @@ def decide_sharded_async(g: Graph, k: int, clique=(), *, shards: int,
     DESIGN.md §8).  With ``mesh`` spanning >1 devices the rung runs on
     the mesh via ``core.distributed`` instead of vmapped shards.
     """
-    from . import batch as batch_lib
-
     shards = int(shards)
     if schedule is None:
-        schedule = "doubling" if backend == "pallas" else "while"
+        schedule = backend_lib.default_schedule(backend)
     backend_lib.validate(backend, mode=mode, schedule=schedule,
                          use_mmw=use_mmw, use_simplicial=use_simplicial,
                          m_bits=m_bits, shards=shards)
@@ -313,7 +242,6 @@ def decide_sharded_async(g: Graph, k: int, clique=(), *, shards: int,
         if cap is None:
             cap = batch_lib.plan_capacity(n, block=block,
                                           budget_bytes=budget_bytes)
-        from . import distributed as dist_lib
         return dist_lib.decide_launch(
             g, k, clique, mesh, cap_local=cap, block=block,
             use_mmw=use_mmw, use_simplicial=use_simplicial,
@@ -347,7 +275,7 @@ def decide_sharded_async(g: Graph, k: int, clique=(), *, shards: int,
 
     def finalize(host):
         counts_h, expanded_h, dropped_h, stats_h = host
-        _record_stats(stats_h, tracker=tr)
+        record_stats(stats_h, tracker=tr)
         return [batch_lib.LaneResult(int(np.sum(counts_h)) > 0,
                                      int(dropped_h) > 0, int(expanded_h))]
 

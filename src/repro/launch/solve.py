@@ -21,10 +21,12 @@ to the sequential ladder.
 
 ``--backend`` selects the op implementations through the registry
 (``repro.core.backend``): "jax" reference or the fused Pallas wavefront
-kernel ("pallas"; interpret mode off-TPU).  The pre-registry ``impl=``
-spelling survives only as a hidden deprecated alias.  Unsupported
-combinations are rejected here with a capability error before anything
-is traced.
+kernel ("pallas"; interpret mode off-TPU).  Unsupported combinations are
+rejected here with a capability error before anything is traced.
+
+Every path runs the deepening ladder of ``repro.core.ladder``; each rung
+is one dispatch of the lane program (``--shards``: the sharded program,
+``--distributed``: the mesh program).
 
 ``--cap`` defaults to auto-sizing (``repro.core.batch.plan_capacity``).
 To serve a *stream* of solve requests through one lane pool instead of
@@ -53,19 +55,16 @@ def main(argv=None):
                          "caps are split across devices, not planned)")
     ap.add_argument("--block", type=int, default=1 << 10)
     ap.add_argument("--mode", default="sort", choices=["sort", "bloom"])
-    ap.add_argument("--engine", default="fused", choices=["fused", "host"],
-                    help="wavefront driver: device-resident while_loop "
-                         "(one dispatch per k) or per-level host loop")
     ap.add_argument("--batch", type=int, default=1, metavar="LANES",
                     help="speculative deepening width: decide k..k+LANES-1 "
                          "concurrently in one multi-lane dispatch "
-                         "(core.batch; fused engine only, results "
-                         "bit-identical to --batch 1). Default 1")
+                         "(core.batch; results bit-identical to "
+                         "--batch 1). Default 1")
     ap.add_argument("--shards", type=int, default=1, metavar="S",
                     help="intra-request scale-out: split each rung's "
                          "frontier across S vmapped shard lanes with "
-                         "work donation (core.shard; fused engine only, "
-                         "results bit-identical to --shards 1). Default 1")
+                         "work donation (core.shard; results "
+                         "bit-identical to --shards 1). Default 1")
     ap.add_argument("--donate-ratio", type=float, default=None,
                     help="sharded work-donation trigger: rebalance when "
                          "the max shard exceeds ratio x mean occupancy "
@@ -76,8 +75,6 @@ def main(argv=None):
     ap.add_argument("--backend", default="jax", choices=["jax", "pallas"],
                     help="op implementations (repro.core.backend registry): "
                          "jax reference or fused pallas kernels")
-    ap.add_argument("--impl", default=None, choices=["jax", "pallas"],
-                    help=argparse.SUPPRESS)   # deprecated alias of --backend
     ap.add_argument("--schedule", default="doubling",
                     choices=["doubling", "while", "linear", "matmul"])
     ap.add_argument("--no-paths", action="store_true")
@@ -101,14 +98,11 @@ def main(argv=None):
     if args.devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
-    if args.impl is not None:
-        print("[solve] --impl is deprecated; use --backend", file=sys.stderr)
-        args.backend = args.impl
 
     from repro.core import backend as backend_lib
     from repro.core import distributed as dist_lib
     from repro.core import graph as graph_lib
-    from repro.core import solver as solver_lib
+    from repro.core import ladder, solver
 
     # fail on unsupported backend/flag combos here, with an actionable
     # message, instead of deep inside a jit
@@ -140,23 +134,23 @@ def main(argv=None):
         kw = {}
         if args.donate_ratio is not None:
             kw["donate_ratio"] = args.donate_ratio
-        res = dist_lib.solve_distributed(
+        res = ladder.solve_distributed(
             g, mesh, cap_local=cap // max(1, mesh.devices.size),
             block=args.block, use_mmw=args.mmw,
             use_simplicial=args.simplicial,
             schedule=args.schedule, backend=args.backend,
             use_clique=not args.no_clique, use_paths=not args.no_paths,
             use_preprocess=not args.no_preprocess, verbose=args.verbose,
-            engine=args.engine, **kw)
+            **kw)
     else:
-        res = solver_lib.solve(
+        res = ladder.solve(
             g, cap=args.cap, block=args.block, mode=args.mode,
             use_mmw=args.mmw, backend=args.backend, schedule=args.schedule,
             use_simplicial=args.simplicial,
             use_clique=not args.no_clique, use_paths=not args.no_paths,
             use_preprocess=not args.no_preprocess,
             reconstruct=args.reconstruct, verbose=args.verbose,
-            engine=args.engine, lanes=args.batch, shards=args.shards,
+            lanes=args.batch, shards=args.shards,
             donate_ratio=args.donate_ratio,
             heuristics=args.heuristics, seed=args.seed)
 
@@ -164,7 +158,7 @@ def main(argv=None):
           f"lb={res.lb} ub={res.ub} states_expanded={res.expanded} "
           f"time={res.time_sec:.2f}s")
     if res.order is not None:
-        width = solver_lib.order_width(g, res.order)
+        width = solver.order_width(g, res.order)
         print(f"[solve] elimination order verified: width={width}")
     return 0
 

@@ -9,7 +9,7 @@ continuous-batching lane scheduler (``repro.serve.twscheduler``).
 Every request is one graph; the scheduler packs all in-flight requests'
 current deepening rungs into shared multi-lane dispatches (DESIGN.md
 §10).  ``--compare`` additionally runs the same stream through
-sequential per-request ``solver.solve`` calls, asserts result parity,
+sequential per-request ``ladder.solve`` calls, asserts result parity,
 and reports the dispatch/sync reduction.
 
 This CLI drains one fixed stream and exits; for the long-lived service
@@ -78,7 +78,7 @@ def main(argv=None):
     from repro.core import backend as backend_lib
     from repro.core import engine as engine_lib
     from repro.core import graph as graph_lib
-    from repro.core import solver as solver_lib
+    from repro.core import ladder, solver
     from repro.core.bitset import n_words as bitset_words
     from repro.serve.twscheduler import TwScheduler
 
@@ -129,7 +129,7 @@ def main(argv=None):
                 f"exact={r.exact} lb={r.lb} ub={r.ub} "
                 f"expanded={r.expanded}")
         if r.order is not None:
-            line += f" order_width={solver_lib.order_width(g, r.order)}"
+            line += f" order_width={solver.order_width(g, r.order)}"
         print(line, flush=True)
     print(f"[twserve] {len(gs)} requests in {dt:.2f}s "
           f"({len(gs) / max(dt, 1e-9):.2f} req/s), "
@@ -142,8 +142,8 @@ def main(argv=None):
         solve_kw.pop("cap_max", None)
         engine_lib.reset_counters()
         t0 = time.time()
-        seq = [solver_lib.solve(g, reconstruct=args.reconstruct,
-                                **solve_kw) for g in gs]
+        seq = [ladder.solve(g, reconstruct=args.reconstruct, **solve_kw)
+               for g in gs]
         seq_dt = time.time() - t0
         seq_counters = dict(engine_lib.COUNTERS)
         # bit-parity is only promised outside the §8/§10 padding caveats:

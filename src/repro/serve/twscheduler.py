@@ -21,9 +21,9 @@ requests:
     slots immediately and are packed into the *next* dispatch instead of
     waiting for an idle pool (DESIGN.md §11's overlap pipeline);
   * when the verdicts are synced, each lane's result is fed to its
-    request's ``batch.InstanceState`` (the same per-rung accounting
+    request's ``ladder.InstanceState`` (the same per-rung accounting
     ``solve``/``solve_many`` use, so results are bit-identical to
-    sequential ``solver.solve`` per request) and the slot is immediately
+    sequential ``ladder.solve`` per request) and the slot is immediately
     recycled — to the request's next rung, its next block, or the next
     queued request.
 
@@ -123,10 +123,11 @@ from repro.core import bounds_engine
 from repro.core import canon
 from repro.core import engine as engine_lib
 from repro.core import frontier as frontier_lib
+from repro.core import ladder
 from repro.core import shard as shard_lib
-from repro.core import solver as solver_lib
 from repro.core import telemetry
 from repro.core.graph import Graph
+from repro.core.solver import SolveResult
 
 from .cache import ResultCache
 from .slots import QueueFull, SlotPool
@@ -230,12 +231,12 @@ def _round32(n: int) -> int:
 class TwScheduler:
     """Asynchronous continuous-batching scheduler over solve requests.
 
-    Constructor knobs mirror ``solver.solve`` and set the pool defaults;
+    Constructor knobs mirror ``ladder.solve`` and set the pool defaults;
     each ``submit`` may override the per-request subset (class docstring).
     ``cap=None`` (default) auto-sizes each dispatch's per-lane frontier
     buffer via ``batch.plan_capacity``; ``budget_bytes`` (int or
     ``"auto"``) bounds the whole L-lane pool.  Results per request are
-    bit-identical to ``solver.solve(g, ...)`` with the same knobs (see
+    bit-identical to ``ladder.solve(g, ...)`` with the same knobs (see
     DESIGN.md §10/§11 for the two padded-lane caveats inherited from §8).
 
     Traffic-shaping knobs (DESIGN.md §12): ``max_queue`` bounds the
@@ -292,7 +293,7 @@ class TwScheduler:
                  cache=None,
                  verbose: bool = False, tracker=None):
         if schedule is None:
-            schedule = "doubling" if backend == "pallas" else "while"
+            schedule = backend_lib.default_schedule(backend)
         backend_lib.validate(backend, mode=mode, schedule=schedule,
                              use_mmw=use_mmw, use_simplicial=use_simplicial,
                              m_bits=m_bits, lanes=int(lanes))
@@ -655,20 +656,20 @@ class TwScheduler:
                 # any prior stream state)
                 prog = self._prog.get(req.rid) or [0, max(0, req.g.n - 1),
                                                    0]
-                res = solver_lib.SolveResult(prog[1], False, prog[0],
-                                             prog[1], 0, 0.0, None, {})
+                res = SolveResult(prog[1], False, prog[0], prog[1], 0, 0.0,
+                                  None, {})
                 self._resolve_timeout(req, res)
                 return None
             if req.heuristic_only:
                 # bounds-only serving: no preprocess, no block plans, no
                 # exact rungs — just the improver lanes (DESIGN.md §15)
                 inst = bounds_engine.HeuristicState(
-                    req.g, solver_lib, seed=self._req_seed(req),
+                    req.g, seed=self._req_seed(req),
                     max_rounds=self._req_heuristics(req),
                     tracker=req.tracker)
             else:
-                inst = batch.InstanceState(
-                    req.g, solver_lib, use_preprocess=self.use_preprocess,
+                inst = ladder.InstanceState(
+                    req.g, use_preprocess=self.use_preprocess,
                     plan_kw=dict(start_k=req.start_k,
                                  seed=self._req_seed(req), **self.plan_kw),
                     reconstruct=req.reconstruct,
@@ -709,7 +710,7 @@ class TwScheduler:
         self.tracker.drop_child(f"req{req.rid}")
         return snap
 
-    def _finish(self, req: SolveRequest, inst: batch.InstanceState):
+    def _finish(self, req: SolveRequest, inst: ladder.InstanceState):
         r = inst.result
         self.done[req.rid] = r
         self.terminal[req.rid] = "done"

@@ -7,12 +7,11 @@ registry; unsupported op/backend/flag combinations raise
 inside a jit.
 """
 import os
-import warnings
 
 import pytest
 
 from repro.core import backend as backend_lib
-from repro.core import graph, solver
+from repro.core import graph, ladder, solver
 from repro.core.backend import BackendCapabilityError
 
 
@@ -95,31 +94,19 @@ def test_solver_entry_points_fail_fast():
     with pytest.raises(BackendCapabilityError):
         solver.decide(g, 3, [], schedule="while", backend="pallas", **kw)
     with pytest.raises(BackendCapabilityError):
-        solver.solve(g, cap=1 << 8, block=32, backend="pallas",
+        ladder.solve(g, cap=1 << 8, block=32, backend="pallas",
                      schedule="linear")
     with pytest.raises(BackendCapabilityError):
-        solver.solve(g, cap=1 << 8, block=32, backend="opencl")
+        ladder.solve(g, cap=1 << 8, block=32, backend="opencl")
 
 
 def test_solve_schedule_default_is_backend_aware():
     """schedule=None resolves per backend, so the pallas default just works
     instead of tripping over the jax-only 'while' schedule."""
     g = graph.petersen()
-    a = solver.solve(g, cap=1 << 10, block=32, backend="jax")
-    b = solver.solve(g, cap=1 << 10, block=32, backend="pallas")
+    a = ladder.solve(g, cap=1 << 10, block=32, backend="jax")
+    b = ladder.solve(g, cap=1 << 10, block=32, backend="pallas")
     assert a.width == b.width == 4
-
-
-def test_deprecated_impl_alias_still_routes():
-    g = graph.petersen()
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        with pytest.raises(DeprecationWarning):
-            solver.solve(g, cap=1 << 10, block=32, impl="jax")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        res = solver.solve(g, cap=1 << 10, block=32, impl="jax")
-    assert res.width == 4
 
 
 def test_cli_reports_capability_error(capsys):

@@ -11,8 +11,8 @@ Also pins the two user-facing bugfixes that ride along:
   * ``solve(reconstruct=True, use_preprocess=True)`` used to silently
     return ``order=None`` (the preprocess loop hardcoded
     ``reconstruct=False``);
-  * ``solve_block`` with ``start_k >= ub`` used to overwrite the genuine
-    lower bound and report ``exact=True`` with zero search.
+  * a block with ``start_k >= ub`` used to overwrite the genuine lower
+    bound and report ``exact=True`` with zero search.
 """
 import warnings
 
@@ -21,7 +21,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import backend as backend_lib
-from repro.core import batch, engine, graph, preprocess, solver
+from repro.core import batch, engine, graph, ladder, preprocess, solver
 
 BLOCK = 32
 FAST = dict(cap=1 << 12, block=BLOCK)
@@ -38,7 +38,7 @@ DECIDE_KW = dict(cap=1 << 10, block=BLOCK, m_bits=1 << 12, k_hashes=4,
                  schedule="doubling")
 
 
-# ------------------------------------------------------------ decide_batch
+# ------------------------------------------------- speculative k-windows
 
 @pytest.mark.parametrize("backend", ["jax", "pallas"])
 @pytest.mark.parametrize("cfg", CONFIGS, ids=CONFIG_IDS)
@@ -48,8 +48,8 @@ def test_decide_batch_matches_sequential_decide(cfg, backend):
     padding caveats apply)."""
     g = graph.petersen()
     ks = list(range(2, 6))
-    lanes = batch.decide_batch(g, ks, [], backend=backend, **DECIDE_KW,
-                               **cfg)
+    lanes = batch.decide_lanes([batch.Lane(g, k) for k in ks],
+                               backend=backend, **DECIDE_KW, **cfg)
     for k, lane in zip(ks, lanes):
         ref = solver.decide(g, k, [], engine="fused", backend=backend,
                             **DECIDE_KW, **cfg)
@@ -74,7 +74,8 @@ def test_decide_batch_random_graphs_with_clique(seed):
     kw = dict(cap=512, block=BLOCK, m_bits=1 << 12, k_hashes=4,
               schedule="doubling", mode="sort", use_mmw=False,
               use_simplicial=False)
-    lanes = batch.decide_batch(g, ks, clique, **kw)
+    lanes = batch.decide_lanes([batch.Lane(g, k, tuple(clique)) for k in ks],
+                               **kw)
     for k, lane in zip(ks, lanes):
         ref = solver.decide(g, k, clique, engine="fused", **kw)
         assert (lane.feasible, lane.inexact, lane.expanded) == \
@@ -149,11 +150,33 @@ def test_decide_lanes_trivial_target_matches_decide_early_return():
         (ref.feasible, ref.inexact, ref.expanded) == (True, False, 0)
 
 
+def test_single_lane_decide_copies_counts_only():
+    """A one-lane rung (``solver.decide``'s device path) keeps its
+    frontier on device: the handle's arrays hold per-lane counts only,
+    no ``(cap, W)`` buffer, so its one host sync copies a few ints."""
+    g = graph.myciel(3)
+    cap = 1 << 10
+    h = batch.decide_lanes_async([batch.Lane(g, 4)], cap=cap, block=BLOCK,
+                                 mode="sort", use_mmw=False, m_bits=1,
+                                 k_hashes=1, schedule="doubling")
+    import jax
+    leaves = jax.tree_util.tree_leaves(h.arrays)
+    assert leaves and all(leaf.ndim == 1 and leaf.shape[0] == 1
+                          for leaf in leaves), [x.shape for x in leaves]
+    assert sum(leaf.size for leaf in leaves) < cap
+    [res] = h.result()
+    ref = solver.decide(g, 4, [], engine="host", cap=cap, block=BLOCK,
+                        mode="sort", use_mmw=False, m_bits=1, k_hashes=1,
+                        schedule="doubling")
+    assert (res.feasible, res.inexact, res.expanded) == \
+        (ref.feasible, ref.inexact, ref.expanded)
+
+
 def test_lanes_capability_validation():
     with pytest.raises(backend_lib.BackendCapabilityError):
         backend_lib.validate("jax", lanes=0)
     with pytest.raises(backend_lib.BackendCapabilityError):
-        solver.solve(graph.petersen(), lanes=0, **FAST)
+        ladder.solve(graph.petersen(), lanes=0, **FAST)
     # both shipped backends are vmap-safe; a non-member must be rejected
     # before tracing
     old = backend_lib.BATCHED_BACKENDS
@@ -171,13 +194,31 @@ def test_solve_speculative_lanes_agreement():
     """solve(lanes=L) is bit-identical to solve() in result AND ladder
     accounting, for several L."""
     for g in [graph.petersen(), graph.myciel(3), graph.gnp(12, 0.35, 3)]:
-        ref = solver.solve(g, **FAST)
+        ref = ladder.solve(g, **FAST)
         for lanes in (2, 3, 8):
-            got = solver.solve(g, lanes=lanes, **FAST)
+            got = ladder.solve(g, lanes=lanes, **FAST)
             assert (got.width, got.exact, got.expanded, got.lb, got.ub,
                     got.per_k) == \
                 (ref.width, ref.exact, ref.expanded, ref.lb, ref.ub,
                  ref.per_k), (g.name, lanes)
+
+
+@pytest.mark.parametrize("lanes", [1, 3])
+def test_solve_is_the_round_loop_of_one_instance(lanes):
+    """solve(g, lanes=L) and solve_many([g], speculate=L) run the same
+    round loop: same width, bounds, expanded and per-rung accounting, for
+    a graph whose preprocessing leaves several blocks."""
+    a, b = graph.myciel(3), graph.petersen()       # joined at a cut vertex
+    edges = [(int(u), int(v)) for u, v in zip(*np.nonzero(np.triu(a.adj)))]
+    edges += [(int(u) + a.n - 1, int(v) + a.n - 1)
+              for u, v in zip(*np.nonzero(np.triu(b.adj)))]
+    g = graph.from_edges(a.n + b.n - 1, edges, name="myciel3+petersen")
+    one = ladder.solve(g, lanes=lanes, **FAST)
+    [many] = ladder.solve_many([g], speculate=lanes, **FAST)
+    assert (one.width, one.exact, one.lb, one.ub, one.expanded,
+            one.per_k) == (many.width, many.exact, many.lb, many.ub,
+                           many.expanded, many.per_k)
+    assert len(one.per_k) == 2 and all(one.per_k.values())
 
 
 def test_solve_speculative_fewer_dispatches():
@@ -185,10 +226,10 @@ def test_solve_speculative_fewer_dispatches():
     in fewer dispatches at lanes=4 than sequentially."""
     g = graph.myciel(4)
     engine.reset_counters()
-    ref = solver.solve(g, **FAST)
+    ref = ladder.solve(g, **FAST)
     seq = dict(engine.COUNTERS)
     engine.reset_counters()
-    got = solver.solve(g, lanes=4, **FAST)
+    got = ladder.solve(g, lanes=4, **FAST)
     bat = dict(engine.COUNTERS)
     assert (got.width, got.exact, got.expanded) == \
         (ref.width, ref.exact, ref.expanded)
@@ -210,10 +251,10 @@ def test_solve_many_matches_sequential_solve_with_fewer_dispatches():
     full result surface) to sequential solve, in fewer total dispatches."""
     gs = _suite_graphs()
     engine.reset_counters()
-    seq = [solver.solve(g, **FAST) for g in gs]
+    seq = [ladder.solve(g, **FAST) for g in gs]
     seq_c = dict(engine.COUNTERS)
     engine.reset_counters()
-    man = batch.solve_many(gs, **FAST)
+    man = ladder.solve_many(gs, **FAST)
     bat_c = dict(engine.COUNTERS)
     for g, a, b in zip(gs, seq, man):
         assert (a.width, a.exact, a.expanded, a.lb, a.ub, a.per_k) == \
@@ -231,8 +272,8 @@ def test_solve_many_backend_mode_matrix(backend, mode):
     gs = [graph.petersen(), graph.myciel(3), graph.desargues()]
     kw = dict(cap=1 << 12, block=BLOCK, mode=mode, backend=backend,
               m_bits=1 << 14, schedule="doubling")
-    seq = [solver.solve(g, **kw) for g in gs]
-    man = batch.solve_many(gs, **kw)
+    seq = [ladder.solve(g, **kw) for g in gs]
+    man = ladder.solve_many(gs, **kw)
     for g, a, b in zip(gs, seq, man):
         assert (a.width, a.exact, a.expanded) == \
             (b.width, b.exact, b.expanded), (g.name, backend, mode)
@@ -243,8 +284,8 @@ def test_solve_many_pruning_rules_verdict_parity():
     padding-weakened-MMW caveat) but widths and exactness must match."""
     gs = [graph.petersen(), graph.myciel(3)]
     kw = dict(cap=1 << 12, block=BLOCK, use_mmw=True, use_simplicial=True)
-    seq = [solver.solve(g, **kw) for g in gs]
-    man = batch.solve_many(gs, **kw)
+    seq = [ladder.solve(g, **kw) for g in gs]
+    man = ladder.solve_many(gs, **kw)
     for g, a, b in zip(gs, seq, man):
         assert (a.width, a.exact) == (b.width, b.exact), g.name
         assert b.expanded >= a.expanded, g.name
@@ -260,8 +301,8 @@ def test_solve_many_edge_instances():
     disc_adj[5:, 5:] = graph.cycle(6).adj
     disc = graph.Graph(11, disc_adj, "disc")
     gs = [empty, single, disc, graph.petersen()]
-    seq = [solver.solve(g, **FAST) for g in gs]
-    man = batch.solve_many(gs, **FAST)
+    seq = [ladder.solve(g, **FAST) for g in gs]
+    man = ladder.solve_many(gs, **FAST)
     for g, a, b in zip(gs, seq, man):
         assert (a.width, a.exact, a.expanded, a.per_k) == \
             (b.width, b.exact, b.expanded, b.per_k), g.name
@@ -269,10 +310,10 @@ def test_solve_many_edge_instances():
 
 def test_solve_many_no_preprocess_and_speculate():
     gs = [graph.petersen(), graph.gnp(12, 0.3, 11)]
-    seq = [solver.solve(g, use_preprocess=False, **FAST) for g in gs]
+    seq = [ladder.solve(g, use_preprocess=False, **FAST) for g in gs]
     for spec in (1, 3):
-        man = batch.solve_many(gs, use_preprocess=False, speculate=spec,
-                               **FAST)
+        man = ladder.solve_many(gs, use_preprocess=False, speculate=spec,
+                                **FAST)
         for g, a, b in zip(gs, seq, man):
             assert (a.width, a.exact, a.expanded, a.lb, a.ub, a.per_k) == \
                 (b.width, b.exact, b.expanded, b.lb, b.ub, b.per_k), \
@@ -303,7 +344,7 @@ def test_reconstruct_with_preprocess_returns_certified_order():
     hardcoded reconstruct=False)."""
     for g in [graph.petersen(), _articulated_graph(), graph.grid(3, 5),
               graph.gnp(14, 0.25, 51)]:
-        r = solver.solve(g, reconstruct=True, use_preprocess=True, **FAST)
+        r = ladder.solve(g, reconstruct=True, use_preprocess=True, **FAST)
         assert r.order is not None, g.name
         assert sorted(r.order) == list(range(g.n)), g.name
         assert solver.order_width(g, r.order) <= r.width, g.name
@@ -319,7 +360,7 @@ def test_reconstruct_preprocess_property(seed):
     rng = np.random.RandomState(seed)
     n = int(rng.randint(6, 15))
     g = graph.gnp(n, float(rng.uniform(0.12, 0.3)), seed)
-    r = solver.solve(g, reconstruct=True, use_preprocess=True, **FAST)
+    r = ladder.solve(g, reconstruct=True, use_preprocess=True, **FAST)
     assert r.order is not None and sorted(r.order) == list(range(n))
     assert solver.order_width(g, r.order) <= r.width
 
@@ -341,8 +382,8 @@ def test_stitch_block_orders_handles_empty_bridge_blocks():
 
 def test_reconstruction_agrees_with_and_without_preprocess():
     g = graph.queen(5)
-    a = solver.solve(g, reconstruct=True, use_preprocess=False, **FAST)
-    b = solver.solve(g, reconstruct=True, use_preprocess=True, **FAST)
+    a = ladder.solve(g, reconstruct=True, use_preprocess=False, **FAST)
+    b = ladder.solve(g, reconstruct=True, use_preprocess=True, **FAST)
     assert a.width == b.width == 18
     assert solver.order_width(g, a.order) == 18
     assert solver.order_width(g, b.order) == 18
@@ -356,7 +397,7 @@ def test_start_k_at_or_above_ub_is_not_exact():
     g = graph.petersen()
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
-        r = solver.solve(g, use_preprocess=False, start_k=50, **FAST)
+        r = ladder.solve(g, use_preprocess=False, start_k=50, **FAST)
     assert r.expanded == 0
     assert not r.exact                       # nothing was proven
     assert r.width == r.ub                   # heuristic ub passed through
@@ -368,7 +409,7 @@ def test_start_k_forced_above_lb_feasible_immediately_is_inexact():
     """tw(petersen)=4: starting at 4 finds it feasible at once, but
     nothing proved tw > 3, so exact must be False."""
     g = graph.petersen()
-    r = solver.solve(g, use_preprocess=False, start_k=4, **FAST)
+    r = ladder.solve(g, use_preprocess=False, start_k=4, **FAST)
     assert r.width == 4 and not r.exact
 
 
@@ -376,16 +417,16 @@ def test_start_k_forced_but_ladder_proves_exactness():
     """Starting above lb but below tw: the infeasible rung below the
     answer restores the proof, so exact stays True."""
     g = graph.torus_grid(4, 4)   # genuine lb 4 < tw 6
-    ref = solver.solve(g, use_preprocess=False, **FAST)
+    ref = ladder.solve(g, use_preprocess=False, **FAST)
     assert ref.exact and ref.width == 6 and ref.lb == 4
-    r = solver.solve(g, use_preprocess=False, start_k=5, **FAST)
+    r = ladder.solve(g, use_preprocess=False, start_k=5, **FAST)
     assert r.width == 6 and r.exact
     assert r.lb == 4             # reported lb is the genuine bound
 
 
 def test_start_k_below_lb_keeps_exactness():
     g = graph.petersen()
-    r = solver.solve(g, use_preprocess=False, start_k=1, **FAST)
+    r = ladder.solve(g, use_preprocess=False, start_k=1, **FAST)
     assert r.width == 4 and r.exact
 
 
@@ -394,8 +435,8 @@ def test_start_k_speculative_lanes_agree():
     for sk in (1, 4, 50):
         with warnings.catch_warnings():
             warnings.simplefilter("ignore")
-            a = solver.solve(g, use_preprocess=False, start_k=sk, **FAST)
-            b = solver.solve(g, use_preprocess=False, start_k=sk, lanes=4,
+            a = ladder.solve(g, use_preprocess=False, start_k=sk, **FAST)
+            b = ladder.solve(g, use_preprocess=False, start_k=sk, lanes=4,
                              **FAST)
         assert (a.width, a.exact, a.expanded, a.lb, a.ub) == \
             (b.width, b.exact, b.expanded, b.lb, b.ub), sk

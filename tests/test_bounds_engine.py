@@ -14,7 +14,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 import oracle
-from repro.core import batch, bounds, bounds_engine, graph, solver, telemetry
+from repro.core import bounds, bounds_engine, graph, ladder, solver, telemetry
 from repro.serve.twscheduler import TwScheduler
 
 BLOCK = 32
@@ -129,8 +129,8 @@ PLAN_KW = dict(use_clique=True, use_paths=True, start_k=None)
 
 def test_instance_improve_bounds_clamps_the_ladder_monotonically():
     g = graph.queen(5)                     # tw 18: a long ladder
-    inst = batch.InstanceState(g, solver, use_preprocess=False,
-                               plan_kw=dict(PLAN_KW))
+    inst = ladder.InstanceState(g, use_preprocess=False,
+                                plan_kw=dict(PLAN_KW))
     run = inst.run
     lb0, ub0 = inst.bounds()
     # a worse ub (no certificate needed to reject) and a worse lb: no-op
@@ -150,12 +150,12 @@ def test_instance_improve_bounds_clamps_the_ladder_monotonically():
 
 def test_instance_improve_bounds_ub_certificate_can_finish_the_run():
     g = graph.petersen()
-    inst = batch.InstanceState(g, solver, use_preprocess=False,
-                               plan_kw=dict(PLAN_KW))
+    inst = ladder.InstanceState(g, use_preprocess=False,
+                                plan_kw=dict(PLAN_KW))
     lb0, _ub0 = inst.bounds()
     # hand it a perfect certificate: an order of the exact width, with
     # lb pushed to meet it -> the run finishes without any DP rung
-    r = solver.solve(g, reconstruct=True, use_preprocess=False, **FAST)
+    r = ladder.solve(g, reconstruct=True, use_preprocess=False, **FAST)
     out = inst.improve_bounds(lb=r.width, ub=r.width, ub_order=r.order)
     assert out["finished"] and inst.result is not None
     assert inst.result.width == r.width and inst.result.exact
@@ -175,7 +175,7 @@ def test_pool_with_improver_lanes_keeps_exact_verdicts(heuristics):
     rids = [sched.submit(g) for g in gs]
     done = sched.run()
     for rid, g in zip(rids, gs):
-        ref = solver.solve(g, **FAST)
+        ref = ladder.solve(g, **FAST)
         assert (done[rid].width, done[rid].exact) == \
             (ref.width, ref.exact), g.name
         if heuristics == 0:
@@ -194,7 +194,7 @@ def test_improver_lanes_skip_exact_rungs_and_stream_bounds(
     sched = TwScheduler(lanes=1, pipeline=2, heuristics=8, **FAST)
     rid = sched.submit(graph.petersen(), start_k=0, on_event=evs.append)
     done = sched.run()
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert (done[rid].width, done[rid].exact) == (ref.width, ref.exact)
 
     base = TwScheduler(lanes=1, pipeline=2, **FAST)
@@ -218,11 +218,11 @@ def test_solver_heuristics_knob_plans_a_tighter_ladder():
     """solve(heuristics=N) applies the same improvers at plan time:
     same verdict, never more expanded states."""
     g = graph.petersen()
-    a = solver.solve(g, start_k=0, **FAST)
-    b = solver.solve(g, start_k=0, heuristics=8, **FAST)
+    a = ladder.solve(g, start_k=0, **FAST)
+    b = ladder.solve(g, start_k=0, heuristics=8, **FAST)
     assert (a.width, a.exact) == (b.width, b.exact)
     assert b.expanded <= a.expanded
-    c = solver.solve(g, start_k=0, heuristics=8, **FAST)
+    c = ladder.solve(g, start_k=0, heuristics=8, **FAST)
     assert (b.width, b.expanded, b.per_k) == (c.width, c.expanded, c.per_k)
 
 
@@ -273,7 +273,7 @@ def test_heuristic_only_mixes_with_exact_requests_in_one_pool():
     r_h = sched.submit(graph.mcgee(), heuristic_only=True, heuristics=4)
     r_e = sched.submit(graph.petersen())
     done = sched.run()
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert (done[r_e].width, done[r_e].exact, done[r_e].expanded) == \
         (ref.width, ref.exact, ref.expanded)
     assert done[r_h].lb <= 7 <= done[r_h].ub     # mcgee tw = 7

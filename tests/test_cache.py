@@ -247,7 +247,7 @@ def test_admission_error_is_never_inserted(monkeypatch):
     def boom(*a, **kw):
         raise RuntimeError("admission blew up")
 
-    monkeypatch.setattr(twscheduler.batch, "InstanceState", boom)
+    monkeypatch.setattr(twscheduler.ladder, "InstanceState", boom)
     rid = sched.submit(graph.petersen())
     sched.run()
     assert sched.terminal[rid] == "error"

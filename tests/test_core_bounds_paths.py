@@ -1,6 +1,6 @@
 """The paths rule (Clautiaux et al., the paper's rule 2) on the ladder.
 
-``solver.plan_block`` computes disjoint-path counts only for pairs that
+``ladder.plan_block`` computes disjoint-path counts only for pairs that
 can add an edge to some ``G_k`` with ``k0 <= k < ub`` (non-adjacent, both
 degrees at least ``k0 + 1``), and stops each max-flow at ``ub``.  These
 tests pin every ``G_k`` the ladder can read to the one built from the
@@ -15,7 +15,7 @@ import os
 import numpy as np
 import pytest
 
-from repro.core import batch, bounds, graph, solver, telemetry
+from repro.core import bounds, graph, ladder, telemetry
 from repro.serve.twscheduler import TwScheduler
 
 GOLDEN = json.load(open(os.path.join(os.path.dirname(__file__),
@@ -88,7 +88,7 @@ def _reference(name: str, cap: int) -> np.ndarray:
 
 
 def _plan(g, start_k=None):
-    return solver.plan_block(g, use_clique=True, use_paths=True,
+    return ladder.plan_block(g, use_clique=True, use_paths=True,
                              start_k=start_k)
 
 
@@ -150,9 +150,9 @@ def test_plan_counts_the_flows_it_ran(name, flows):
     else:
         assert 0 < plan.paths_flows < plan.paths_pairs
     tr = telemetry.root().child(f"paths_{name}")
-    batch.InstanceState(g, solver, use_preprocess=True,
-                        plan_kw=dict(use_clique=True, use_paths=True,
-                                     start_k=None), tracker=tr)
+    ladder.InstanceState(g, use_preprocess=True,
+                         plan_kw=dict(use_clique=True, use_paths=True,
+                                      start_k=None), tracker=tr)
     assert tr.value("paths_flows") == plan.paths_flows
     assert tr.value("paths_pairs") == plan.paths_pairs
 

@@ -4,7 +4,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import bitset, components, graph, mmw, solver
+from repro.core import bitset, components, graph, ladder, mmw
 
 
 def _jax_mmw(g, s, k=1000):
@@ -38,7 +38,7 @@ def test_mmw_is_lower_bound(seed):
     n = rng.randint(4, 14)
     g = graph.gnp(n, 0.4, seed)
     lb = _jax_mmw(g, set())
-    tw = solver.solve(g, cap=1 << 12, block=1 << 6).width
+    tw = ladder.solve(g, cap=1 << 12, block=1 << 6).width
     assert lb <= tw, (g.name, lb, tw)
 
 
@@ -52,7 +52,7 @@ def test_early_exit_prunes():
 def test_solver_mmw_equivalent_results():
     for name in ["petersen", "mcgee", "grid6x6"]:
         g = graph.REGISTRY[name]()
-        a = solver.solve(g, cap=1 << 14, block=1 << 8, use_mmw=False)
-        b = solver.solve(g, cap=1 << 14, block=1 << 8, use_mmw=True)
+        a = ladder.solve(g, cap=1 << 14, block=1 << 8, use_mmw=False)
+        b = ladder.solve(g, cap=1 << 14, block=1 << 8, use_mmw=True)
         assert a.width == b.width
         assert b.expanded <= a.expanded   # MMW can only prune

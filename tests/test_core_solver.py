@@ -5,7 +5,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 import oracle
-from repro.core import bounds, graph, preprocess, solver
+from repro.core import bounds, graph, ladder, preprocess, solver
 
 FAST = dict(cap=1 << 16, block=1 << 9)
 
@@ -18,14 +18,14 @@ HEAVY = oracle.golden_widths()
 @pytest.mark.parametrize("name,gf,want", KNOWN, ids=[c[0] for c in KNOWN])
 def test_known_treewidth(name, gf, want):
     g = gf()
-    r = solver.solve(g, **FAST)
+    r = ladder.solve(g, **FAST)
     assert r.exact and r.width == want, (g.name, r)
 
 
 @pytest.mark.slow
 def test_grid5x5_heavy():
     """Grids are state-heavy (cf. the paper's 8x6 torus at 2.1e9 states)."""
-    r = solver.solve(graph.grid(5, 5), cap=1 << 19, block=1 << 11)
+    r = ladder.solve(graph.grid(5, 5), cap=1 << 19, block=1 << 11)
     assert r.exact and r.width == HEAVY["grid5x5"]["tw"]
 
 
@@ -33,23 +33,23 @@ def test_mcgee_overflow_semantics():
     """With a small list cap the run overflows: the found width is still the
     true value here (paper: myciel5 found exactly despite overflow), but the
     result must be flagged inexact."""
-    r = solver.solve(graph.mcgee(), cap=1 << 16, block=1 << 9)
+    r = ladder.solve(graph.mcgee(), cap=1 << 16, block=1 << 9)
     assert r.width == HEAVY["mcgee"]["tw"] and not r.exact
 
 
 @pytest.mark.slow
 def test_mcgee_exact():
-    r = solver.solve(graph.mcgee(), cap=1 << 22, block=1 << 12)
+    r = ladder.solve(graph.mcgee(), cap=1 << 22, block=1 << 12)
     assert r.exact and r.width == HEAVY["mcgee"]["tw"]
 
 
 def test_relabel_invariance():
     rng = np.random.RandomState(3)
     g = graph.queen(5)
-    base = solver.solve(g, **FAST).width
+    base = ladder.solve(g, **FAST).width
     for _ in range(2):
         perm = rng.permutation(g.n)
-        assert solver.solve(g.relabel(perm), **FAST).width == base
+        assert ladder.solve(g.relabel(perm), **FAST).width == base
 
 
 @given(st.integers(0, 1000))
@@ -60,15 +60,15 @@ def test_property_partial_ktree_bound(seed):
     k = rng.randint(1, 4)
     n = rng.randint(k + 2, 16)
     g = graph.random_partial_ktree(n, k, drop=0.3, seed=seed)
-    r = solver.solve(g, **FAST)
+    r = ladder.solve(g, **FAST)
     assert r.exact and r.width <= k
 
 
 def test_bloom_mode_agrees():
     for name in ["petersen", "myciel3", "queen5_5"]:
         g = graph.REGISTRY.get(name, lambda: graph.petersen())()
-        a = solver.solve(g, mode="sort", **FAST)
-        b = solver.solve(g, mode="bloom", m_bits=1 << 22, **FAST)
+        a = ladder.solve(g, mode="sort", **FAST)
+        b = ladder.solve(g, mode="bloom", m_bits=1 << 22, **FAST)
         assert a.width == b.width
 
 
@@ -81,20 +81,20 @@ def test_disconnected_graph():
     adj[:5, :5] = a.adj
     adj[5:, 5:] = b.adj
     g = graph.Graph(n, adj, "disc")
-    r = solver.solve(g, **FAST)
+    r = ladder.solve(g, **FAST)
     assert r.exact and r.width == 4
 
 
 def test_overflow_marks_inexact():
     g = graph.queen(5)
-    r = solver.solve(g, cap=64, block=32, use_preprocess=False, use_paths=False)
+    r = ladder.solve(g, cap=64, block=32, use_preprocess=False, use_paths=False)
     # tiny capacity must either still find the right answer or mark inexact
     assert (not r.exact) or r.width == 18
 
 
 def test_reconstruction_order_is_valid():
     g = graph.petersen()
-    r = solver.solve(g, use_preprocess=False, reconstruct=True, **FAST)
+    r = ladder.solve(g, use_preprocess=False, reconstruct=True, **FAST)
     assert r.order is not None and len(r.order) == g.n
     assert sorted(r.order) == list(range(g.n))
     assert solver.order_width(g, r.order) == r.width == 4
@@ -102,7 +102,7 @@ def test_reconstruction_order_is_valid():
 
 def test_reconstruction_queen5():
     g = graph.queen(5)
-    r = solver.solve(g, use_preprocess=False, reconstruct=True, **FAST)
+    r = ladder.solve(g, use_preprocess=False, reconstruct=True, **FAST)
     assert solver.order_width(g, r.order) == 18
 
 
@@ -111,22 +111,22 @@ def test_preprocess_block_safety():
     rng = random.Random(11)
     for seed in range(3):
         g = graph.gnp(14, 0.25, 50 + seed)
-        a = solver.solve(g, use_preprocess=True, **FAST)
-        b = solver.solve(g, use_preprocess=False, **FAST)
+        a = ladder.solve(g, use_preprocess=True, **FAST)
+        b = ladder.solve(g, use_preprocess=False, **FAST)
         assert a.width == b.width, (seed, a.width, b.width)
 
 
 def test_schedules_agree():
     g = graph.myciel(3)
-    widths = {s: solver.solve(g, schedule=s, **FAST).width
+    widths = {s: ladder.solve(g, schedule=s, **FAST).width
               for s in ("doubling", "while", "linear")}
     assert set(widths.values()) == {5}
 
 
 def test_expanded_counts_deterministic():
     g = graph.petersen()
-    a = solver.solve(g, **FAST)
-    b = solver.solve(g, **FAST)
+    a = ladder.solve(g, **FAST)
+    b = ladder.solve(g, **FAST)
     assert a.expanded == b.expanded
 
 

@@ -27,17 +27,18 @@ def _run(script: str, devices: int = 8, timeout: int = 420):
 
 def test_distributed_matches_single_device():
     stdout = _run("""
-        from repro.core import graph, distributed, solver
+        from repro.core import graph, distributed, ladder
         mesh = distributed.make_solver_mesh()
         assert mesh.devices.size == 8
         for name, want in [("petersen", 4), ("myciel3", 5), ("queen5_5", 18)]:
             g = graph.REGISTRY[name]()
-            r = distributed.solve_distributed(g, mesh, cap_local=1 << 12,
-                                              block=1 << 6)
-            s = solver.solve(g, cap=1 << 15, block=1 << 9)
+            r = ladder.solve_distributed(g, mesh, cap_local=1 << 12,
+                                         block=1 << 6)
+            s = ladder.solve(g, cap=1 << 15, block=1 << 9)
             assert r.width == s.width == want, (name, r.width, s.width)
             assert r.exact and s.exact
             assert r.expanded == s.expanded, (name, r.expanded, s.expanded)
+            assert r.per_k == s.per_k, (name, r.per_k, s.per_k)
         print("MATCH-OK")
     """)
     assert "MATCH-OK" in stdout
@@ -72,12 +73,11 @@ def test_checkpoint_restart_and_elastic():
 
 def test_overflow_marks_inexact_distributed():
     stdout = _run("""
-        from repro.core import graph, distributed
+        from repro.core import graph, distributed, ladder
         mesh = distributed.make_solver_mesh()
         g = graph.queen(5)
-        r = distributed.solve_distributed(g, mesh, cap_local=32, block=32,
-                                          use_preprocess=False,
-                                          use_paths=False)
+        r = ladder.solve_distributed(g, mesh, cap_local=32, block=32,
+                                     use_preprocess=False, use_paths=False)
         assert (not r.exact) or r.width == 18
         print("OVERFLOW-OK", r.width, r.exact)
     """)
@@ -86,13 +86,13 @@ def test_overflow_marks_inexact_distributed():
 
 def test_mmw_distributed():
     stdout = _run("""
-        from repro.core import graph, distributed
+        from repro.core import graph, distributed, ladder
         mesh = distributed.make_solver_mesh()
         g = graph.petersen()
-        a = distributed.solve_distributed(g, mesh, cap_local=1 << 11,
-                                          block=1 << 6, use_mmw=True)
-        b = distributed.solve_distributed(g, mesh, cap_local=1 << 11,
-                                          block=1 << 6, use_mmw=False)
+        a = ladder.solve_distributed(g, mesh, cap_local=1 << 11,
+                                     block=1 << 6, use_mmw=True)
+        b = ladder.solve_distributed(g, mesh, cap_local=1 << 11,
+                                     block=1 << 6, use_mmw=False)
         assert a.width == b.width == 4
         assert a.expanded <= b.expanded
         print("MMW-OK")

@@ -1,17 +1,19 @@
-"""Engine and backend parity: host vs fused, jax vs pallas, bit for bit.
+"""Engine and backend parity: host loop vs lane program, jax vs pallas.
 
-The fused engine must be a pure performance transform: same frontiers, same
-verdicts, same drop accounting, bit for bit.  Both engines are driven with
-the same pinned ``block`` so their chunk partitioning — and therefore their
-dedup and overflow behaviour — is identical; any divergence is a bug in the
+The device program (one lane of ``batch._lanes_decide``, the compiled
+``engine.decide_loop``) must be a pure performance transform of the host
+reference loop (``solver.run_level``): same frontiers, same verdicts, same
+drop accounting, bit for bit.  Both are driven with the same pinned
+``block`` so their chunk partitioning — and therefore their dedup and
+overflow behaviour — is identical; any divergence is a bug in the
 while_loop fusion, not legitimate nondeterminism.
 
-The same contract holds across the backend axis (ISSUE 2): the fused
-pallas wavefront kernel dispatched by ``backend="pallas"`` must reproduce
-the jax reference composition exactly, for every engine × dedup mode ×
-pruning flag — pinned here as a backend × engine matrix on interpret-mode
-pallas, with registry capability errors for the combinations that are
-genuinely unsupported.
+The same contract holds across the backend axis: the fused pallas
+wavefront kernel dispatched by ``backend="pallas"`` must reproduce the jax
+reference composition exactly, for every engine × dedup mode × pruning
+flag — pinned here as a backend × engine matrix on interpret-mode pallas,
+with registry capability errors for the combinations that are genuinely
+unsupported.
 
 Also pins the engine's contract: O(1) dispatches/host syncs per decide, and
 end-to-end ``solve`` agreement with a pure-python Held-Karp treewidth
@@ -25,8 +27,8 @@ import jax.numpy as jnp
 
 import oracle
 from repro.core import backend as backend_lib
-from repro.core import bitset, engine, frontier as frontier_lib
-from repro.core import graph, solver
+from repro.core import batch, bitset, engine, frontier as frontier_lib
+from repro.core import graph, ladder, solver
 
 BLOCK = 32          # pinned: host run_level adapts within [32, block], so 32
                     # forces identical chunking in both engines
@@ -44,6 +46,24 @@ def _devify(g):
     adj = jnp.asarray(g.packed())
     allowed = jnp.asarray(np.asarray(bitset.full(g.n)))
     return adj, allowed
+
+
+def _one_lane(adj, allowed, k, target, *, cap, fr=None, **kw):
+    """One lane of the lane program (``batch._lanes_decide``), run for up
+    to ``target`` levels from ``fr`` (default the DP root).  Returns
+    (feasible, inexact, expanded, frontier, refills, appended), the
+    frontier without its lane axis and with the rung's dropped total."""
+    if fr is None:
+        fr = frontier_lib.empty_frontier(cap, adj.shape[-1])
+    out, _lvl, exp, drop, ref, app = batch._lanes_decide(
+        adj[None], allowed[None], jnp.asarray([k], jnp.int32),
+        jnp.asarray([target], jnp.int32),
+        frontier_lib.Frontier(fr.states[None], jnp.asarray(fr.count)[None],
+                              jnp.asarray(fr.dropped)[None]),
+        cap=cap, **kw)
+    fr1 = frontier_lib.Frontier(out.states[0], out.count[0], drop[0])
+    return (int(fr1.count) > 0, int(drop[0]) > 0, int(exp[0]), fr1,
+            int(ref[0]), int(app[0]))
 
 
 def _host_levels(adj, allowed, k, levels, *, n, cap, **kw):
@@ -80,7 +100,7 @@ def test_frontier_parity_random_graphs(cfg, seed):
               schedule="doubling", backend="jax", **cfg)
 
     fr_h, exp_h, drop_h = _host_levels(adj, allowed, k, target, **kw)
-    feas_f, inexact_f, exp_f, fr_f = engine.fused_decide(
+    feas_f, inexact_f, exp_f, fr_f, _r, _a = _one_lane(
         adj, allowed, k, target, block=BLOCK, **kw)
 
     assert exp_f == exp_h
@@ -161,10 +181,10 @@ def test_backend_frontier_bit_parity(cfg):
         adj, allowed = _devify(g)
         out = {}
         for backend in BACKENDS:
-            out[backend] = engine.fused_decide(
+            out[backend] = _one_lane(
                 adj, allowed, k, target, n=n, cap=cap, block=BLOCK,
                 m_bits=1 << 12, k_hashes=4, schedule="doubling",
-                backend=backend, **cfg)
+                backend=backend, **cfg)[:4]
         (feas_j, inex_j, exp_j, fr_j) = out["jax"]
         (feas_p, inex_p, exp_p, fr_p) = out["pallas"]
         assert (feas_j, inex_j, exp_j) == (feas_p, inex_p, exp_p)
@@ -185,39 +205,67 @@ def test_unsupported_backend_combos_fail_at_dispatch():
     with pytest.raises(backend_lib.BackendCapabilityError):
         solver.decide(g, 3, [], schedule="doubling", backend="rocm", **kw)
     with pytest.raises(backend_lib.BackendCapabilityError):
-        engine.fused_decide(*_devify(g), 3, 5, n=g.n, cap=1 << 8,
-                            block=BLOCK, mode="bloom", use_mmw=False,
-                            m_bits=100, k_hashes=4, schedule="doubling",
-                            backend="pallas")
+        batch.decide_lanes([batch.Lane(g, 3)], cap=1 << 8, block=BLOCK,
+                           mode="bloom", use_mmw=False, m_bits=100,
+                           k_hashes=4, schedule="doubling",
+                           backend="pallas")
 
 
 def test_solve_matches_python_oracle():
-    """End-to-end fused solve() against the exact python DP
+    """End-to-end solve() against the exact python DP
     (``tests/oracle.py``, shared with the bounds-engine invariants)."""
     for seed in range(5):
         rng = np.random.RandomState(100 + seed)
         g = graph.gnp(8, float(rng.uniform(0.2, 0.6)), 100 + seed)
         want = oracle.tw_oracle(g)
-        got = solver.solve(g, cap=1 << 12, block=BLOCK, engine="fused")
+        got = ladder.solve(g, cap=1 << 12, block=BLOCK)
         assert got.exact and got.width == want, (seed, want, got)
 
 
+def _host_rungs(res, g, *, use_preprocess=True, **kw):
+    """Re-decide every rung a solve ran, on the host loop: the blocks'
+    ``G_k`` and cliques re-planned with the solve's defaults."""
+    from repro.core import preprocess
+    parts = ([b.g for b in preprocess.preprocess(g).blocks]
+             if use_preprocess else [g])
+    per_k = res.per_k if use_preprocess else {g.name: res.per_k}
+    rungs = 0
+    for part in parts:
+        if part.name not in per_k:
+            continue
+        plan = ladder.plan_block(part, use_clique=True, use_paths=True,
+                                 start_k=None)
+        for k, rung in per_k[part.name].items():
+            ref = solver.decide(plan.graph_at(k), k, plan.clique,
+                                engine="host", **kw)
+            assert (ref.feasible, ref.inexact, ref.expanded) == \
+                (rung["feasible"], rung["inexact"], rung["expanded"]), \
+                (part.name, k, ref, rung)
+            rungs += 1
+    return rungs
+
+
 def test_solve_engine_agreement_end_to_end():
-    """Full solve(): width/exact/expanded identical between engines."""
+    """Full solve(): every rung it ran, re-decided on the host loop, gives
+    the same verdict, drop flag and expanded count."""
     cases = [graph.petersen(), graph.myciel(3), graph.grid(3, 5),
              graph.gnp(13, 0.3, 7)]
+    rungs = 0
     for g in cases:
-        solve_kw = dict(cap=1 << 13, block=BLOCK)
-        a = solver.solve(g, engine="host", **solve_kw)
-        b = solver.solve(g, engine="fused", **solve_kw)
-        assert (a.width, a.exact, a.expanded) == \
-            (b.width, b.exact, b.expanded), (g.name, a, b)
+        res = ladder.solve(g, cap=1 << 13, block=BLOCK)
+        rungs += _host_rungs(res, g, cap=1 << 13, block=BLOCK, mode="sort",
+                             use_mmw=False, m_bits=1 << 24, k_hashes=17,
+                             schedule="while")
+        assert res.expanded == sum(
+            rung["expanded"] for block in res.per_k.values()
+            for rung in block.values()), g.name
+    assert rungs > 0
 
 
 @pytest.mark.parametrize("cfg", CONFIGS, ids=CONFIG_IDS)
 def test_lane_engine_frontier_bit_parity(cfg):
-    """The multi-lane engine (ISSUE 3) is a pure scheduling transform of
-    the fused engine: per-lane final frontier buffers — states, counts,
+    """The multi-lane engine is a pure scheduling transform of the host
+    reference loop: per-lane final frontier buffers — states, counts,
     drop accounting — are bit-identical to running each (k) alone."""
     from repro.core import batch, frontier as fr_lib
 
@@ -234,12 +282,13 @@ def test_lane_engine_frontier_bit_parity(cfg):
     out_fr, _lvl, exp_b, drop_b, _ref, _app = batch._lanes_decide(
         adj_b, al_b, jnp.asarray(ks, jnp.int32),
         jnp.asarray([n - (k + 1) for k in ks], jnp.int32), fr_b, **kw)
+    host_kw = {key: v for key, v in kw.items() if key != "block"}
     for i, k in enumerate(ks):
-        feas, inexact, exp, fr_ref = engine.fused_decide(
-            adj, allowed, k, n - (k + 1), **kw)
+        fr_ref, exp, dropped = _host_levels(adj, allowed, k, n - (k + 1),
+                                            **host_kw)
         assert exp == int(exp_b[i])
-        assert inexact == (int(drop_b[i]) > 0)
-        assert feas == (int(out_fr.count[i]) > 0)
+        assert (dropped > 0) == (int(drop_b[i]) > 0)
+        assert (int(fr_ref.count) > 0) == (int(out_fr.count[i]) > 0)
         np.testing.assert_array_equal(np.asarray(out_fr.states[i]),
                                       np.asarray(fr_ref.states))
         np.testing.assert_array_equal(
@@ -255,10 +304,10 @@ def test_solve_many_dispatch_reduction_quick_suite():
           ("myciel3", "petersen", "desargues")]
     kw = dict(cap=1 << 12, block=BLOCK)
     engine.reset_counters()
-    seq = [solver.solve(g, **kw) for g in gs]
+    seq = [ladder.solve(g, **kw) for g in gs]
     seq_c = dict(engine.COUNTERS)
     engine.reset_counters()
-    man = batch.solve_many(gs, **kw)
+    man = ladder.solve_many(gs, **kw)
     bat_c = dict(engine.COUNTERS)
     for a, b in zip(seq, man):
         assert (a.width, a.exact, a.expanded) == \
@@ -267,11 +316,15 @@ def test_solve_many_dispatch_reduction_quick_suite():
 
 
 def test_keep_levels_forces_host_engine():
-    """Reconstruction path still works when the fused engine is requested:
-    keep_levels falls back to the host loop and returns snapshots."""
+    """Reconstruction replays the winning rung with keep_levels, which
+    runs the host loop and returns snapshots, even from the lane ladder."""
     g = graph.petersen()
-    res = solver.solve(g, cap=1 << 13, block=BLOCK, use_preprocess=False,
-                      reconstruct=True, engine="fused")
+    assert solver.decide(g, 4, [], cap=1 << 13, block=BLOCK, mode="sort",
+                         use_mmw=False, m_bits=1, k_hashes=1,
+                         schedule="doubling", keep_levels=True,
+                         engine="fused").levels
+    res = ladder.solve(g, cap=1 << 13, block=BLOCK, use_preprocess=False,
+                       reconstruct=True)
     assert res.order is not None
     assert solver.order_width(g, res.order) == res.width == 4
 
@@ -335,27 +388,32 @@ REFILL_LEVELS = [("petersen", 4, 128, 1 << 12),
 @pytest.mark.parametrize("name,k,cap,wide", REFILL_LEVELS,
                          ids=[c[0] for c in REFILL_LEVELS])
 def test_refill_keeps_frontiers_level_by_level(name, k, cap, wide):
-    """One level at a time: the fused engine at the refill cap, the host
-    loop at the same cap and the fused engine at a cap that never refills
+    """One level at a time: the lane program at the refill cap, the host
+    loop at the same cap and the lane program at a cap that never refills
     hold the same frontier after every level, with nothing dropped."""
     from repro.core.telemetry import Tracker
     g = graph.REGISTRY[name]()
     adj, allowed = _devify(g)
     kw = dict(n=g.n, block=BLOCK, mode="sort", use_mmw=False, m_bits=1,
-              k_hashes=1, schedule="doubling", backend="jax")
+              k_hashes=1, schedule="doubling", backend="jax",
+              use_simplicial=False)
     fr_f = frontier_lib.empty_frontier(cap, 1)
     fr_h = frontier_lib.empty_frontier(cap, 1)
     fr_w = frontier_lib.empty_frontier(wide, 1)
-    tr_f, tr_h, tr_w = Tracker(), Tracker(), Tracker()
+    tr_h = Tracker()
+    refills = {"f": 0, "w": 0}
+    appended = {"f": 0, "w": 0}
     widest_stream = 0
     for _level in range(g.n - (k + 1)):
-        before = tr_f.counters().get("appended_rows", 0)
-        _, inexact, _, fr_f = engine.fused_decide(
-            adj, allowed, k, 1, cap=cap, fr=fr_f, tracker=tr_f, **kw)
-        widest_stream = max(widest_stream,
-                            tr_f.counters()["appended_rows"] - before)
-        _, inexact_w, _, fr_w = engine.fused_decide(
-            adj, allowed, k, 1, cap=wide, fr=fr_w, tracker=tr_w, **kw)
+        _, inexact, _, fr_f, r_f, a_f = _one_lane(
+            adj, allowed, k, 1, cap=cap, fr=fr_f, **kw)
+        widest_stream = max(widest_stream, a_f)
+        _, inexact_w, _, fr_w, r_w, a_w = _one_lane(
+            adj, allowed, k, 1, cap=wide, fr=fr_w, **kw)
+        refills["f"] += r_f
+        refills["w"] += r_w
+        appended["f"] += a_f
+        appended["w"] += a_w
         fr_h, stats = solver.run_level(adj, fr_h, k, allowed, cap=cap,
                                        tracker=tr_h, **kw)
         count = int(fr_f.count)
@@ -368,10 +426,9 @@ def test_refill_keeps_frontiers_level_by_level(name, k, cap, wide):
         if count == 0:
             break
     assert widest_stream > cap          # the append stream did not fit
-    assert tr_f.counters()["refills"] == tr_h.counters()["refills"] > 0
-    assert tr_w.counters()["refills"] == 0
-    assert tr_f.counters()["appended_rows"] == \
-        tr_w.counters()["appended_rows"]
+    assert refills["f"] == tr_h.counters()["refills"] > 0
+    assert refills["w"] == 0
+    assert appended["f"] == appended["w"]
 
 
 @pytest.mark.parametrize("eng", ["host", "fused"])
@@ -381,11 +438,17 @@ def test_refill_keeps_frontiers_level_by_level(name, k, cap, wide):
 def test_refill_solve_matches_reference(name, cap, eng):
     """At a cap the levels' append streams overflow, solve() refills and
     answers exactly, with the golden width (and the Held-Karp oracle's
-    where it reaches), and no rung drops a state."""
+    where it reaches), and no rung drops a state.  The host case
+    re-decides every rung on the host loop, which refills too."""
     from repro.core.telemetry import Tracker
     g = graph.REGISTRY[name]()
     tr = Tracker()
-    res = solver.solve(g, cap=cap, block=BLOCK, engine=eng, tracker=tr)
+    res = ladder.solve(g, cap=cap, block=BLOCK, tracker=tr)
+    if eng == "host":
+        tr = Tracker()
+        assert _host_rungs(res, g, cap=cap, block=BLOCK, mode="sort",
+                           use_mmw=False, m_bits=1 << 24, k_hashes=17,
+                           schedule="while", tracker=tr) > 0
     assert res.exact and res.width == _golden(name)
     if g.n <= 12:
         assert res.width == oracle.tw_oracle(g)
@@ -400,13 +463,16 @@ def test_refill_cannot_save_a_level_past_the_cap():
     cap far below the widest level."""
     from repro.core.telemetry import Tracker
     g = graph.REGISTRY["desargues"]()
-    adj, allowed = _devify(g)
     tr = Tracker()
-    _, inexact, _, fr = engine.fused_decide(
-        adj, allowed, 5, g.n - 6, n=g.n, cap=1 << 14, block=BLOCK,
+    [res] = batch.decide_lanes(
+        [batch.Lane(g, 5)], cap=1 << 14, block=BLOCK, mode="sort",
+        use_mmw=False, m_bits=1, k_hashes=1, schedule="doubling",
+        tracker=tr)
+    _, inexact, _, fr, refills, _ = _one_lane(
+        *_devify(g), 5, g.n - 6, n=g.n, cap=1 << 14, block=BLOCK,
         mode="sort", use_mmw=False, m_bits=1, k_hashes=1,
-        schedule="doubling", tracker=tr)
-    assert inexact and int(fr.dropped) > 0
-    assert tr.counters()["refills"] > 0
-    res = solver.solve(g, cap=1 << 10, block=BLOCK)
+        schedule="doubling", backend="jax", use_simplicial=False)
+    assert inexact and res.inexact and int(fr.dropped) > 0
+    assert refills == tr.counters()["refills"] > 0
+    res = ladder.solve(g, cap=1 << 10, block=BLOCK)
     assert not res.exact and res.lb <= _golden("desargues") <= res.ub

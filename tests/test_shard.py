@@ -18,7 +18,7 @@ import pytest
 
 jnp = pytest.importorskip("jax.numpy")
 
-from repro.core import bloom, engine, graph, shard, solver  # noqa: E402
+from repro.core import bloom, engine, graph, ladder, shard  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -95,28 +95,28 @@ def test_sharded_solve_bit_parity_matrix(backend, mode, mmw, simp):
     g = graph.REGISTRY["petersen"]()
     kw = dict(block=BLOCK, backend=backend, mode=mode, use_mmw=mmw,
               use_simplicial=simp)
-    ref = solver.solve(g, engine="fused", **kw)
+    ref = ladder.solve(g, **kw)
     assert ref.width == 4
     for s in (2, 3):
-        res = solver.solve(g, shards=s, **kw)
+        res = ladder.solve(g, shards=s, **kw)
         _parity(ref, res, (backend, mode, mmw, simp, s))
 
 
 def test_sharded_solve_parity_across_instances():
     for name, want in [("myciel3", 5), ("queen5_5", 18)]:
         g = graph.REGISTRY[name]()
-        ref = solver.solve(g, block=BLOCK)
+        ref = ladder.solve(g, block=BLOCK)
         assert ref.width == want
         for s in (2, 4):
-            _parity(ref, solver.solve(g, shards=s, block=BLOCK), (name, s))
+            _parity(ref, ladder.solve(g, shards=s, block=BLOCK), (name, s))
 
 
 def test_forced_skew_donation_triggers_and_preserves_parity():
     g = graph.REGISTRY["myciel3"]()
-    ref = solver.solve(g, block=BLOCK)
+    ref = ladder.solve(g, block=BLOCK)
     engine.reset_counters()
     # ratio <= 1.0 rebalances every level: the donation path runs hot
-    res = solver.solve(g, shards=4, block=BLOCK, donate_ratio=1.0)
+    res = ladder.solve(g, shards=4, block=BLOCK, donate_ratio=1.0)
     assert engine.COUNTERS["shard_donations"] > 0
     assert engine.COUNTERS["shard_donated_rows"] > 0
     _parity(ref, res, "forced-skew")
@@ -127,11 +127,11 @@ def test_forced_skew_donation_triggers_and_preserves_parity():
 def test_shards1_and_lanes1_are_exact_aliases():
     g = graph.REGISTRY["petersen"]()
     engine.reset_counters()
-    ref = solver.solve(g, block=BLOCK)
+    ref = ladder.solve(g, block=BLOCK)
     c_ref = dict(engine.COUNTERS)
     for kw in ({"shards": 1}, {"lanes": 1}):
         engine.reset_counters()
-        res = solver.solve(g, block=BLOCK, **kw)
+        res = ladder.solve(g, block=BLOCK, **kw)
         # not just equal results: the identical engine path — same
         # dispatch/sync/shard counter trace as the plain call
         assert dict(engine.COUNTERS) == c_ref, (kw, engine.COUNTERS, c_ref)
@@ -144,7 +144,7 @@ def test_shards_reject_unsupported_combos():
     from repro.core import backend as backend_lib
     g = graph.REGISTRY["petersen"]()
     with pytest.raises(backend_lib.BackendCapabilityError):
-        solver.solve(g, shards=0, block=BLOCK)
+        ladder.solve(g, shards=0, block=BLOCK)
 
 
 # ---------------------------------------------------------------- mesh
@@ -176,10 +176,10 @@ def test_vmapped_shards_match_under_forced_devices():
     # the CI job runs this file under 8 forced host devices; the vmapped
     # (mesh-free) shard path must be device-count independent
     stdout = _run("""
-        from repro.core import graph, solver
+        from repro.core import graph, ladder
         g = graph.REGISTRY["myciel3"]()
-        ref = solver.solve(g, block=1 << 6)
-        res = solver.solve(g, shards=4, block=1 << 6)
+        ref = ladder.solve(g, block=1 << 6)
+        res = ladder.solve(g, shards=4, block=1 << 6)
         assert (res.width, res.exact, res.expanded, res.per_k) == \\
             (ref.width, ref.exact, ref.expanded, ref.per_k), (res, ref)
         print("VMAP-8DEV-OK")

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import bitset, expand, graph, solver
+from repro.core import bitset, expand, graph, ladder
 
 
 def _is_simplicial_oracle(g, s, v):
@@ -78,8 +78,8 @@ def test_collapse_keeps_single_candidate():
 @pytest.mark.parametrize("name,want", [("petersen", 4), ("myciel3", 5)])
 def test_solver_simplicial_correct_and_prunes(name, want):
     g = graph.REGISTRY[name]()
-    a = solver.solve(g, cap=1 << 14, block=1 << 8)
-    b = solver.solve(g, cap=1 << 14, block=1 << 8, use_simplicial=True)
+    a = ladder.solve(g, cap=1 << 14, block=1 << 8)
+    b = ladder.solve(g, cap=1 << 14, block=1 << 8, use_simplicial=True)
     assert a.width == b.width == want
     assert b.expanded <= a.expanded
 
@@ -88,7 +88,7 @@ def test_tree_collapses_greedily():
     """Trees are chordal-ish: every state has a simplicial leaf, so the
     search degenerates to a single path (massive reduction)."""
     g = graph.random_tree(14, 5)
-    b = solver.solve(g, cap=1 << 12, block=1 << 6, use_simplicial=True,
+    b = ladder.solve(g, cap=1 << 12, block=1 << 6, use_simplicial=True,
                      use_preprocess=False, use_paths=False,
                      use_clique=False)
     assert b.width == 1

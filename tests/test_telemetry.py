@@ -14,7 +14,7 @@ import threading
 
 import pytest
 
-from repro.core import engine, graph, solver, telemetry
+from repro.core import engine, graph, ladder, telemetry
 from repro.core.telemetry import (InMemorySink, JsonlSink, NullTracker,
                                   StdoutSink, Tracker)
 
@@ -316,11 +316,11 @@ def test_null_tracker_leaves_solo_solve_counters_unchanged():
     as it found it, while the default (root) path still counts."""
     g = graph.petersen()
     engine.reset_counters()
-    res_null = solver.solve(g, cap=1 << 12, block=32,
+    res_null = ladder.solve(g, cap=1 << 12, block=32,
                             tracker=telemetry.NULL)
     assert all(v == 0 for v in engine.COUNTERS.values())
 
-    res_root = solver.solve(g, cap=1 << 12, block=32)
+    res_root = ladder.solve(g, cap=1 << 12, block=32)
     assert engine.COUNTERS["dispatches"] > 0
     assert (res_null.width, res_null.exact, res_null.expanded) == \
         (res_root.width, res_root.exact, res_root.expanded)
@@ -333,7 +333,7 @@ def test_detached_tracker_isolates_a_measurement():
     g = graph.petersen()
     engine.reset_counters()
     tr = Tracker()
-    res = solver.solve(g, cap=1 << 12, block=32, tracker=tr)
+    res = ladder.solve(g, cap=1 << 12, block=32, tracker=tr)
     assert res.width == 4
     assert tr["dispatches"] > 0
     assert tr["expanded"] == res.expanded

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import batch, bitset, engine, frontier, graph
+from repro.core import batch, bitset, frontier, graph
 from repro.core.telemetry import Tracker
 from repro.serve.twscheduler import TwScheduler
 
@@ -165,13 +165,10 @@ def test_fill_counters_of_one_padded_dispatch():
                              tracker=tr, **DECIDE)
     levels = []
     for lane in lanes:
-        args = _lanes_args([lane], 32, cap)
-        fr = frontier.empty_frontier(cap, bitset.n_words(32))
-        _fr, level, expanded, _d, _r, _a = engine._fused_decide(
-            args[0][0], args[1][0], args[2][0], args[3][0], fr, n=32,
-            cap=cap, **DECIDE)
-        levels.append(int(level))
-        assert int(expanded) == res[len(levels) - 1].expanded
+        _fr, level, expanded, _d, _r, _a = batch._lanes_decide(
+            *_lanes_args([lane], 32, cap), n=32, cap=cap, **DECIDE)
+        levels.append(int(level[0]))
+        assert int(expanded[0]) == res[len(levels) - 1].expanded
     c = tr.counters()
     assert c["lanes_decided"] == 3 and c["lane_slots"] == 8
     assert c["lane_row_slots"] == cap * sum(levels) > 0

@@ -2,7 +2,7 @@
 
 The service contract (ISSUE 4 / DESIGN.md §10): N concurrent requests
 through ``TwScheduler`` produce results bit-identical to per-request
-``solver.solve`` — width, exactness, bounds, ``expanded``, ``per_k`` and
+``ladder.solve`` — width, exactness, bounds, ``expanded``, ``per_k`` and
 (when requested) the reconstructed elimination order — in strictly fewer
 dispatches, with the pooled frontier buffers sized by
 ``batch.plan_capacity`` instead of the fixed worst-case cap.
@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from repro.core import backend as backend_lib
-from repro.core import batch, bitset, engine, frontier, graph, solver
+from repro.core import batch, bitset, engine, frontier, graph, ladder, solver
 from repro.serve.slots import SlotPool
 from repro.serve.twscheduler import TwScheduler
 
@@ -41,7 +41,7 @@ def test_service_matches_sequential_solve_with_fewer_dispatches():
     and the whole stream in fewer dispatches than per-request solving."""
     gs = _request_stream()
     engine.reset_counters()
-    seq = [solver.solve(g, **FAST) for g in gs]
+    seq = [ladder.solve(g, **FAST) for g in gs]
     seq_c = dict(engine.COUNTERS)
     engine.reset_counters()
     srv, sched = _serve(gs, **FAST)
@@ -63,7 +63,7 @@ def test_service_backend_mode_matrix(backend, mode):
     gs = [graph.petersen(), graph.myciel(3), graph.grid(3, 4)]
     kw = dict(cap=1 << 12, block=BLOCK, mode=mode, backend=backend,
               m_bits=1 << 14, schedule="doubling")
-    seq = [solver.solve(g, **kw) for g in gs]
+    seq = [ladder.solve(g, **kw) for g in gs]
     srv, _ = _serve(gs, lanes=2, **kw)
     for g, a, b in zip(gs, seq, srv):
         assert (a.width, a.exact, a.expanded, a.per_k) == \
@@ -75,7 +75,7 @@ def test_service_reconstruction_parity():
     sequential solver produces (same host-level snapshots, same backtrack),
     with expanded parity — the certification replay is uncounted."""
     gs = [graph.petersen(), graph.queen(5)]
-    seq = [solver.solve(g, reconstruct=True, **FAST) for g in gs]
+    seq = [ladder.solve(g, reconstruct=True, **FAST) for g in gs]
     srv, _ = _serve(gs, lanes=2, reconstruct=True, **FAST)
     for g, a, b in zip(gs, seq, srv):
         assert a.order == b.order, g.name
@@ -97,7 +97,7 @@ def test_service_reconstruction_stitches_articulated_instances():
             adj[u, v] = adj[v, u] = True
     adj[8, 9] = adj[9, 8] = adj[9, 10] = adj[10, 9] = True
     g = graph.Graph(12, adj, "barbell")
-    ref = solver.solve(g, reconstruct=True, **FAST)
+    ref = ladder.solve(g, reconstruct=True, **FAST)
     srv, _ = _serve([g, graph.petersen()], lanes=2, reconstruct=True, **FAST)
     assert srv[0].order is not None
     assert sorted(srv[0].order) == list(range(g.n))
@@ -115,7 +115,7 @@ def test_more_requests_than_lanes_fifo_recycling():
     assert len(srv) == len(gs)
     assert sorted(sched.done) == list(range(len(gs)))
     for g, b in zip(gs, srv):
-        a = solver.solve(g, **FAST)
+        a = ladder.solve(g, **FAST)
         assert (a.width, a.exact, a.expanded) == \
             (b.width, b.exact, b.expanded), g.name
 
@@ -132,7 +132,7 @@ def test_trivial_requests_never_occupy_a_lane():
     assert dict(engine.COUNTERS)["dispatches"] == 0
     assert sched.rounds == 0
     for g, b in zip(gs, srv):
-        a = solver.solve(g, **FAST)
+        a = ladder.solve(g, **FAST)
         assert (a.width, a.exact, a.expanded) == \
             (b.width, b.exact, b.expanded), g.name
 
@@ -143,7 +143,7 @@ def test_service_start_k_and_forced_inexactness():
     g = graph.petersen()
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")
-        seq = [solver.solve(g, use_preprocess=False, start_k=sk, **FAST)
+        seq = [ladder.solve(g, use_preprocess=False, start_k=sk, **FAST)
                for sk in (1, 4, 50)]
         sched = TwScheduler(lanes=2, use_preprocess=False, **FAST)
         rids = [sched.submit(g, start_k=sk) for sk in (1, 4, 50)]
@@ -228,8 +228,8 @@ def test_plan_capacity_is_drop_free_for_small_blocks():
     bit.  gnp(13, .5) floods levels hard; the planned cap must hold."""
     for seed in (0, 1, 2):
         g = graph.gnp(13, 0.5, seed)
-        a = solver.solve(g, cap=batch.DEFAULT_CAP, block=BLOCK)
-        b = solver.solve(g, cap=None, block=BLOCK)
+        a = ladder.solve(g, cap=batch.DEFAULT_CAP, block=BLOCK)
+        b = ladder.solve(g, cap=None, block=BLOCK)
         assert (a.width, a.exact, a.expanded, a.per_k) == \
             (b.width, b.exact, b.expanded, b.per_k), seed
         assert a.exact     # nothing dropped at the planned cap either

@@ -7,12 +7,12 @@ packed into the *next* dispatch, never waiting for an idle pool),
 per-request knob overrides coexist in one pool via config-group
 sub-dispatches, and every request can stream per-rung events whose
 running lb/ub are monotone and whose ordering is pinned — all while
-results stay bit-identical to sequential ``solver.solve``.
+results stay bit-identical to sequential ``ladder.solve``.
 """
 import pytest
 
 from repro.core import backend as backend_lib
-from repro.core import batch, engine, graph, solver
+from repro.core import batch, engine, graph, ladder
 from repro.serve.twscheduler import TwScheduler
 
 BLOCK = 32
@@ -50,26 +50,6 @@ def test_decide_lanes_async_empty_is_a_noop():
     engine.reset_counters()
     assert batch.decide_lanes_async([], **LANE_KW).result() == []
     assert all(v == 0 for v in engine.COUNTERS.values()), engine.COUNTERS
-
-
-def test_fused_decide_launch_handle_parity():
-    """engine.fused_decide == fused_decide_launch().result(), bit for bit,
-    and the handle reports ready after the sync."""
-    import jax.numpy as jnp
-    from repro.core import bitset
-    g = graph.petersen()
-    adj = jnp.asarray(g.packed())
-    allowed = jnp.asarray(bitset.np_allowed(g.n, []))
-    kw = dict(n=g.n, cap=1 << 10, block=BLOCK, mode="sort", use_mmw=False,
-              m_bits=1 << 12, k_hashes=4, schedule="while")
-    h = engine.fused_decide_launch(adj, allowed, 3, g.n - 4, **kw)
-    feas, inex, exp, fr = h.result()
-    assert h.ready()
-    feas2, inex2, exp2, fr2 = engine.fused_decide(adj, allowed, 3,
-                                                  g.n - 4, **kw)
-    assert (feas, inex, exp) == (feas2, inex2, exp2)
-    assert int(fr.count) == int(fr2.count)
-    assert (fr.states == fr2.states).all()
 
 
 # ------------------------------------------------------------- streaming
@@ -122,7 +102,7 @@ def test_streamed_per_k_deltas_reassemble_the_result_per_k():
     res = done[rid]
     searched = {blk: pk for blk, pk in res.per_k.items() if pk}
     assert got == searched
-    seq = solver.solve(g, **FAST)
+    seq = ladder.solve(g, **FAST)
     assert res.per_k == seq.per_k
 
 
@@ -133,7 +113,7 @@ def test_broken_event_sink_does_not_break_the_solve():
     with pytest.warns(UserWarning, match="event sink"):
         rid = sched.submit(graph.petersen(), on_event=sink)
         done = sched.run()
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert done[rid].width == ref.width
 
 
@@ -163,7 +143,7 @@ def test_mixed_per_request_configs_in_one_pool_match_solo_solves():
         solo_kw["cap"] = kw.get("cap", solo_kw["cap"])
         if "mode" in kw:
             solo_kw["mode"] = kw["mode"]
-        a = solver.solve(g, use_mmw=kw.get("use_mmw", False), **solo_kw)
+        a = ladder.solve(g, use_mmw=kw.get("use_mmw", False), **solo_kw)
         b = done[rid]
         assert (a.width, a.exact, a.lb, a.ub) == \
             (b.width, b.exact, b.lb, b.ub), g.name
@@ -175,7 +155,7 @@ def test_mixed_per_request_configs_in_one_pool_match_solo_solves():
 
 def test_per_request_speculate_keeps_parity_in_fewer_rounds():
     g = graph.queen(5)
-    seq = solver.solve(g, **FAST)
+    seq = ladder.solve(g, **FAST)
     rungs = sum(len(pk) for pk in seq.per_k.values())
     assert rungs > 1, "need a multi-rung ladder for this test"
     one = TwScheduler(lanes=4, **FAST)
@@ -208,9 +188,9 @@ def test_budget_splits_across_a_steps_concurrent_dispatches():
     # a binding budget may reintroduce drops (documented §10): results
     # stay correct-as-upper-bounds and every request completes
     assert set(done) == {r0, r1}
-    assert done[r0].width >= solver.solve(graph.petersen(),
+    assert done[r0].width >= ladder.solve(graph.petersen(),
                                           block=BLOCK).width
-    assert done[r1].width >= solver.solve(graph.myciel(3), use_mmw=True,
+    assert done[r1].width >= ladder.solve(graph.myciel(3), use_mmw=True,
                                           block=BLOCK).width
 
 
@@ -229,7 +209,7 @@ def test_recover_after_failed_step_keeps_serving():
     sched.recover()
     assert not sched.in_flight
     done = sched.run()                           # re-packs the same rung
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert (done[rid].width, done[rid].exact) == (ref.width, ref.exact)
 
 
@@ -241,7 +221,7 @@ def test_per_request_capability_error_is_per_request():
     with pytest.raises(ValueError):
         sched.submit(graph.petersen(), cap=100)      # not a clean geometry
     rid = sched.submit(graph.petersen())
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert sched.run()[rid].width == ref.width
 
 
@@ -275,7 +255,7 @@ def test_late_arrival_is_admitted_during_an_inflight_dispatch():
     first_rung = next(e for e in evs if e["event"] == "rung_started")
     assert first_rung["round"] == 2         # the very next dispatch
     for rid, g in ((r0, graph.queen(5)), (r1, graph.petersen())):
-        a = solver.solve(g, **FAST)
+        a = ladder.solve(g, **FAST)
         b = done[rid]
         assert (a.width, a.exact, a.expanded, a.per_k) == \
             (b.width, b.exact, b.expanded, b.per_k), g.name
@@ -305,7 +285,7 @@ def test_overlap_beats_blocking_two_phase_round_count():
 
     assert overlap.rounds < blocking.rounds
     for rid, g in zip(rids, early + late):
-        a = solver.solve(g, **FAST)
+        a = ladder.solve(g, **FAST)
         b = done[rid]
         assert (a.width, a.exact, a.expanded) == \
             (b.width, b.exact, b.expanded), g.name

@@ -6,13 +6,13 @@ with every traffic-shaping feature from DESIGN.md §12: S-slot admission
 is head-of-line (a wide request is never starved by narrow ones),
 cancel/deadline release the whole slot group, priorities still reorder
 the queue, bounded queues still shed.  Throughout, every request's
-result stays bit-identical to sequential ``solver.solve``.
+result stays bit-identical to sequential ``ladder.solve``.
 """
 import time
 
 import pytest
 
-from repro.core import graph, solver
+from repro.core import graph, ladder
 from repro.serve.slots import QueueFull, SlotPool
 from repro.serve.twscheduler import TwScheduler
 
@@ -67,7 +67,7 @@ def test_sharded_request_occupies_shards_slots():
     assert sched.pool.free == 0          # 3 + 1 slots in flight
     assert len(sched.pool.active()) == 2
     done = sched.run()
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert (done[nar].width, done[nar].expanded) == (ref.width, ref.expanded)
 
 
@@ -79,7 +79,7 @@ def test_mixed_stream_parity_with_sharded_and_narrow_requests(
     rids = [sched.submit(g, shards=s, on_event=evs.append) for g, s in gs]
     done = sched.run()
     for rid, (g, s) in zip(rids, gs):
-        ref = solver.solve(g, **FAST)
+        ref = ladder.solve(g, **FAST)
         res = done[rid]
         assert (res.width, res.exact, res.expanded, res.per_k) == \
             (ref.width, ref.exact, ref.expanded, ref.per_k), (g.name, s)
@@ -117,7 +117,7 @@ def test_deadline_preempts_a_sharded_request_with_anytime_bounds(
         req.deadline = time.monotonic() - 1.0
     done = sched.run()
     res = done[rid]
-    ref = solver.solve(graph.queen(6), **FAST)
+    ref = ladder.solve(graph.queen(6), **FAST)
     assert not res.exact
     assert res.lb <= ref.width <= res.ub
     assert sched.terminal[rid] == "timeout"
@@ -145,7 +145,7 @@ def test_urgent_narrow_overtakes_a_queued_wide_request():
     assert order == [hi, busy, wide]
     for rid, g in ((busy, graph.myciel(3)), (wide, graph.queen(4)),
                    (hi, graph.petersen())):
-        ref = solver.solve(g, **FAST)
+        ref = ladder.solve(g, **FAST)
         assert (done[rid].width, done[rid].expanded) == \
             (ref.width, ref.expanded)
 
@@ -168,8 +168,8 @@ def test_sharded_heavy_request_finishes_in_fewer_rounds():
     complete — and both runs stay bit-identical to sequential solve."""
     heavy = graph.myciel(4)
     smalls = [graph.myciel(3), graph.petersen()]
-    ref_h = solver.solve(heavy, block=1 << 10)
-    ref_s = [solver.solve(g, block=1 << 10) for g in smalls]
+    ref_h = ladder.solve(heavy, block=1 << 10)
+    ref_s = [ladder.solve(g, block=1 << 10) for g in smalls]
     done_round = {}
     for s in (1, 4):
         sched = TwScheduler(lanes=4, block=1 << 10)

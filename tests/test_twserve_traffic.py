@@ -6,7 +6,7 @@ deadline-preempted into monotone anytime bounds, prioritised without
 starving the base class, and shed with a ``retry_after`` hint when the
 admission queue is bounded — while pipelined dispatch (depth > 1) keeps
 the device busy across host syncs.  Throughout, every *surviving*
-request's result stays bit-identical to sequential ``solver.solve``,
+request's result stays bit-identical to sequential ``ladder.solve``,
 and the request lifecycle can no longer lose a request: admission
 failures resolve with an ``error`` terminal event, event sinks are
 invoked outside the scheduler lock, and duplicate rids are rejected.
@@ -16,7 +16,7 @@ import time
 
 import pytest
 
-from repro.core import graph, solver
+from repro.core import graph, ladder
 from repro.serve.slots import QueueFull, SlotPool
 from repro.serve.twscheduler import TwScheduler
 
@@ -82,7 +82,7 @@ def test_cancel_queued_request_never_runs(event_invariants):
     assert sched.cancel(drop)
     assert sched.status(drop) == {"state": "cancelled"}
     done = sched.run()
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert (done[keep].width, done[keep].expanded) == \
         (ref.width, ref.expanded)
     assert drop not in done
@@ -102,7 +102,7 @@ def test_cancel_running_request_frees_the_lane_and_keeps_parity(
     assert sched.cancel(slow)
     assert sched.pool.free == 1                # the lane freed immediately
     done = sched.run()
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert (done[fast].width, done[fast].exact, done[fast].expanded,
             done[fast].per_k) == (ref.width, ref.exact, ref.expanded,
                                   ref.per_k)
@@ -137,7 +137,7 @@ def test_deadline_preempts_mid_ladder_with_monotone_anytime_bounds(
         req.deadline = time.monotonic() - 1.0
     done = sched.run()
     res = done[rid]
-    ref = solver.solve(graph.queen(6), **FAST)
+    ref = ladder.solve(graph.queen(6), **FAST)
     assert not res.exact
     assert res.lb <= ref.width <= res.ub       # genuine anytime bounds
     assert res.expanded < ref.expanded         # preempted: partial work
@@ -166,7 +166,7 @@ def test_unhit_deadline_changes_nothing():
     sched = TwScheduler(lanes=1, **FAST)
     rid = sched.submit(graph.petersen(), deadline_s=3600.0)
     done = sched.run()
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert (done[rid].width, done[rid].exact, done[rid].expanded) == \
         (ref.width, ref.exact, ref.expanded)
     assert sched.terminal[rid] == "done"
@@ -189,7 +189,7 @@ def test_high_priority_requests_jump_the_admission_queue():
     done = sched.run()
     assert order[0] == hi and order[1] == lo
     for rid, g in ((lo, graph.myciel(3)), (hi, graph.petersen())):
-        ref = solver.solve(g, **FAST)
+        ref = ladder.solve(g, **FAST)
         assert (done[rid].width, done[rid].expanded) == \
             (ref.width, ref.expanded)
 
@@ -214,9 +214,9 @@ def test_pipeline_depth_2_matches_depth_1_with_fewer_idle_syncs():
     """Depth 2 keeps a second round in flight across each host sync
     (fewer idle host-sync gaps — the device had queued work); results and
     expanded accounting stay bit-identical to depth 1 and to sequential
-    ``solver.solve``."""
+    ``ladder.solve``."""
     suite = [graph.queen(5), graph.myciel(3), graph.petersen()]
-    refs = [solver.solve(g, **FAST) for g in suite]
+    refs = [ladder.solve(g, **FAST) for g in suite]
     stats = {}
     for depth in (1, 2):
         sched = TwScheduler(lanes=3, pipeline=depth, **FAST)
@@ -253,7 +253,7 @@ def test_pipeline_recover_after_failed_sync_keeps_parity():
     sched.recover()
     assert not sched.in_flight
     done = sched.run()                         # re-packs from host state
-    ref = solver.solve(graph.queen(5), **FAST)
+    ref = ladder.solve(graph.queen(5), **FAST)
     assert (done[rid].width, done[rid].exact, done[rid].expanded) == \
         (ref.width, ref.exact, ref.expanded)
 
@@ -276,7 +276,7 @@ def test_poisoned_admission_is_isolated_and_emits_error(event_invariants):
     assert event_invariants(evs, rid=bad)["event"] == "error"
     st = sched.status(bad)
     assert st["state"] == "error" and "AttributeError" in st["error"]
-    ref = solver.solve(graph.petersen(), **FAST)
+    ref = ladder.solve(graph.petersen(), **FAST)
     assert (done[good].width, done[good].expanded) == \
         (ref.width, ref.expanded)
 
@@ -305,7 +305,7 @@ def test_event_sinks_run_outside_the_scheduler_lock():
     rid = sched.submit(graph.petersen(), on_event=probe)
     done = sched.run()
     assert lock_free and all(lock_free)
-    assert done[rid].width == solver.solve(graph.petersen(), **FAST).width
+    assert done[rid].width == ladder.solve(graph.petersen(), **FAST).width
 
 
 def test_event_ordering_guarantees_survive_deferred_delivery(
@@ -337,7 +337,7 @@ def test_synthetic_overload_stream_degrades_gracefully():
     """The acceptance scenario: queue at its bound, mixed priorities, one
     deadline-bound and one cancelled request.  The service rejects with
     ``retry_after``, preempts and cancels correctly, and every surviving
-    request's result is bit-identical to sequential ``solver.solve``."""
+    request's result is bit-identical to sequential ``ladder.solve``."""
     sched = TwScheduler(lanes=2, max_queue=2, prio_weight=2, **FAST)
     surv_a = sched.submit(graph.petersen())              # takes a lane
     doomed = sched.submit(graph.queen(6))                # takes a lane
@@ -356,12 +356,12 @@ def test_synthetic_overload_stream_degrades_gracefully():
 
     assert sched.terminal[victim] == "cancelled" and victim not in done
     assert sched.terminal[doomed] == "timeout"
-    ref_doomed = solver.solve(graph.queen(6), **FAST)
+    ref_doomed = ladder.solve(graph.queen(6), **FAST)
     assert not done[doomed].exact
     assert done[doomed].lb <= ref_doomed.width <= done[doomed].ub
     for rid, g in ((surv_a, graph.petersen()), (surv_b, graph.myciel(3)),
                    (surv_c, graph.queen(5))):
-        ref = solver.solve(g, **FAST)
+        ref = ladder.solve(g, **FAST)
         assert (done[rid].width, done[rid].exact, done[rid].expanded,
                 done[rid].per_k) == (ref.width, ref.exact, ref.expanded,
                                      ref.per_k)

@@ -11,7 +11,7 @@ import socket
 
 import pytest
 
-from repro.core import graph, solver
+from repro.core import graph, ladder, solver
 from repro.launch.twserved import TwServer
 from repro.serve.client import TwClient, TwServerError
 
@@ -41,7 +41,7 @@ def test_submit_stream_result_roundtrip(server):
                for a, b in zip(bounds, bounds[1:]))
 
     res = c.result(rid)
-    ref = solver.solve(graph.petersen(), cap=1 << 12, block=BLOCK)
+    ref = ladder.solve(graph.petersen(), cap=1 << 12, block=BLOCK)
     assert (res["width"], res["exact"], res["expanded"]) == \
         (ref.width, ref.exact, ref.expanded)
     st = c.status(rid)
@@ -55,7 +55,7 @@ def test_submit_wire_graph_with_per_request_knobs(server):
     g = graph.myciel(3)
     rid = c.submit(g, mode="bloom", speculate=2)     # Graph over the wire
     res = c.result(rid)
-    ref = solver.solve(g, cap=1 << 12, block=BLOCK, mode="bloom",
+    ref = ladder.solve(g, cap=1 << 12, block=BLOCK, mode="bloom",
                        m_bits=1 << 14)
     assert (res["width"], res["exact"]) == (ref.width, ref.exact)
     rid2 = c.submit(g, reconstruct=True)
@@ -73,7 +73,7 @@ def test_invalid_submits_fail_per_request_and_pool_survives(server):
     with pytest.raises(TwServerError, match="unknown rid"):
         c.result(999)
     rid = c.submit("petersen")                       # pool still serving
-    ref = solver.solve(graph.petersen(), cap=1 << 12, block=BLOCK)
+    ref = ladder.solve(graph.petersen(), cap=1 << 12, block=BLOCK)
     assert c.result(rid)["width"] == ref.width
 
 
@@ -142,7 +142,7 @@ def test_cancel_over_the_wire(server):
         c.result(rid)
     assert c.status(rid)["state"] == "cancelled"
     other = c.submit("petersen")                   # pool keeps serving
-    ref = solver.solve(graph.petersen(), cap=1 << 12, block=BLOCK)
+    ref = ladder.solve(graph.petersen(), cap=1 << 12, block=BLOCK)
     assert c.result(other)["width"] == ref.width
 
 
@@ -151,7 +151,7 @@ def test_deadline_and_priority_knobs_ride_the_submit_line(server):
     # an unhit deadline and a priority class change nothing about the result
     rid = c.submit("petersen", priority=1, deadline_s=3600.0)
     res = c.result(rid)
-    ref = solver.solve(graph.petersen(), cap=1 << 12, block=BLOCK)
+    ref = ladder.solve(graph.petersen(), cap=1 << 12, block=BLOCK)
     assert (res["width"], res["exact"], res["expanded"]) == \
         (ref.width, ref.exact, ref.expanded)
     assert "timed_out" not in res
@@ -217,7 +217,7 @@ def test_eviction_skips_logs_with_blocked_readers():
             c.result(c.submit("myciel3"))
         t.join(timeout=120)
         assert not t.is_alive()
-        ref = solver.solve(graph.queen(6), cap=1 << 12, block=BLOCK)
+        ref = ladder.solve(graph.queen(6), cap=1 << 12, block=BLOCK)
         assert (got["res"]["width"], got["res"]["exact"]) == \
             (ref.width, ref.exact)
     finally:
